@@ -2,7 +2,10 @@
 
     PYTHONPATH=src python -m benchmarks.run
 
-Prints ``name,us_per_call,derived`` CSV per benchmark:
+Runs each module in a process of its own, one after another; this parent
+never imports JAX, so a module that needs the accelerator can take it.
+Exits nonzero if any module failed. Each prints ``name,us_per_call,derived``
+CSV:
   bench_rounds      — Theorem 1 & 2 round complexity scaling
   bench_accuracy    — Monte-Carlo accuracy vs K (Avrachenkov claim)
   bench_congestion  — Lemma 1/3 per-edge message bits
@@ -13,7 +16,9 @@ Prints ``name,us_per_call,derived`` CSV per benchmark:
   bench_kernels     — Pallas kernel micro-benches + TPU roofline estimates
   roofline_report   — dry-run roofline aggregation (all cells)
 """
-import importlib
+import os
+import subprocess
+import sys
 
 MODULES = [
     "benchmarks.bench_rounds",
@@ -26,16 +31,21 @@ MODULES = [
     "benchmarks.bench_kernels",
     "benchmarks.roofline_report",
 ]
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
 
-def main() -> None:
+def main() -> int:
+    failed = []
     for name in MODULES:
         print(f"\n=== {name} ===", flush=True)
-        try:
-            importlib.import_module(name).main()
-        except Exception as e:  # noqa: BLE001 — report and continue
-            print(f"{name},0,ERROR={type(e).__name__}:{e}")
+        rc = subprocess.run([sys.executable, "-m", name], cwd=ROOT).returncode
+        if rc != 0:
+            print(f"{name},0,ERROR=exit code {rc}", flush=True)
+            failed.append(name)
+    if failed:
+        print(f"\nfailed: {', '.join(failed)}", flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

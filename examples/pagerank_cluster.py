@@ -28,11 +28,11 @@ def main():
         print("--- clean run ---")
         pi_clean = run(n=256, eps=0.2, walks_per_node=64,
                        graph_kind="erdos_renyi", checkpoint_dir=None,
-                       fail_at=[])
+                       fail_at=[]).pi
         print("--- run with failures at rounds 6 and 17 ---")
         pi_ft = run(n=256, eps=0.2, walks_per_node=64,
                     graph_kind="erdos_renyi", checkpoint_dir=ckpt_dir,
-                    fail_at=[6, 17])
+                    fail_at=[6, 17]).pi
     exact = np.array_equal(np.asarray(pi_clean), np.asarray(pi_ft))
     print(f"recovered run bit-exact with clean run: {exact}")
     assert exact

@@ -99,18 +99,17 @@ def _layout_cached(deg_bytes: bytes, rows_per_shard: int, shards: int,
     n_b = int(np.ceil(np.log2(max_deg))) + 1
     widths = tuple(min(1 << b, max_deg) for b in range(n_b))
     b_of = bucket_of(deg)
-    counts = np.zeros((shards, n_b), np.int64)
-    for p in range(shards):
-        np.add.at(counts[p], b_of[p], 1)
+    counts = np.stack([np.bincount(b, minlength=n_b) for b in b_of])
     caps = tuple(int(c) for c in counts.max(axis=0))
     starts = np.concatenate([[0], np.cumsum(caps)[:-1]])
     perm = np.full((shards, int(sum(caps))), -1, np.int32)
     for p in range(shards):
-        fill = starts.copy()
-        for r in range(rows_per_shard):
-            b = b_of[p, r]
-            perm[p, fill[b]] = r
-            fill[b] += 1
+        # rows grouped by bucket, ascending row id within a bucket
+        order = np.argsort(b_of[p], kind="stable")
+        b_sorted = b_of[p][order]
+        first = np.concatenate([[0], np.cumsum(counts[p])[:-1]])
+        rank = np.arange(rows_per_shard) - first[b_sorted]
+        perm[p, starts[b_sorted] + rank] = order
     layout = BucketLayout(widths=widths, caps=caps, n_rows=rows_per_shard)
     return layout, perm
 
@@ -135,31 +134,47 @@ def build_layout_sharded(deg: np.ndarray, max_deg: int, *,
                           bool(bucketed))
 
 
-def bucketize_adjacency(nbr: np.ndarray, perm: np.ndarray,
-                        layout: BucketLayout, *,
-                        pad_dst: int = 0) -> np.ndarray:
-    """Flat bucketed neighbor table [*, total_edges]: bucket b contributes
-    a [caps[b], widths[b]] block of `nbr[perm]` rows (row-major). Padding
-    slots point at `pad_dst` — they only ever carry zero counts.
+def bucketize_csr(row_ptr: np.ndarray, col_idx: np.ndarray,
+                  deg: np.ndarray, perm: np.ndarray, layout: BucketLayout, *,
+                  pad_dst: int = 0) -> np.ndarray:
+    """Flat bucketed neighbor table [*, total_edges], built straight from
+    the CSR: bucket b contributes a [widths[b], caps[b]] block (slot-major)
+    whose column i holds the first widths[b] out-neighbours of row
+    perm[i]. Slot-major matches `flatten_moves`: on TPU, flattening a
+    [caps[b], widths[b]] block row-major makes the compiler relayout a
+    narrow minor dimension, which takes minutes to compile at n = 2^22.
 
-    Round-trips to the flat padded adjacency bit-exactly: row perm[i]'s
-    first widths[b] slots are nbr[perm[i], :widths[b]], and every slot
-    beyond a row's bucket width is structurally count-free because the
-    row's degree is <= its bucket width (tests/test_property.py).
+    `deg` is the [shards, rows] (or [rows]) degree matrix the layout was
+    built from; row r of shard p is global vertex p * rows + r, and rows
+    past the CSR's last vertex have degree 0. Slots past a row's degree,
+    and every slot of a padding row (perm == -1), point at `pad_dst` —
+    they only ever carry zero counts, because a row's degree is <= its
+    bucket width (tests/test_property.py).
     """
-    nbr = np.asarray(nbr)
-    lead = nbr.shape[:-2]
-    flat = np.empty(lead + (layout.total_edges,), nbr.dtype)
+    row_ptr = np.asarray(row_ptr, np.int64)
+    n = len(row_ptr) - 1
+    col = np.concatenate([np.asarray(col_idx, np.int32),
+                          np.asarray([pad_dst], np.int32)])
+    m = len(col) - 1
+    perm2 = np.atleast_2d(perm)
+    deg2 = np.asarray(deg).reshape(perm2.shape[0], -1)
+    rows_per_shard = deg2.shape[1]
+    base = (np.arange(perm2.shape[0]) * rows_per_shard)[:, None]
+    flat = np.empty((perm2.shape[0], layout.total_edges), np.int32)
     s_rows, s_edges = 0, 0
     for cap, w in zip(layout.caps, layout.widths):
-        rows = perm[..., s_rows:s_rows + cap]
-        blk = np.take_along_axis(
-            nbr[..., :w], np.maximum(rows, 0)[..., None], axis=-2)
-        blk = np.where((rows < 0)[..., None], pad_dst, blk)
-        flat[..., s_edges:s_edges + cap * w] = blk.reshape(lead + (cap * w,))
+        rows = perm2[:, s_rows:s_rows + cap]
+        ok_row = rows >= 0
+        v = np.minimum(np.where(ok_row, rows + base, n), n)
+        d = np.where(ok_row, np.take_along_axis(deg2, np.maximum(rows, 0),
+                                                axis=1), 0)
+        j = np.arange(w)
+        idx = np.where(j < d[..., None], row_ptr[v][..., None] + j, m)
+        flat[:, s_edges:s_edges + cap * w] = col[idx].transpose(
+            0, 2, 1).reshape(len(perm2), cap * w)
         s_rows += cap
         s_edges += cap * w
-    return flat
+    return flat.reshape(np.shape(perm)[:-1] + (layout.total_edges,))
 
 
 def sample_buckets(counts, deg, rid, key_words, perm, layout: BucketLayout,
@@ -196,9 +211,9 @@ def sample_buckets(counts, deg, rid, key_words, perm, layout: BucketLayout,
 
 
 def flatten_moves(samples) -> jnp.ndarray:
-    """Per-edge counts [total_edges] aligned with `bucketize_adjacency`
-    (termination column dropped)."""
-    return jnp.concatenate([T[:, 1:].reshape(-1) for _, T in samples])
+    """Per-edge counts [total_edges] aligned with `bucketize_csr`
+    (termination column dropped, each bucket's block slot-major)."""
+    return jnp.concatenate([T[:, 1:].T.reshape(-1) for _, T in samples])
 
 
 def scatter_cells(samples, layout: BucketLayout, max_deg: int

@@ -49,7 +49,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.aggregate_sampler import (BucketLayout, build_layout_sharded,
-                                          bucketize_adjacency, flatten_moves,
+                                          bucketize_csr, flatten_moves,
                                           sample_buckets)
 from repro.core.distributed import AXIS, shard_map
 from repro.core.estimator import pagerank_from_visits
@@ -63,22 +63,21 @@ from repro.runtime import Stage, StagedState, StageSchedule, run_staged
 
 @dataclasses.dataclass(frozen=True)
 class ShardedPaddedGraph:
-    """Per-shard padded adjacency with static cross-shard lane bounds and
-    the degree-bucketed sampler layout (see `core/aggregate_sampler`)."""
+    """Per-shard degree-bucketed adjacency (see `core/aggregate_sampler`)
+    with static cross-shard lane bounds. Host numpy arrays: the engine
+    places them on its mesh itself."""
 
     n: int
     n_pad: int
     n_loc: int
     shards: int
     max_deg: int
-    nbr: jnp.ndarray        # [P, n_loc, max_deg] global dst (self-padded)
-    valid: jnp.ndarray      # [P, n_loc, max_deg]
-    deg: jnp.ndarray        # [P, n_loc]
+    deg: np.ndarray         # [P, n_loc]
     lane_cap: int           # max edges crossing any (src,dst) shard pair
     layout: BucketLayout    # shard-uniform bucket caps/widths (static)
-    bperm: jnp.ndarray      # [P, layout.total_rows] bucket-grouped local
+    bperm: np.ndarray       # [P, layout.total_rows] bucket-grouped local
                             # row ids (-1 = padding slot)
-    bnbr: jnp.ndarray       # [P, layout.total_edges] flat bucketed dst
+    bnbr: np.ndarray        # [P, layout.total_edges] flat bucketed dst
 
 
 def shard_graph_padded(graph: CSRGraph, shards: int, *,
@@ -86,36 +85,44 @@ def shard_graph_padded(graph: CSRGraph, shards: int, *,
     n_loc = math.ceil(graph.n / shards)
     n_pad = n_loc * shards
     md = max(graph.max_out_deg, 1)
-    rp = np.asarray(graph.row_ptr)
     col = np.asarray(graph.col_idx)
     degs = np.asarray(graph.out_deg)
-    nbr = np.tile(np.arange(n_pad, dtype=np.int32)[:, None] * 0, (1, md))
-    nbr = np.zeros((n_pad, md), np.int32)
-    valid = np.zeros((n_pad, md), bool)
-    for v in range(graph.n):
-        d = degs[v]
-        nbr[v, :d] = col[rp[v]:rp[v] + d]
-        valid[v, :d] = True
-    deg_pad = np.concatenate([degs, np.zeros(n_pad - graph.n, np.int32)])
+    deg_sh = np.zeros(n_pad, np.int32)
+    deg_sh[:graph.n] = degs
+    deg_sh = deg_sh.reshape(shards, n_loc)
     # static lane bound: edges from shard p to shard q
-    cut = np.zeros((shards, shards), np.int64)
-    owner_of = lambda v: v // n_loc
     src_owner = np.repeat(np.arange(graph.n) // n_loc, degs)
-    dst_owner = col // n_loc
-    np.add.at(cut, (src_owner, dst_owner), 1)
+    cut = np.bincount(src_owner * shards + col // n_loc,
+                      minlength=shards * shards)
     # lanes hold (vertex,count) pairs: at most min(cut, n_loc) distinct
-    lane_cap = int(min(cut.max(), n_loc)) or 1
-    deg_sh = deg_pad.reshape(shards, n_loc)
-    nbr_sh = nbr.reshape(shards, n_loc, md)
+    lane_cap = int(min(cut.max(initial=0), n_loc)) or 1
     layout, bperm = build_layout_sharded(deg_sh, md, bucketed=bucketed)
-    bnbr = bucketize_adjacency(nbr_sh, bperm, layout)
+    bnbr = bucketize_csr(graph.row_ptr, col, deg_sh, bperm, layout)
     return ShardedPaddedGraph(
         n=graph.n, n_pad=n_pad, n_loc=n_loc, shards=shards, max_deg=md,
-        nbr=jnp.asarray(nbr_sh),
-        valid=jnp.asarray(valid.reshape(shards, n_loc, md)),
-        deg=jnp.asarray(deg_sh),
-        lane_cap=lane_cap,
-        layout=layout, bperm=jnp.asarray(bperm), bnbr=jnp.asarray(bnbr))
+        deg=deg_sh, lane_cap=lane_cap, layout=layout, bperm=bperm,
+        bnbr=bnbr)
+
+
+# Packed lanes carry (local vid:16b | count:15b) in one int32, plus one
+# spill entry per vertex, so they are exact only while every local vertex
+# id fits 16 bits and no vertex can receive more than 2 * _PACK_CMAX walks
+# in a round (bounded by the total walk count).
+_PACK_CMAX = 32767
+
+
+def resolve_packed(packed: Optional[bool], n_loc: int,
+                   total_walks: int) -> bool:
+    """`None` packs whenever packing is exact; an explicit True that
+    cannot be exact is refused rather than silently losing counts."""
+    fits = n_loc <= 1 << 16 and total_walks <= 2 * _PACK_CMAX
+    if packed is None:
+        return fits
+    if packed and not fits:
+        raise ValueError(
+            f"packed count lanes need n_loc <= 65536 and n * walks <= "
+            f"{2 * _PACK_CMAX}; got n_loc={n_loc}, walks={total_walks}")
+    return bool(packed)
 
 
 @jax.tree_util.register_dataclass
@@ -166,6 +173,12 @@ def _exchange_step(bnbr, flat_T, zeta, *, n_loc: int, shards: int,
         jnp.where(local_mask, flat_T, 0),
         jnp.clip(flat_dst - shard_id * n_loc, 0, n_loc - 1),
         num_segments=n_loc)
+    if shards == 1:
+        # one shard owns every vertex: no lane can fill, so skip the lane
+        # packing below (a stable sort and scatters over every vertex)
+        zero = jnp.int32(0)
+        return (arrive[None], (zeta + arrive)[None],
+                jax.lax.psum(jnp.sum(arrive), AXIS), zero, zero, zero)
 
     # cross-shard: aggregate counts per destination vertex, then lane-pack
     # (vertex, count) per target shard. Aggregate first so the lane bound
@@ -177,12 +190,12 @@ def _exchange_step(bnbr, flat_T, zeta, *, n_loc: int, shards: int,
     if packed:
         # 4B lanes: (local vid:16b | count:15b) — 15-bit count keeps the
         # packed int32 non-negative (-1 stays the empty sentinel); larger
-        # counts spill into a second entry for the same vertex.
-        CMAX = 32767
-        spill = jnp.maximum(per_vertex - CMAX, 0)
-        c_main = jnp.minimum(per_vertex, CMAX)
+        # counts spill into a second entry for the same vertex (see
+        # `resolve_packed` for when that is exact).
+        spill = jnp.maximum(per_vertex - _PACK_CMAX, 0)
+        c_main = jnp.minimum(per_vertex, _PACK_CMAX)
         vid2 = jnp.concatenate([vid, vid])
-        cnt2 = jnp.concatenate([c_main, jnp.minimum(spill, CMAX)])
+        cnt2 = jnp.concatenate([c_main, jnp.minimum(spill, _PACK_CMAX)])
     else:
         vid2 = vid
         cnt2 = per_vertex
@@ -304,7 +317,7 @@ class CountDistResult:
 def distributed_pagerank_counts(graph: CSRGraph, eps: float,
                                 walks_per_node: int, key: jnp.ndarray, *,
                                 mesh: Optional[Mesh] = None,
-                                packed: bool = True,
+                                packed: Optional[bool] = None,
                                 max_rounds: int = 100_000,
                                 checkpoint_dir: Optional[str] = None,
                                 fail_at: Optional[Sequence[int]] = None,
@@ -321,6 +334,8 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
     PRNG keys), so the recovered run is bit-exact. `bucketed=False` keeps
     the single-bucket max_deg-wide sampler layout (pre-bucketing shape,
     for benchmarking); the draws themselves are layout-independent.
+    `packed=None` ships 4-byte packed lanes where they are exact and
+    8-byte (vertex, count) lanes otherwise (`resolve_packed`).
 
     Snapshots are mesh-size-agnostic: the round key is REPLICATED across
     shards (every shard advances the same stream; draws are distinguished
@@ -334,6 +349,7 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
     use_pallas = resolve_use_pallas(use_pallas)
     shards = mesh.devices.size
     sg = shard_graph_padded(graph, shards, bucketed=bucketed)
+    packed = resolve_packed(packed, sg.n_loc, graph.n * walks_per_node)
     spec = NamedSharding(mesh, P(AXIS))
 
     counts0 = np.zeros((shards, sg.n_loc), np.int32)
@@ -341,7 +357,7 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
     # REPLICATED round key: every shard splits the same stream, draws are
     # distinguished only by the counter-based global vertex id — so the
     # trajectory is a pure function of (seed, graph), not the mesh size
-    keys = jnp.tile(jnp.asarray(key)[None], (shards, 1))
+    keys = np.tile(np.asarray(key)[None], (shards, 1))
     deg = jax.device_put(sg.deg, spec)
     bperm = jax.device_put(sg.bperm, spec)
     bnbr = jax.device_put(sg.bnbr, spec)
@@ -377,8 +393,8 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
     schedule = StageSchedule([Stage("counts", _step)])
     ms = StagedState(
         stage=schedule.first_stage,
-        arrays=dict(counts=jax.device_put(jnp.asarray(counts0), spec),
-                    zeta=jax.device_put(jnp.asarray(counts0), spec),
+        arrays=dict(counts=jax.device_put(counts0, spec),
+                    zeta=jax.device_put(counts0, spec),
                     key=jax.device_put(keys, spec),
                     round=jnp.int32(0)),
         host=dict(rounds=0, a2a=0, a2a_entries=0, overflow=0, sampler_us=0.0,
@@ -388,7 +404,7 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
 
     def _put(name, arr):
         return (jnp.asarray(arr) if name == "round"
-                else jax.device_put(jnp.asarray(arr), spec))
+                else jax.device_put(np.asarray(arr), spec))
 
     ms, restarts, checkpoints_written = run_staged(
         schedule, ms, _put, checkpoint_dir=checkpoint_dir, fail_at=fail_at,
@@ -411,7 +427,7 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
 
 
 def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
-               walks_per_node: int = 2, packed: bool = True,
+               walks_per_node: int = 2, packed: Optional[bool] = None,
                use_pallas: bool = False, bucketed: bool = True):
     """CONGEST-auditor spec: the exact memoized step programs the engine
     runs (same cache keys => same traced jaxprs), the declared wire budget
@@ -420,6 +436,7 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
                                        StageProgram)
     shards = int(mesh.devices.size)
     sg = shard_graph_padded(graph, shards, bucketed=bucketed)
+    packed = resolve_packed(packed, sg.n_loc, graph.n * walks_per_node)
     n_loc = sg.n_loc
     sample, exchange = make_count_superstep(
         mesh, float(eps), n_loc=n_loc, shards=shards, layout=sg.layout,
@@ -449,8 +466,10 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
         StageProgram(stage="counts", program="sample", fn=sample,
                      example_args=(bperm, deg, state), sites=(),
                      count_bound=graph.n * walks_per_node),
+        # a one-shard exchange has no wire (see `_exchange_step`)
         StageProgram(stage="counts", program="exchange", fn=exchange,
-                     example_args=(bnbr, flat_T, key, state), sites=(site,),
+                     example_args=(bnbr, flat_T, key, state),
+                     sites=(site,) if shards > 1 else (),
                      count_bound=graph.n * walks_per_node),
     ]
     return EngineAuditSpec(

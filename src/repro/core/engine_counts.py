@@ -30,9 +30,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.accounting import RoundTrace
-from repro.core.aggregate_sampler import (build_layout, bucketize_adjacency,
+from repro.core.aggregate_sampler import (build_layout, bucketize_csr,
                                           flatten_moves, sample_buckets)
-from repro.core.graph import CSRGraph, padded_adjacency
+from repro.core.graph import CSRGraph
 from repro.kernels import resolve_use_pallas
 from repro.kernels.multinomial_rows._math import key_words
 
@@ -106,11 +106,11 @@ def run_traced(graph: CSRGraph, eps: float, walks_per_node: int,
                use_pallas=None, bucketed: bool = True
                ) -> Tuple[CountState, List[RoundTrace]]:
     use_pallas = resolve_use_pallas(use_pallas)
-    nbr, _ = padded_adjacency(graph)
-    max_deg = int(nbr.shape[1])
-    layout, perm_np = build_layout(np.asarray(graph.out_deg), max_deg,
+    deg = np.asarray(graph.out_deg)
+    layout, perm_np = build_layout(deg, max(graph.max_out_deg, 1),
                                    bucketed=bucketed)
-    bnbr = jnp.asarray(bucketize_adjacency(np.asarray(nbr), perm_np, layout))
+    bnbr = jnp.asarray(bucketize_csr(graph.row_ptr, graph.col_idx, deg,
+                                     perm_np, layout))
     perm = jnp.asarray(perm_np)
     state = init_state(graph, walks_per_node, key)
     traces: List[RoundTrace] = []

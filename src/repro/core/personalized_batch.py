@@ -102,9 +102,16 @@ def _ppr_superstep(rp, ci, dg, pos, qid, zeta, key, *, eps: float,
     u = dst * Q + qid
     per_virtual = vertex_histogram(u, survive, shards * n_loc * Q,
                                    use_pallas=use_pallas)
-    arrivals, sent_entries, sent_bytes = route_counts(
-        per_virtual, axis=AXIS, shard_id=shard_id, n_loc=n_loc * Q,
-        shards=shards, use_pallas=use_pallas, count_bound=count_bound)
+    if shards == 1:
+        # one shard owns every lane: nothing crosses the wire, and
+        # `route_counts` would sort and scatter all n * Q lanes only to
+        # hand them back (seconds per superstep at n = 2^22, Q = 8)
+        arrivals, sent_entries, sent_bytes = (per_virtual, jnp.int32(0),
+                                              jnp.int32(0))
+    else:
+        arrivals, sent_entries, sent_bytes = route_counts(
+            per_virtual, axis=AXIS, shard_id=shard_id, n_loc=n_loc * Q,
+            shards=shards, use_pallas=use_pallas, count_bound=count_bound)
 
     # every arrival is a visit to an owned vertex
     zeta = zeta + arrivals.reshape(n_loc, Q)
@@ -405,8 +412,10 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
                         "lanes — Lemma 1 extended by the query-id lane"),
         wire_class="count",
         note="bounded by distinct (vertex, query) pairs, never walk count")
+    # a one-shard superstep has no wire (see `_ppr_superstep`)
     prog = StageProgram(stage="serve", program="superstep", fn=engine._step,
-                        example_args=args, sites=(site,),
+                        example_args=args,
+                        sites=(site,) if shards > 1 else (),
                         count_bound=walks_per_query)
     return EngineAuditSpec(
         engine="ppr", programs=[prog],

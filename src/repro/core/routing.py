@@ -33,8 +33,9 @@ added or dropped.
 `advance_owned` and `count_owned_arrivals` accept `use_pallas` to run the
 per-walk advancement / histogram through the Pallas kernels in
 `repro.kernels` (`walk_step`, `histogram`); the kernels are bit-identical
-to the jnp paths (same uniforms, same decision logic) and fall back to
-interpret mode off-TPU.
+to the jnp paths (same uniforms, same decision logic) and run in
+interpret mode off-TPU; `walk_step` refuses to run compiled on TPU (see
+`kernels/walk_step/ops.py`).
 
 All helpers run *inside* shard_map: `jax.lax.axis_index`/`all_to_all` refer
 to the mesh axis passed as `axis`.
@@ -50,22 +51,13 @@ from repro.kernels import histogram as _histogram_kernel
 from repro.kernels import segment_spmv as _segment_spmv_kernel
 from repro.kernels import walk_step as _walk_step_kernel
 
-try:  # jax >= 0.6 stable API
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        # check_vma=False: jax.random.binomial's internal while_loop mixes
-        # varying/invariant carries under the VMA checker; collectives in
-        # our supersteps are explicit (psum/all_to_all), so the check adds
-        # nothing.
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    # check_vma=False: jax.random.binomial's internal while_loop mixes
+    # varying/invariant carries under the VMA checker; collectives in our
+    # supersteps are explicit (psum/all_to_all), so the check adds nothing.
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def rank_within(sort_key: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -86,9 +78,13 @@ def rank_within(sort_key: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     idx = jnp.arange(W)
     is_start = jnp.concatenate([jnp.ones((1,), bool),
                                 sorted_k[1:] != sorted_k[:-1]])
-    run_start = jax.lax.associative_scan(jnp.maximum,
-                                         jnp.where(is_start, idx, 0))
-    rank_sorted = idx - run_start
+    # each run's first index, found by run number (a cumsum) rather than
+    # a running max: on TPU `associative_scan` takes minutes to compile
+    # at millions of elements, a cumsum and a scatter take seconds
+    run = jnp.cumsum(is_start.astype(jnp.int32)) - 1
+    first = jnp.zeros((W,), idx.dtype).at[
+        jnp.where(is_start, run, W)].set(idx, mode="drop")
+    rank_sorted = idx - first[run]
     rank = jnp.zeros((W,), jnp.int32).at[order].set(
         rank_sorted.astype(jnp.int32))
     return rank, order

@@ -6,8 +6,17 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# XLA lays a 1-D 32-bit array out in tiles of 1024 elements on TPU; a
+# 1-D block must be a multiple of it or Mosaic refuses the operand layout
+LANE_TILE = 1024
+
+
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def round_up(a: int, multiple: int) -> int:
+    return cdiv(a, multiple) * multiple
 
 
 def pad_to(x: jnp.ndarray, multiple: int, axis: int = 0, fill=0) -> jnp.ndarray:

@@ -10,8 +10,8 @@ one-hot reduction:
 Grid: (vertex_blocks, id_blocks); for a fixed vertex block the id blocks
 iterate minormost and accumulate into the same VMEM output tile, so each
 output tile is written once. ids == -1 (dead/masked walks) never match and
-are naturally dropped. Block sizes are lane-aligned (multiples of 128) for
-the 8x128 VPU.
+are naturally dropped. Both 1-D blocks are multiples of the 1024-element
+tile XLA gives a 1-D int32 array on TPU.
 """
 from __future__ import annotations
 
@@ -21,11 +21,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import cdiv
+from repro.kernels.common import LANE_TILE, cdiv, round_up
 
 
 DEFAULT_BLOCK_IDS = 2048
-DEFAULT_BLOCK_N = 512
+DEFAULT_BLOCK_N = 1024
 
 
 def _hist_kernel(ids_ref, out_ref, *, block_n: int):
@@ -59,7 +59,7 @@ def histogram_pallas(ids: jnp.ndarray, num_segments: int, *,
     ids entries outside [0, num_segments) are ignored (use -1 to mask).
     """
     W = ids.shape[0]
-    block_ids = min(block_ids, max(256, W))
+    block_ids = min(block_ids, round_up(max(W, 1), LANE_TILE))
     n_pad = cdiv(num_segments, block_n) * block_n
     w_pad = cdiv(max(W, 1), block_ids) * block_ids
     ids_p = jnp.full((w_pad,), -1, jnp.int32).at[:W].set(ids.astype(jnp.int32))

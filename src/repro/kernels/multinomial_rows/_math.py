@@ -59,7 +59,9 @@ def counter_u01(rid, t, k0, k1):
     h = _fmix32((_u32(rid) * _u32(0x9E3779B1)) ^ _u32(k0))
     h = _fmix32(h + ((_u32(t) * _u32(0x85EBCA77)) ^ _u32(k1)))
     # 24 mantissa bits, offset half a ulp: strictly inside (0, 1)
-    return ((h >> _u32(8)).astype(jnp.float32) + 0.5) * jnp.float32(2.0 ** -24)
+    # via int32 (exact below 2**24): Mosaic has no uint32 -> float32 cast
+    return (((h >> _u32(8)).astype(jnp.int32).astype(jnp.float32) + 0.5)
+            * jnp.float32(2.0 ** -24))
 
 
 def _ndtri(u):
@@ -134,32 +136,41 @@ def binomial_counter(n, p, u):
     return jnp.where(flip, n - x, x)
 
 
-def sample_rows_math(counts, deg, rid, k0, k1, *, eps: float, width: int):
-    """Fused termination + conditional-binomial chain for a block of rows.
-
-    counts/deg/rid: [R] int32. Returns T [R, width+1] int32 where column 0
-    is the termination count (a dangling row — deg == 0 — terminates
-    whole) and column 1+j the count sent down out-edge slot j. Rows with
-    deg <= width conserve mass exactly: T.sum(1) == counts, because the
-    last live slot draws p == 1 (endpoint-exact) and every draw is
-    clipped to [0, remaining].
-    """
+def termination(counts, deg, rid, k0, k1, *, eps: float):
+    """Column 0 of the fused sampler: (term, rem) with `term` the
+    Binomial(counts, eps) termination count (a dangling row — deg == 0 —
+    terminates whole) and `rem = counts - term` the survivors."""
     counts = counts.astype(jnp.int32)
-    deg = deg.astype(jnp.int32)
     u_t = counter_u01(rid, 0, k0, k1)
     term = jnp.where(deg > 0,
                      binomial_counter(counts, jnp.float32(eps), u_t),
                      counts)
-    rem0 = counts - term
+    return term, counts - term
 
-    def body(rem, j):
-        u = counter_u01(rid, j + 1, k0, k1)
-        slots = jnp.maximum(deg - j, 1).astype(jnp.float32)
-        p = jnp.where(j < deg, 1.0 / slots, 0.0)
-        t = jnp.minimum(binomial_counter(rem, p, u), rem)
-        return rem - t, t
 
-    _, T = jax.lax.scan(body, rem0, jnp.arange(width, dtype=jnp.int32))
+def chain_slot(rem, j, deg, rid, k0, k1):
+    """Slot j of the conditional-binomial chain: (rem - t, t) with `t` the
+    count sent down out-edge slot j of the `rem` survivors still unsplit."""
+    u = counter_u01(rid, j + 1, k0, k1)
+    slots = jnp.maximum(deg - j, 1).astype(jnp.float32)
+    p = jnp.where(j < deg, 1.0 / slots, 0.0)
+    t = jnp.minimum(binomial_counter(rem, p, u), rem)
+    return rem - t, t
+
+
+def sample_rows_math(counts, deg, rid, k0, k1, *, eps: float, width: int):
+    """Fused termination + conditional-binomial chain for a block of rows.
+
+    counts/deg/rid: [R] int32. Returns T [R, width+1] int32 where column 0
+    is the termination count and column 1+j the count sent down out-edge
+    slot j. Rows with deg <= width conserve mass exactly: T.sum(1) ==
+    counts, because the last live slot draws p == 1 (endpoint-exact) and
+    every draw is clipped to [0, remaining].
+    """
+    deg = deg.astype(jnp.int32)
+    term, rem0 = termination(counts, deg, rid, k0, k1, eps=eps)
+    _, T = jax.lax.scan(lambda rem, j: chain_slot(rem, j, deg, rid, k0, k1),
+                        rem0, jnp.arange(width, dtype=jnp.int32))
     return jnp.concatenate([term[:, None], T.T], axis=1)
 
 

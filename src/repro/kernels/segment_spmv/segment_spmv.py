@@ -19,11 +19,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import cdiv
+from repro.kernels.common import LANE_TILE, cdiv, round_up
 
 
 DEFAULT_BLOCK_E = 2048
-DEFAULT_BLOCK_N = 512
+DEFAULT_BLOCK_N = 1024
 
 
 def _spmv_kernel(val_ref, dst_ref, out_ref, *, block_n: int):
@@ -57,7 +57,7 @@ def segment_spmv_pallas(values: jnp.ndarray, dst: jnp.ndarray,
                         interpret: bool = True) -> jnp.ndarray:
     """y[v] = sum over edges e with dst[e]==v of values[e]  (fp32)."""
     E = values.shape[0]
-    block_e = min(block_e, max(256, E))
+    block_e = min(block_e, round_up(max(E, 1), LANE_TILE))
     n_pad = cdiv(num_segments, block_n) * block_n
     e_pad = cdiv(max(E, 1), block_e) * block_e
     val_p = jnp.zeros((e_pad,), values.dtype).at[:E].set(values)

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import cdiv
+from repro.kernels.common import LANE_TILE, cdiv, round_up
 
 
 DEFAULT_BLOCK_W = 4096
@@ -61,7 +61,7 @@ def walk_step_pallas(pos: jnp.ndarray, alive: jnp.ndarray,
                      interpret: bool = True):
     """Returns (new_pos [W] int32, new_alive [W] int32/bool-ish)."""
     W = pos.shape[0]
-    block_w = min(block_w, max(256, W))
+    block_w = min(block_w, round_up(max(W, 1), LANE_TILE))
     w_pad = cdiv(max(W, 1), block_w) * block_w
     pad = lambda x, fill: jnp.full((w_pad,), fill, x.dtype).at[:W].set(x)
     grid = (w_pad // block_w,)

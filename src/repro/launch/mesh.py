@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -21,7 +21,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     need = int(np.prod(shape))
     devs = jax.devices()
     if len(devs) == need:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     if len(devs) < need:
         raise RuntimeError(f"need {need} devices, have {len(devs)} — "
                            "set XLA_FLAGS=--xla_force_host_platform_device_count")
@@ -30,4 +31,5 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 def make_local_mesh() -> Mesh:
     """Degenerate 1x1 mesh with production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

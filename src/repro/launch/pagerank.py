@@ -25,8 +25,14 @@ Engine selection (`--algo`):
 histograms, count reductions) through the Pallas kernels in
 `repro.kernels` — interpret mode on CPU, compiled on TPU. The kernels
 share decision logic and uniforms with the jnp fallbacks, so results are
-bit-identical either way; the REPRO_USE_PALLAS env var is the flagless
-default (the `counts` engine takes only the env var).
+bit-identical either way in interpret mode; the REPRO_USE_PALLAS env var
+is the flagless default. On TPU `walk_step` does not compile, so engines
+that step single walks (walks, ppr, the 3-phase tail) raise there under
+`--use-pallas` rather than fall back.
+
+`main` keeps JAX's persistent compilation cache in
+`JAX_COMPILATION_CACHE_DIR`, or in `.jax_cache/` at the checkout's root
+(`repro.launch.compile_cache`).
 
 Fault tolerance applies to EVERY engine: `--checkpoint-dir` enables
 periodic snapshots, `--fail-at R [R ...]` injects simulated failures at
@@ -139,7 +145,10 @@ resized mesh, so their resume is statistical (tolerance-gated).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import tempfile
+import time
+from typing import Optional
 
 import jax
 import numpy as np
@@ -153,29 +162,48 @@ from repro.core.distributed import (AXIS, DistState, _make_superstep,
 from repro.core.distributed_counts import distributed_pagerank_counts
 from repro.core.distributed_directed import distributed_directed_pagerank
 from repro.core.distributed_improved import distributed_improved_pagerank
+from repro.core.graph import CSRGraph
 from repro.graphs import GENERATORS
+from repro.kernels import resolve_use_pallas
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import FailureSchedule, Supervisor
 
 import jax.numpy as jnp
 
+# the --check accuracy gate against the plain reference
+L1_TOL = 0.15
+TOPK_MIN = 0.6
+
+
+@dataclasses.dataclass
+class RunResult:
+    pi: np.ndarray             # rank vector; [queries, n] estimates for ppr
+    engine: object = None      # the engine's own result and telemetry
+                               # (None for walks and ppr)
+    accuracy: Optional[dict] = None   # vs power iteration (not for ppr)
+
 
 def _report_accuracy(pi, g, eps: float, check: bool = False,
-                     l1_tol: float = 0.15, topk_min: float = 0.6) -> None:
+                     l1_tol: float = L1_TOL,
+                     topk_min: float = TOPK_MIN) -> dict:
     pi = np.asarray(pi, dtype=np.float64)
-    pi_ref, _, _ = power_iteration(g, eps)
+    t0 = time.perf_counter()
+    pi_ref, _, iters = power_iteration(g, eps)
+    seconds = time.perf_counter() - t0
     l1 = l1_error(pi / pi.sum(), pi_ref)
     topk = topk_overlap(pi, np.asarray(pi_ref))
     print(f"[pagerank] L1 vs power-iter: {l1:.4f}  "
-          f"top-10 overlap: {topk:.2f}")
+          f"top-10 overlap: {topk:.2f}  (power iteration: {iters} iters)")
     if check and (l1 >= l1_tol or topk < topk_min):
         raise SystemExit(
             f"[pagerank] accuracy check FAILED: L1 {l1:.4f} "
             f"(tol {l1_tol}) top-10 {topk:.2f} (min {topk_min})")
+    return dict(l1=l1, topk=topk, iters=iters, seconds=seconds)
 
 
 def run_walks(g, eps: float, walks_per_node: int, checkpoint_dir,
               fail_at, seed: int, resume: bool = False,
-              use_pallas: bool = False, mesh=None,
+              use_pallas: Optional[bool] = None, mesh=None,
               max_restarts: int = 16):
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()), (AXIS,))
@@ -203,7 +231,7 @@ def run_walks(g, eps: float, walks_per_node: int, checkpoint_dir,
     rp, ci, dg = (jax.device_put(x, spec)
                   for x in (sg.row_ptr, sg.col_idx, sg.out_deg))
     step = _make_superstep(mesh, eps, sg.n_loc, shards, route_cap, 0,
-                           use_pallas=use_pallas)
+                           use_pallas=resolve_use_pallas(use_pallas))
 
     def step_fn(s):
         s2, active, _, _ = step(rp, ci, dg, s)
@@ -229,8 +257,9 @@ def run_walks(g, eps: float, walks_per_node: int, checkpoint_dir,
 
 
 def run_ppr(g, eps: float, walks_per_query: int, num_queries: int,
-            seed: int, check: bool = False, use_pallas: bool = False,
-            l1_tol: float = 0.15, topk_min: float = 0.6, mesh=None):
+            seed: int, check: bool = False,
+            use_pallas: Optional[bool] = None, l1_tol: float = L1_TOL,
+            topk_min: float = TOPK_MIN, mesh=None):
     """Batched PPR: seed-derived multi-source queries, one shared engine.
 
     Validates each query against its OWN `exact_ppr` oracle — PPR has no
@@ -248,7 +277,7 @@ def run_ppr(g, eps: float, walks_per_query: int, num_queries: int,
         queries.append((sources, None))
     res = batched_personalized_pagerank(
         g, eps, queries, walks_per_query, jax.random.PRNGKey(seed),
-        mesh=mesh, use_pallas=use_pallas or None)
+        mesh=mesh, use_pallas=use_pallas)
     peak = max(res.active_trace) if res.active_trace else 0
     print(f"[pagerank] algo=ppr n={g.n} shards={res.shards} "
           f"queries={num_queries} walks/query={walks_per_query} "
@@ -277,9 +306,13 @@ def run_ppr(g, eps: float, walks_per_query: int, num_queries: int,
 def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
         checkpoint_dir: str | None, fail_at: list[int], seed: int = 0,
         algo: str = "walks", avg_deg: float = 6.0, resume: bool = False,
-        check: bool = False, use_pallas: bool = False,
+        check: bool = False, use_pallas: Optional[bool] = None,
         num_queries: int = 4, shards: int | None = None,
-        max_restarts: int = 16):
+        max_restarts: int = 16,
+        graph: Optional[CSRGraph] = None) -> RunResult:
+    """One run of `algo` on a `graph_kind` graph made from `seed` (or on
+    `graph`, when given, in place of generating one). `use_pallas=None`
+    defers to REPRO_USE_PALLAS; an explicit True/False wins."""
     if resume and not checkpoint_dir:
         raise SystemExit("[pagerank] --resume needs --checkpoint-dir "
                          "(there is no snapshot to cold-start from)")
@@ -290,13 +323,19 @@ def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
             raise SystemExit(f"[pagerank] --shards {shards} out of range: "
                              f"{len(devs)} devices available")
         mesh = Mesh(np.array(devs[:shards]), (AXIS,))
-    g = GENERATORS[graph_kind](n, avg_deg, seed) if graph_kind != "ring" \
-        else GENERATORS[graph_kind](n)
+    if graph is not None:
+        g = graph
+    elif graph_kind == "ring":
+        g = GENERATORS[graph_kind](n)
+    else:
+        g = GENERATORS[graph_kind](n, avg_deg, seed)
+    res = None
     if algo == "ppr":
         # PPR validates per-query vs exact_ppr inside run_ppr; the
         # power-iteration report below does not apply to it
-        return run_ppr(g, eps, walks_per_node * g.n, num_queries, seed,
-                       check=check, use_pallas=use_pallas, mesh=mesh)
+        return RunResult(pi=run_ppr(g, eps, walks_per_node * g.n,
+                                    num_queries, seed, check=check,
+                                    use_pallas=use_pallas, mesh=mesh))
     if algo == "walks":
         pi = run_walks(g, eps, walks_per_node, checkpoint_dir, fail_at,
                        seed, resume=resume, use_pallas=use_pallas,
@@ -305,8 +344,7 @@ def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
         res = distributed_pagerank_counts(
             g, eps, walks_per_node, jax.random.PRNGKey(seed), mesh=mesh,
             checkpoint_dir=checkpoint_dir, fail_at=fail_at, resume=resume,
-            max_restarts=max_restarts,
-            use_pallas=use_pallas or None)
+            max_restarts=max_restarts, use_pallas=use_pallas)
         print(f"[pagerank] algo=counts n={g.n} shards={res.shards} "
               f"rounds={res.rounds} restarts={res.restarts} "
               f"lane_cap={res.lane_cap} "
@@ -344,8 +382,8 @@ def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
         pi = res.pi
     else:
         raise ValueError(f"unknown algo {algo!r}")
-    _report_accuracy(pi, g, eps, check=check)
-    return pi
+    accuracy = _report_accuracy(pi, g, eps, check=check)
+    return RunResult(pi=pi, engine=res, accuracy=accuracy)
 
 
 def main():
@@ -398,6 +436,7 @@ def main():
                          "exits non-zero on any violation (see the module "
                          "docstring for the budget table)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.audit:
         import json
 
@@ -413,7 +452,8 @@ def main():
         return
     run(args.n, args.eps, args.walks, args.graph, args.checkpoint_dir,
         args.fail_at, seed=args.seed, algo=args.algo, avg_deg=args.avg_deg,
-        resume=args.resume, check=args.check, use_pallas=args.use_pallas,
+        resume=args.resume, check=args.check,
+        use_pallas=args.use_pallas or None,
         num_queries=args.queries, shards=args.shards,
         max_restarts=args.max_restarts)
 

@@ -7,6 +7,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -63,3 +65,21 @@ def test_packed_lanes_exact():
     """))
     assert r["equal"] is True            # packing is bit-exact
     assert 1.9 < r["ratio"] < 2.1        # exactly half the wire bytes
+
+
+@pytest.mark.parametrize("packed,n_loc,walks,want", [
+    (None, 1 << 16, 2 * 32767, True),
+    (None, (1 << 16) + 1, 100, False),     # local id past 16 bits
+    (None, 100, 2 * 32767 + 1, False),     # a count could spill twice
+    (False, 100, 100, False),
+    (True, 100, 100, True),
+])
+def test_resolve_packed_packs_only_where_exact(packed, n_loc, walks, want):
+    from repro.core.distributed_counts import resolve_packed
+    assert resolve_packed(packed, n_loc, walks) is want
+
+
+def test_resolve_packed_refuses_inexact_packing():
+    from repro.core.distributed_counts import resolve_packed
+    with pytest.raises(ValueError, match="packed count lanes"):
+        resolve_packed(True, 1 << 17, 100)

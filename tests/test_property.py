@@ -245,34 +245,37 @@ def test_bucket_permutation_is_a_bijection(degs):
            st.lists(st.integers(min_value=0, max_value=60),
                     min_size=s * 2, max_size=s * 8))))
 def test_bucketed_adjacency_roundtrips_flat_csr(case):
-    """The flat bucketed neighbor table is a pure re-layout: reading back
-    through the permutation reproduces each row's first deg slots of the
-    padded adjacency bit-exactly."""
+    """The flat bucketed neighbor table is a pure re-layout of the CSR:
+    reading back through the permutation reproduces each row's out-
+    neighbours in CSR order bit-exactly, and every other slot is 0.
+    Each bucket's block is slot-major: slot j of bucket row i sits at
+    j * cap + i."""
     from repro.core.aggregate_sampler import (build_layout_sharded,
-                                              bucketize_adjacency)
+                                              bucketize_csr)
     shards, degs = case
     n_loc = len(degs) // shards
     deg = np.asarray(degs[:n_loc * shards], np.int32).reshape(shards, n_loc)
     md = max(int(deg.max()), 1)
     rng = np.random.default_rng(0)
-    nbr = rng.integers(0, 1000, size=(shards, n_loc, md)).astype(np.int32)
-    for p in range(shards):
-        for r in range(n_loc):
-            nbr[p, r, deg[p, r]:] = 0          # padding slots
+    nbr = rng.integers(1, 1000, size=(shards, n_loc, md)).astype(np.int32)
+    row_ptr = np.concatenate([[0], np.cumsum(deg.reshape(-1))])
+    col = np.concatenate([nbr[p, r, :deg[p, r]] for p in range(shards)
+                          for r in range(n_loc)] + [np.zeros(0, np.int32)])
     layout, perm = build_layout_sharded(deg, md)
-    flat = bucketize_adjacency(nbr, perm, layout)
+    flat = bucketize_csr(row_ptr, col, deg, perm, layout)
     assert flat.shape == (shards, layout.total_edges)
     s_rows, s_edges = 0, 0
     for cap, w in zip(layout.caps, layout.widths):
         for p in range(shards):
             for i in range(cap):
                 r = perm[p, s_rows + i]
-                blk = flat[p, s_edges + i * w: s_edges + (i + 1) * w]
+                blk = flat[p, s_edges + i: s_edges + cap * w: cap]
                 if r < 0:
                     np.testing.assert_array_equal(blk, 0)
                 else:
                     d = deg[p, r]
                     np.testing.assert_array_equal(blk[:d], nbr[p, r, :d])
+                    np.testing.assert_array_equal(blk[d:], 0)
         s_rows += cap
         s_edges += cap * w
 
