@@ -35,12 +35,13 @@ a hub-heavy `barabasi_albert_hub` graph (forced hub of degree ~n/4 next
 to a median degree of ~3) twice — with the degree-bucketed aggregate
 sampler (the default) and with `bucketed=False` (the pre-bucketing
 single-bucket layout, same code path) — and the row reports both warm
-wall times AND both engines' `sampler_us` telemetry (wall microseconds
-inside the sample program alone), plus the per-bucket occupancy. The
-draws are bit-identical across the two layouts (counter RNG), so the
-`sampler_speedup` column isolates exactly the O(max_deg) -> O(bucket
-width) chain-scan win the bucketing exists for; on hub-heavy graphs it
-should be >= 2x.
+wall times, the improved engine's `sampler_us` telemetry (wall
+microseconds inside its Phase-1 sample program alone), and the per-bucket
+occupancy. The draws are bit-identical across the two layouts (counter
+RNG), so the improved engine's `sampler_speedup` column isolates exactly
+the O(max_deg) -> O(bucket width) chain-scan win the bucketing exists
+for; on hub-heavy graphs it should be >= 2x. The counts engine's rows
+compare warm wall times only (`flat_us`).
 
 `--json [PATH]` additionally writes the raw rows to a machine-readable
 artifact (default BENCH_distributed.json) so the perf trajectory can be
@@ -123,8 +124,9 @@ out.append(dict(K=K, shards=rd.shards, directed=True,
                 dir_budget=rd.uniform_budget, dir_dropped=rd.dropped))
 
 # Power-law hub stress (8-shard leg): bucketed vs flat sampler layout.
-# Same keys -> bit-identical trajectories, so the sampler_us delta is
-# pure layout (O(max_deg) chain scan vs O(bucket width)).
+# Same keys -> bit-identical trajectories, so the wall-time and
+# sampler_us deltas are pure layout (O(max_deg) chain scan vs O(bucket
+# width)).
 if jax.device_count() >= 8:
     gh = barabasi_albert_hub(1024, 3, seed=7)
     K = 100
@@ -142,8 +144,6 @@ if jax.device_count() >= 8:
         K=K, shards=rb.shards, powerlaw=True, n=gh.n,
         max_deg=int(max(gh.out_deg)),
         count_us=tb, count_cold_us=cb, count_flat_us=tf,
-        count_sampler_us=rb.sampler_us,
-        count_flat_sampler_us=rf.sampler_us,
         count_rounds=rb.rounds, count_occupancy=list(rb.occupancy),
         count_dropped=rb.overflow + rf.overflow
         + abs(rb.residual) + abs(rf.residual),
@@ -189,17 +189,12 @@ def report(rows):
             continue
         p, k = r["shards"], r["K"]
         if r.get("powerlaw"):
-            c_spd = (r["count_flat_sampler_us"]
-                     / max(r["count_sampler_us"], 1.0))
             i_spd = (r["imp_flat_sampler_us"]
                      / max(r["imp_sampler_us"], 1.0))
             print(f"dist_hubcount_P{p}_K{k},{r['count_us']:.0f},"
                   f"cold_us={r['count_cold_us']:.0f};"
                   f"flat_us={r['count_flat_us']:.0f};"
                   f"rounds={r['count_rounds']};"
-                  f"sampler_us={r['count_sampler_us']:.0f};"
-                  f"flat_sampler_us={r['count_flat_sampler_us']:.0f};"
-                  f"sampler_speedup={c_spd:.2f}x;"
                   f"max_deg={r['max_deg']};"
                   f"occupancy={r['count_occupancy']};"
                   f"dropped={r['count_dropped']}")

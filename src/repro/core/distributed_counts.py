@@ -30,16 +30,22 @@ function of (per-round key words, global row id = padded vertex id, slot
 index) — see `kernels/multinomial_rows/_math` — so rows sample
 independently of bucket order and blocking, `use_pallas` (kernel vs jnp
 ref) never changes the draws, and checkpoint replay stays bit-exact.
-The super-step is two jitted programs, sample then exchange, so the
-driver can clock the sampler separately: per-round sampler microseconds
-and per-bucket occupancy land in the host telemetry dict next to the
-wire counters (`sampler_us`, `occupancy`).
+The super-step is two jitted programs, sample then exchange, dispatched
+back to back with one host sync per round (the `device_get` of the
+round's counters); a profiler trace times each on the device
+(`jit_sample`, `jit_exchange`). Per-bucket occupancy lands in the host
+telemetry dict next to the wire counters (`occupancy`).
+
+A job is one `counts.job` span of `runtime.tracing` (count `rounds`):
+`counts.build` (host build and placement), one `round.counts` per round
+holding `counts.sample`, `counts.exchange` (the two dispatches) and
+`counts.sync` (count `active`, walks alive after the round), then
+`counts.finish`.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from functools import lru_cache, partial
 from typing import Optional, Sequence
 
@@ -59,6 +65,7 @@ from repro.checkpoint import LayoutSpec
 from repro.kernels import resolve_use_pallas
 from repro.kernels.multinomial_rows._math import key_words
 from repro.runtime import Stage, StagedState, StageSchedule, run_staged
+from repro.runtime import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,9 +145,8 @@ def _sample_step(bperm, deg, counts, key, *, eps: float, n_loc: int,
                  shards: int, layout: BucketLayout, use_pallas: bool):
     """Program 1 of the super-step: the degree-bucketed aggregate draw.
 
-    Pure per-shard compute (no collectives beyond the telemetry psums), so
-    the driver can clock it separately — its wall time is the engine's
-    `sampler_us` telemetry. Returns the flat per-edge counts aligned with
+    Pure per-shard compute (no collectives beyond the telemetry psums).
+    Returns the flat per-edge counts aligned with
     `ShardedPaddedGraph.bnbr`, the advanced key, global per-bucket
     occupancy, and the (must-be-zero) conservation residual.
     """
@@ -256,7 +262,8 @@ def make_count_superstep(mesh: Mesh, eps: float, *, n_loc: int, shards: int,
                          layout: BucketLayout, lane_cap: int,
                          packed: bool = True, use_pallas: bool = False):
     """Returns (sample, exchange): the two jitted halves of the super-step.
-    The driver times `sample` (block_until_ready) for `sampler_us`."""
+    Their names are what a profiler trace calls them (`jit_sample`,
+    `jit_exchange`)."""
     sample_sh = shard_map(
         partial(_sample_step, eps=eps, n_loc=n_loc, shards=shards,
                 layout=layout, use_pallas=use_pallas),
@@ -308,7 +315,6 @@ class CountDistResult:
     a2a_entries_total: int = 0   # routed (vertex, count) lane entries
     restarts: int = 0            # supervisor recoveries (fault injection)
     checkpoints_written: int = 0
-    sampler_us: float = 0.0      # total wall time inside the sample program
     occupancy: tuple = ()        # per-bucket rows-with-coupons, summed over
                                  # rounds and shards (len = #buckets)
     residual: int = 0            # conservation leak — must stay 0
@@ -348,82 +354,88 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
         mesh = Mesh(np.array(jax.devices()), (AXIS,))
     use_pallas = resolve_use_pallas(use_pallas)
     shards = mesh.devices.size
-    sg = shard_graph_padded(graph, shards, bucketed=bucketed)
-    packed = resolve_packed(packed, sg.n_loc, graph.n * walks_per_node)
-    spec = NamedSharding(mesh, P(AXIS))
+    with tracing.span("counts.job"):
+        with tracing.span("counts.build"):
+            sg = shard_graph_padded(graph, shards, bucketed=bucketed)
+            packed = resolve_packed(packed, sg.n_loc,
+                                    graph.n * walks_per_node)
+            spec = NamedSharding(mesh, P(AXIS))
+            counts0 = np.zeros((shards, sg.n_loc), np.int32)
+            counts0.reshape(-1)[: graph.n] = walks_per_node
+            # REPLICATED round key: every shard splits the same stream,
+            # draws are distinguished only by the counter-based global
+            # vertex id — so the trajectory is a pure function of
+            # (seed, graph), not the mesh size
+            keys = np.tile(np.asarray(key)[None], (shards, 1))
+            deg = jax.device_put(sg.deg, spec)
+            bperm = jax.device_put(sg.bperm, spec)
+            bnbr = jax.device_put(sg.bnbr, spec)
+            arrays = dict(counts=jax.device_put(counts0, spec),
+                          zeta=jax.device_put(counts0, spec),
+                          key=jax.device_put(keys, spec),
+                          round=jnp.int32(0))
+            sample, exchange = make_count_superstep(
+                mesh, float(eps), n_loc=sg.n_loc, shards=sg.shards,
+                layout=sg.layout, lane_cap=sg.lane_cap, packed=packed,
+                use_pallas=use_pallas)
 
-    counts0 = np.zeros((shards, sg.n_loc), np.int32)
-    counts0.reshape(-1)[: graph.n] = walks_per_node
-    # REPLICATED round key: every shard splits the same stream, draws are
-    # distinguished only by the counter-based global vertex id — so the
-    # trajectory is a pure function of (seed, graph), not the mesh size
-    keys = np.tile(np.asarray(key)[None], (shards, 1))
-    deg = jax.device_put(sg.deg, spec)
-    bperm = jax.device_put(sg.bperm, spec)
-    bnbr = jax.device_put(sg.bnbr, spec)
+        def _step(ms: StagedState):
+            a = ms.arrays
+            st = CountDistState(counts=a["counts"], zeta=a["zeta"],
+                                key=a["key"], round=a["round"])
+            with tracing.span("counts.sample"):
+                flat_T, key2, occ, residual = sample(bperm, deg, st)
+            with tracing.span("counts.exchange"):
+                st, active, entries, a2a, ovf = exchange(bnbr, flat_T, key2,
+                                                         st)
+            a.update(counts=st.counts, zeta=st.zeta, key=st.key,
+                     round=st.round)
+            with tracing.span("counts.sync"):
+                active_i, entries_i, a2a_i, ovf_i, occ_v, res_i = \
+                    jax.device_get((active, entries, a2a, ovf, occ,
+                                    residual))
+                tracing.count("active", int(active_i))
+            h = ms.host
+            h["rounds"] += 1
+            h["a2a"] += int(a2a_i)
+            h["a2a_entries"] += int(entries_i)
+            h["overflow"] += int(ovf_i)
+            h["occupancy"] = [int(x) + int(y)
+                              for x, y in zip(h["occupancy"], occ_v)]
+            h["residual"] += int(res_i)
+            return ms, int(active_i) == 0 or h["rounds"] >= max_rounds
 
-    sample, exchange = make_count_superstep(
-        mesh, float(eps), n_loc=sg.n_loc, shards=sg.shards,
-        layout=sg.layout, lane_cap=sg.lane_cap, packed=packed,
-        use_pallas=use_pallas)
+        schedule = StageSchedule([Stage("counts", _step)])
+        ms = StagedState(
+            stage=schedule.first_stage, arrays=arrays,
+            host=dict(rounds=0, a2a=0, a2a_entries=0, overflow=0,
+                      occupancy=[0] * len(sg.layout.caps), residual=0),
+            layouts={"counts": _count_layouts(graph.n)},
+            shards=shards)
 
-    def _step(ms: StagedState):
-        a = ms.arrays
-        st = CountDistState(counts=a["counts"], zeta=a["zeta"],
-                            key=a["key"], round=a["round"])
-        t0 = time.perf_counter()
-        flat_T, key2, occ, residual = sample(bperm, deg, st)
-        jax.block_until_ready(flat_T)
-        t1 = time.perf_counter()
-        st, active, entries, a2a, ovf = exchange(bnbr, flat_T, key2, st)
-        a.update(counts=st.counts, zeta=st.zeta, key=st.key, round=st.round)
+        def _put(name, arr):
+            return (jnp.asarray(arr) if name == "round"
+                    else jax.device_put(np.asarray(arr), spec))
+
+        ms, restarts, checkpoints_written = run_staged(
+            schedule, ms, _put, checkpoint_dir=checkpoint_dir,
+            fail_at=fail_at, checkpoint_every=checkpoint_every,
+            max_restarts=max_restarts, resume=resume,
+            max_rounds=max_rounds + 1, tmp_prefix="prcnt_ckpt_")
         h = ms.host
-        active_i, entries_i, a2a_i, ovf_i, occ_v, res_i = jax.device_get(
-            (active, entries, a2a, ovf, occ, residual))
-        h["rounds"] += 1
-        h["a2a"] += int(a2a_i)
-        h["a2a_entries"] += int(entries_i)
-        h["overflow"] += int(ovf_i)
-        h["sampler_us"] += (t1 - t0) * 1e6
-        h["occupancy"] = [int(x) + int(y)
-                          for x, y in zip(h["occupancy"], occ_v)]
-        h["residual"] += int(res_i)
-        return ms, int(active_i) == 0 or h["rounds"] >= max_rounds
+        tracing.count("rounds", h["rounds"])
 
-    schedule = StageSchedule([Stage("counts", _step)])
-    ms = StagedState(
-        stage=schedule.first_stage,
-        arrays=dict(counts=jax.device_put(counts0, spec),
-                    zeta=jax.device_put(counts0, spec),
-                    key=jax.device_put(keys, spec),
-                    round=jnp.int32(0)),
-        host=dict(rounds=0, a2a=0, a2a_entries=0, overflow=0, sampler_us=0.0,
-                  occupancy=[0] * len(sg.layout.caps), residual=0),
-        layouts={"counts": _count_layouts(graph.n)},
-        shards=shards)
-
-    def _put(name, arr):
-        return (jnp.asarray(arr) if name == "round"
-                else jax.device_put(np.asarray(arr), spec))
-
-    ms, restarts, checkpoints_written = run_staged(
-        schedule, ms, _put, checkpoint_dir=checkpoint_dir, fail_at=fail_at,
-        checkpoint_every=checkpoint_every, max_restarts=max_restarts,
-        resume=resume, max_rounds=max_rounds + 1,
-        tmp_prefix="prcnt_ckpt_")
-
-    zeta = ms.arrays["zeta"].reshape(-1)[: graph.n]
-    pi = pagerank_from_visits(zeta, graph.n, walks_per_node, eps)
-    return CountDistResult(zeta=zeta, pi=pi, rounds=ms.host["rounds"],
-                           a2a_bytes_total=ms.host["a2a"],
-                           overflow=ms.host["overflow"], shards=shards,
-                           lane_cap=sg.lane_cap,
-                           a2a_entries_total=ms.host["a2a_entries"],
-                           restarts=restarts,
-                           checkpoints_written=checkpoints_written,
-                           sampler_us=float(ms.host["sampler_us"]),
-                           occupancy=tuple(ms.host["occupancy"]),
-                           residual=int(ms.host["residual"]))
+        with tracing.span("counts.finish"):
+            zeta = ms.arrays["zeta"].reshape(-1)[: graph.n]
+            pi = pagerank_from_visits(zeta, graph.n, walks_per_node, eps)
+            return CountDistResult(
+                zeta=zeta, pi=pi, rounds=h["rounds"],
+                a2a_bytes_total=h["a2a"], overflow=h["overflow"],
+                shards=shards, lane_cap=sg.lane_cap,
+                a2a_entries_total=h["a2a_entries"], restarts=restarts,
+                checkpoints_written=checkpoints_written,
+                occupancy=tuple(h["occupancy"]),
+                residual=int(h["residual"]))
 
 
 def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,

@@ -166,7 +166,7 @@ from repro.core.graph import CSRGraph
 from repro.graphs import GENERATORS
 from repro.kernels import resolve_use_pallas
 from repro.launch.compile_cache import enable_compile_cache
-from repro.runtime import FailureSchedule, Supervisor
+from repro.runtime import FailureSchedule, Supervisor, tracing
 
 import jax.numpy as jnp
 
@@ -303,6 +303,20 @@ def run_ppr(g, eps: float, walks_per_query: int, num_queries: int,
     return res.ppr
 
 
+def _span_totals(records) -> str:
+    """Count and mean milliseconds of each span name inside the newest
+    `counts.job` span, in the order the names first open."""
+    job = [r for r in records if r.name == "counts.job"][-1]
+    inside, totals = {job.id}, {}
+    for r in records:          # recorded as they open: parents first
+        if r.parent in inside:
+            inside.add(r.id)
+            k, s = totals.get(r.name, (0, 0.0))
+            totals[r.name] = (k + 1, s + r.seconds)
+    return ", ".join(f"{name} {k} x {1e3 * s / k:.3f} ms"
+                     for name, (k, s) in totals.items())
+
+
 def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
         checkpoint_dir: str | None, fail_at: list[int], seed: int = 0,
         algo: str = "walks", avg_deg: float = 6.0, resume: bool = False,
@@ -349,8 +363,7 @@ def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
               f"rounds={res.rounds} restarts={res.restarts} "
               f"lane_cap={res.lane_cap} "
               f"a2a_bytes={res.a2a_bytes_total} overflow={res.overflow}")
-        print(f"[pagerank] sampler: {res.sampler_us:.0f} us total "
-              f"({res.sampler_us / max(res.rounds, 1):.0f} us/round) "
+        print(f"[pagerank] spans: {_span_totals(tracing.spans())}; "
               f"bucket_occupancy={list(res.occupancy)} "
               f"residual={res.residual}")
         pi = res.pi

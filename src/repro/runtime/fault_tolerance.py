@@ -1,4 +1,4 @@
-"""Fault-tolerance harness: checkpoint/restart, failure injection, heartbeats.
+"""Fault-tolerance harness: checkpoint/restart, failure injection.
 
 The supervisor wraps any step-function-driven engine (the distributed
 PageRank super-step loop, or the training loop) with:
@@ -9,11 +9,10 @@ PageRank super-step loop, or the training loop) with:
   * restart-from-latest-checkpoint recovery. Because engine state is a pure
     pytree that includes the PRNG keys, recovery replays the *identical*
     trajectory — the recovered run is bit-exact with an uninterrupted one
-    (asserted in tests),
-  * a heartbeat/straggler monitor: per-round wall-times are tracked and
-    rounds slower than `straggler_factor` × running median are flagged.
-    (Real deployments feed these flags into the engine's `work_cap`
-    rebalancing — here they are surfaced as stats.)
+    (asserted in tests).
+
+Every round that `run_staged` or the supervisor drives is one span of
+`runtime.tracing` (`round_span`): the per-round timer.
 
 Multi-stage schedules: engines whose run is a *sequence of named phases*
 with different step functions and different device buffers per phase (the
@@ -40,13 +39,13 @@ from __future__ import annotations
 import dataclasses
 import shutil
 import tempfile
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.checkpoint import (Checkpointer, pack_json, relayout_staged_flat,
                               unpack_json)
+from repro.runtime import tracing
 
 
 class SimulatedFailure(RuntimeError):
@@ -64,20 +63,6 @@ class FailureSchedule:
         if round_idx in self.fail_at_rounds and round_idx not in self._fired:
             self._fired.add(round_idx)
             raise SimulatedFailure(f"injected failure at round {round_idx}")
-
-
-@dataclasses.dataclass
-class Heartbeat:
-    straggler_factor: float = 3.0
-    times: List[float] = dataclasses.field(default_factory=list)
-    stragglers: List[int] = dataclasses.field(default_factory=list)
-
-    def record(self, round_idx: int, dt: float):
-        self.times.append(dt)
-        if len(self.times) >= 5:
-            med = float(np.median(self.times))
-            if dt > self.straggler_factor * med:
-                self.stragglers.append(round_idx)
 
 
 @dataclasses.dataclass
@@ -167,6 +152,13 @@ class StageSchedule:
         return state, False
 
 
+def round_span(state: Any):
+    """The span of one round: `round.<stage>` for a `StagedState` (named
+    by the stage the round starts in), `round.step` otherwise."""
+    stage = state.stage if isinstance(state, StagedState) else "step"
+    return tracing.span("round." + stage)
+
+
 def staged_to_host(state: StagedState) -> dict:
     """Checkpoint payload for a `StagedState`: a pure pytree of arrays —
     device buffers as-is, stage tag + host accumulators as JSON leaves."""
@@ -197,7 +189,6 @@ class SupervisorResult:
     rounds: int
     restarts: int
     checkpoints_written: int
-    stragglers: List[int]
 
 
 class Supervisor:
@@ -233,7 +224,6 @@ class Supervisor:
         self.failures = failure_schedule
         self.meta_fn = meta_fn
         self.relayout = relayout
-        self.heartbeat = Heartbeat()
 
     def _meta(self) -> dict:
         return self.meta_fn() if self.meta_fn is not None else {}
@@ -285,11 +275,11 @@ class Supervisor:
                            blocking=True)
             ckpts += 1
         while round_idx < max_rounds:
-            t0 = time.perf_counter()
             try:
                 if self.failures is not None:
                     self.failures.maybe_fail(round_idx)
-                state, done = self.step_fn(state)
+                with round_span(state):
+                    state, done = self.step_fn(state)
                 round_idx += 1
             except SimulatedFailure:
                 restarts += 1
@@ -299,7 +289,6 @@ class Supervisor:
                 state = self.from_host(flat)
                 round_idx = int(manifest["step"])
                 continue
-            self.heartbeat.record(round_idx, time.perf_counter() - t0)
             # always snapshot on `done` — a run finishing between periodic
             # intervals must still leave the directory reflecting its
             # final state (blocking: nothing overlaps a finished run)
@@ -312,8 +301,7 @@ class Supervisor:
                 break
         self.ckpt.wait()
         return SupervisorResult(state=state, rounds=round_idx, restarts=restarts,
-                                checkpoints_written=ckpts,
-                                stragglers=self.heartbeat.stragglers)
+                                checkpoints_written=ckpts)
 
 
 def run_staged(schedule: StageSchedule, state: StagedState,
@@ -340,7 +328,8 @@ def run_staged(schedule: StageSchedule, state: StagedState,
         rounds = 0
         done = False
         while not done and rounds < max_rounds:   # same bound as Supervisor
-            state, done = schedule.step(state)
+            with round_span(state):
+                state, done = schedule.step(state)
             rounds += 1
         return state, 0, 0
     # fail_at without a caller dir: snapshots go to a private temp dir the
