@@ -1,21 +1,25 @@
 """Degree-bucketed aggregate multinomial sampler — the shared compute core
 of every count-moving engine.
 
-Problem: the conditional-binomial chain that splits an aggregate coupon
-count over a vertex's out-edges is a scan whose width used to be the
-GLOBAL max degree, so on power-law graphs one hub made every low-degree
-vertex pay hub cost: per-round sampler FLOPs were n * max_deg.
+Problem: the split of an aggregate coupon count over a vertex's out-edges
+used to span the GLOBAL max degree, so on power-law graphs one hub made
+every low-degree vertex pay hub cost: per-round sampler FLOPs were
+n * max_deg.
 
 Fix: group rows by power-of-two degree buckets. Bucket b holds rows with
-degree in (2^(b-1), 2^b] (bucket 0: degree 0 and 1) and scans width
-min(2^b, max_deg) <= 2 * degree, so the per-round FLOPs drop to
-sum_v O(deg(v)) — per-node work proportional to local degree, the
+degree in (2^(b-1), 2^b] (bucket 0: degree 0 and 1) and splits over
+width min(2^b, max_deg) <= 2 * degree slots, so the per-round FLOPs drop
+to sum_v O(deg(v)) — per-node work proportional to local degree, the
 property the paper's CONGEST model assumes. The grouping is a STATIC
 permutation computed on the host at shard/build time and memoized (like
-the engines' step makers); the per-round work is a python loop over the
-O(log max_deg) buckets, each a single `kernels.multinomial_rows` call
-(Pallas kernel or its jnp ref — same counter-RNG math, so `use_pallas`
-never changes the draws).
+the engines' step makers).
+
+The split is the binomial tree of `kernels/multinomial_rows/_math`. The
+XLA path runs each level of it once for all buckets
+(`_math.split_tree`), so a round is `BucketLayout.depth` =
+ceil(log2 max_deg) sequential levels; the Pallas path calls the
+`kernels.multinomial_rows` kernel once per bucket. Both draw the same
+counter-RNG tree, so `use_pallas` never changes the draws.
 
 Sharded engines run ONE traced program on every shard, so bucket
 capacities must be shard-uniform: `build_layout_sharded` takes the max
@@ -24,7 +28,7 @@ row count per bucket over shards and pads each shard's permutation with
 
 `bucketed=False` (the pre-PR shape, kept for benchmarking and as the
 degenerate fallback) is the SAME machinery with a single bucket of width
-max_deg — one code path, two layouts.
+max_deg — one code path, two layouts, the same draws.
 """
 from __future__ import annotations
 
@@ -36,14 +40,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.multinomial_rows import multinomial_rows
-from repro.kernels.multinomial_rows.ref import multinomial_rows_ref
+from repro.kernels.multinomial_rows._math import (split_tree, termination,
+                                                  tree_depth)
 
 
 @dataclasses.dataclass(frozen=True)
 class BucketLayout:
     """Static (hashable) shape of a bucketed row grouping.
 
-    widths[b]: chain scan width of bucket b (min(2^b, max_deg)).
+    widths[b]: out-edge slots of bucket b (min(2^b, max_deg)).
     caps[b]:   row slots in bucket b (shard-uniform max; >= real rows).
     n_rows:    number of real rows the permutation indexes into.
     """
@@ -68,6 +73,12 @@ class BucketLayout:
             out.append(s)
             s += c
         return tuple(out)
+
+    @property
+    def depth(self) -> int:
+        """Sequential split levels a round on the XLA path: the deepest
+        bucket's tree, ceil(log2 max width)."""
+        return tree_depth(max(self.widths))
 
     def tile(self, copies: int) -> "BucketLayout":
         """Layout for `copies` stacked replicas of the same row set (the
@@ -191,23 +202,28 @@ def sample_buckets(counts, deg, rid, key_words, perm, layout: BucketLayout,
                   with T_b column 0 the termination count;
       occupancy — [n_buckets] int32, rows with a nonzero count per bucket;
       residual  — scalar int32, sum over rows of (count - T.sum()): 0 by
-                  construction (endpoint-exact chain), kept as a tripwire.
+                  construction (endpoint-exact splits), kept as a tripwire.
     """
-    fn = multinomial_rows if use_pallas else multinomial_rows_ref
-    n = counts.shape[0]
-    samples, occ, residual = [], [], jnp.int32(0)
-    for start, cap, w in zip(layout.row_starts, layout.caps, layout.widths):
-        rows_b = jnp.asarray(perm[start:start + cap])
-        ok = rows_b >= 0
-        safe = jnp.clip(rows_b, 0, n - 1)
-        c_b = jnp.where(ok, counts[safe], 0)
-        d_b = jnp.where(ok, deg[safe], 0)
-        r_b = jnp.where(ok, rid[safe], 0)
-        T_b = fn(c_b, d_b, r_b, key_words, eps=eps, width=w)
-        samples.append((rows_b, T_b))
-        occ.append(jnp.sum(c_b > 0))
-        residual = residual + jnp.sum(c_b) - jnp.sum(T_b)
-    return samples, jnp.stack(occ).astype(jnp.int32), residual
+    rows = jnp.asarray(perm)
+    ok = rows >= 0
+    safe = jnp.clip(rows, 0, counts.shape[0] - 1)
+    c, d, r = (jnp.where(ok, x[safe], 0) for x in (counts, deg, rid))
+    cut = lambda x: [x[s:s + cap] for s, cap in zip(layout.row_starts,
+                                                     layout.caps)]
+    if use_pallas:
+        Ts = [multinomial_rows(c_b, d_b, r_b, key_words, eps=eps, width=w)
+              for c_b, d_b, r_b, w in zip(cut(c), cut(d), cut(r),
+                                          layout.widths)]
+    else:
+        k0, k1 = key_words[0], key_words[1]
+        term, rem = termination(c, d, r, k0, k1, eps=eps)
+        slots = split_tree(cut(rem), cut(d), cut(r),
+                           [tree_depth(w) for w in layout.widths], k0, k1)
+        Ts = [jnp.concatenate([t[None, :], s[:w]], axis=0).T
+              for t, s, w in zip(cut(term), slots, layout.widths)]
+    occ = jnp.stack([jnp.sum(c_b > 0) for c_b in cut(c)]).astype(jnp.int32)
+    residual = jnp.sum(c) - sum(jnp.sum(T) for T in Ts)
+    return list(zip(cut(rows), Ts)), occ, residual
 
 
 def flatten_moves(samples) -> jnp.ndarray:

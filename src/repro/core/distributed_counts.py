@@ -14,7 +14,7 @@ overflow path at all (the walk engine needs waiting/carry-over logic).
 
 Per super-step, per shard:
   1. terminations  ~ Binomial(counts, eps)                (paper line 4-5)
-  2. survivors split over out-edges via the conditional-binomial chain
+  2. survivors split over out-edges via the binomial tree
      (exact Multinomial — same sampler as engine_counts)
   3. per-edge counts aggregated per destination *vertex* and exchanged with
      one all_to_all of (vertex, count) lanes               (Lemma 1 wire)
@@ -23,7 +23,7 @@ Per super-step, per shard:
 Steps 1-2 run through the shared degree-bucketed aggregate sampler
 (`core/aggregate_sampler`): rows are grouped by power-of-two degree
 buckets via a static permutation computed at shard time (memoized like
-the step makers), and each bucket's chain scans the bucket width instead
+the step makers), and each bucket's tree spans the bucket width instead
 of the global max degree — per-round sampler FLOPs ~ sum_v deg(v), not
 n_loc * max_deg. Sampler RNG contract: draws are a pure counter-based
 function of (per-round key words, global row id = padded vertex id, slot
@@ -37,7 +37,8 @@ round's counters); a profiler trace times each on the device
 telemetry dict next to the wire counters (`occupancy`).
 
 A job is one `counts.job` span of `runtime.tracing` (count `rounds`):
-`counts.build` (host build and placement), one `round.counts` per round
+`counts.build` (host build and placement; count `sampler_depth`, the
+sampler's sequential split levels a round), one `round.counts` per round
 holding `counts.sample`, `counts.exchange` (the two dispatches) and
 `counts.sync` (count `active`, walks alive after the round), then
 `counts.finish`.
@@ -357,6 +358,7 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
     with tracing.span("counts.job"):
         with tracing.span("counts.build"):
             sg = shard_graph_padded(graph, shards, bucketed=bucketed)
+            tracing.count("sampler_depth", sg.layout.depth)
             packed = resolve_packed(packed, sg.n_loc,
                                     graph.n * walks_per_node)
             spec = NamedSharding(mesh, P(AXIS))

@@ -23,12 +23,12 @@ Phase 1 — short-walk pre-computation. Shard p owns the coupons of its
     sample  — the owner draws, independently for every (home, vertex)
       row, a Binomial(c, eps) termination count (a dangling vertex
       terminates the whole row) and splits the survivors over the
-      out-edges with a conditional-binomial multinomial — the aggregate
+      out-edges with a binomial-tree multinomial — the aggregate
       of c iid walk steps, never c individual steps. The draws run
       through the shared degree-bucketed aggregate sampler
       (`core/aggregate_sampler`): rows grouped by power-of-two degree
-      buckets via a static shard-time permutation, each bucket's chain
-      scanning the bucket width instead of the global max degree, so
+      buckets via a static shard-time permutation, each bucket's tree
+      spanning the bucket width instead of the global max degree, so
       Phase-1 sampler FLOPs are ~ sum_v deg(v) per round. RNG contract:
       counter-based draws keyed on (round key words, globally-unique row
       id, slot) — see `kernels/multinomial_rows/_math` — so the results

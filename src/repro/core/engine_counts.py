@@ -3,16 +3,16 @@
 This engine materializes exactly the paper's CONGEST messages: per round,
 every vertex v holding c_v coupons draws terminations ~ Binomial(c_v, eps)
 and splits the survivors across its out-edges with a Multinomial (sampled as
-the conditional-binomial chain, vectorized over all vertices). The int
-matrix T[v, j] of per-edge counts *is* the message set of the round
-(Lemma 1: counts, never identities).
+a binomial tree over each row's out-edge slots, vectorized over all
+vertices). The int matrix T[v, j] of per-edge counts *is* the message set
+of the round (Lemma 1: counts, never identities).
 
 Slower than the walk-array engine but byte-for-byte faithful to the
 pseudocode — it is the reference for message accounting and for the
 engine-equivalence tests. The per-round splits run through the shared
-degree-bucketed aggregate sampler (`core/aggregate_sampler`): the
-conditional-binomial chain scans each row's power-of-two bucket width
-instead of the global max degree, so per-round sampler FLOPs are
+degree-bucketed aggregate sampler (`core/aggregate_sampler`): each
+row's split spans its power-of-two bucket width instead of the global
+max degree, so per-round sampler FLOPs are
 sum_v O(deg(v)) — hubs no longer tax every low-degree vertex.
 `use_pallas` routes the draws through the `kernels/multinomial_rows`
 Pallas kernel (same counter-RNG math as the jnp ref, so results are

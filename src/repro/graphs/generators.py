@@ -66,7 +66,7 @@ def barabasi_albert_hub(n: int, m_attach: int = 3, seed: int = 0) -> CSRGraph:
     """Preferential attachment plus a forced hub wired to every 4th vertex:
     max degree ~ n/4 while the median degree stays ~ m_attach. The
     max_deg >> typical_deg regime is what the degree-bucketed sampler
-    exists for (the flat chain pays O(max_deg) at EVERY vertex here), so
+    exists for (the flat layout pays O(max_deg) at EVERY vertex here), so
     this is the stress fixture for its tests and benchmarks."""
     base = barabasi_albert(n, m_attach, seed)
     src = np.repeat(np.arange(base.n), np.asarray(base.out_deg))

@@ -3,7 +3,7 @@
   histogram         — visit-count one-hot reduction (engine super-steps)
   segment_spmv      — one-hot-MXU CSR push (power-iteration baseline)
   walk_step         — fused terminate/select/advance walk step
-  multinomial_rows  — fused Binomial-termination + conditional-binomial
+  multinomial_rows  — fused Binomial-termination + binomial-tree
                       aggregate multinomial over a degree bucket
 
 Each subpackage: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd

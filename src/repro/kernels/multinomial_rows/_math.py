@@ -9,21 +9,33 @@ bit-identical across the flag (the repo-wide kernel contract, see
 RNG contract — counter-based, per row:
   u(row, t) = u01(fmix32(fmix32((rid * C1) ^ k0) + ((t * C2) ^ k1)))
 where `rid` is the caller-supplied globally-unique row id, `t` the draw
-index within the row (0 = the eps-termination draw, j+1 = chain slot j),
-and (k0, k1) the two uint32 words of a per-round PRNG key. Draws are pure
-functions of (k0, k1, rid, t): no split-chain threading, so rows sample
-independently in any blocking/order — exactly what a row-blocked kernel
-needs — and replay/checkpoint-recovery stays bit-exact.
+index within the row, and (k0, k1) the two uint32 words of a per-round
+PRNG key. t = 0 is the eps-termination draw; the split of the slot
+interval [i * 2^k, (i + 1) * 2^k) draws t = 32 * i + k (k >= 1), a
+function of the interval alone. Draws are pure functions of
+(k0, k1, rid, t): no split-chain threading, so rows sample independently
+in any blocking/order — exactly what a row-blocked kernel needs — and
+replay/checkpoint-recovery stays bit-exact.
+
+Survivors split over a row's out-edge slots by a binomial tree: the
+width is padded to a power of two and every dyadic interval sends
+Binomial(c, R / (L + R)) of its count c to its right half, with L and R
+the live slots (below the row's degree) in its left and right halves.
+That is an exact multinomial, uniform over the live slots, in
+ceil(log2 width) sequential levels. An interval with no live slot in its
+right half draws p == 0 exactly, so nothing lands beyond a row's degree,
+and the draws of a row do not depend on the width it is padded to.
 
 Binomial(n, p) from ONE uniform (hybrid, complement-flipped so pp <= 1/2):
   * n*pp <= 10 — BINV inverse-CDF walk (exact CDF inversion, truncated at
     `_BINV_ITERS`; the neglected tail mass is < 1e-15 at mean 10);
   * n*pp  > 10 — normal approximation with the Acklam inverse-normal.
 The endpoints are EXACT in integer arithmetic: p == 0 returns 0 and
-p == 1 returns n itself (never n routed through float32) — this is what
-makes the conditional-binomial chain conserve mass bit-exactly at any
-count magnitude, fixing the former `jax.random.binomial(k, c.astype(f32))`
-truncation for counts above 2**24 (see tests/test_sampler_precision.py).
+p == 1 returns n itself (never n routed through float32). Each split
+hands its right half r in [0, c] and its left half c - r, so the tree
+conserves mass bit-exactly at any count magnitude, fixing the former
+`jax.random.binomial(k, c.astype(f32))` truncation for counts above
+2**24 (see tests/test_sampler_precision.py).
 The normal branch evaluates means in float32, so marginals for counts
 beyond 2**24 carry a ~1e-7 relative mean error — statistical, never a
 conservation leak.
@@ -124,8 +136,9 @@ def binomial_counter(n, p, u):
         cdf = cdf + pdf
         return pdf, cdf, x
 
+    # unrolled: one fusion per call, not _BINV_ITERS passes over the carries
     _, _, x_small = jax.lax.fori_loop(1, _BINV_ITERS + 1, body,
-                                      (pdf0, pdf0, x0))
+                                      (pdf0, pdf0, x0), unroll=True)
 
     # --- normal approximation with continuity correction ---
     sd = jnp.sqrt(jnp.maximum(mean * (1.0 - pp), 1e-12))
@@ -148,30 +161,66 @@ def termination(counts, deg, rid, k0, k1, *, eps: float):
     return term, counts - term
 
 
-def chain_slot(rem, j, deg, rid, k0, k1):
-    """Slot j of the conditional-binomial chain: (rem - t, t) with `t` the
-    count sent down out-edge slot j of the `rem` survivors still unsplit."""
-    u = counter_u01(rid, j + 1, k0, k1)
-    slots = jnp.maximum(deg - j, 1).astype(jnp.float32)
-    p = jnp.where(j < deg, 1.0 / slots, 0.0)
-    t = jnp.minimum(binomial_counter(rem, p, u), rem)
-    return rem - t, t
+def tree_depth(width: int) -> int:
+    """Split levels of a row padded to `width` slots: ceil(log2 width)."""
+    return max(int(width) - 1, 0).bit_length()
+
+
+def split_level(c, deg, rid, node, k, k0, k1):
+    """Split the count `c` of slot interval [node * 2^k, (node + 1) * 2^k)
+    between its halves, uniformly over the live slots (those below
+    `deg`): (left, right) counts, left + right == c exactly."""
+    s = 1 << (k - 1)
+    lo = node * (2 * s)
+    live_l = jnp.clip(deg - lo, 0, s)
+    live_r = jnp.clip(deg - lo - s, 0, s)
+    p = (live_r.astype(jnp.float32)
+         / jnp.maximum(live_l + live_r, 1).astype(jnp.float32))
+    r = binomial_counter(c, p, counter_u01(rid, 32 * node + k, k0, k1))
+    return c - r, r
+
+
+def split_tree(rems, degs, rids, depths, k0, k1):
+    """Split each group's survivors over its 2^depth slots by the binomial
+    tree, running each level ONCE for every group deep enough to have it
+    (level k splits the intervals of size 2^k), so the sequential depth
+    is the deepest group's, not the sum over groups.
+
+    rems/degs/rids: per group [R_g] int32. Returns per group the slot
+    counts [2^depth, R_g] int32, slot-major.
+    """
+    nodes = [r[None, :] for r in rems]
+    flat = lambda xs: jnp.concatenate([x.reshape(-1) for x in xs])
+    grid = lambda x, g: jnp.broadcast_to(x, nodes[g].shape)
+    for k in range(max(depths, default=0), 0, -1):
+        live = [g for g, d in enumerate(depths) if d >= k]
+        index = lambda g: jnp.arange(nodes[g].shape[0], dtype=jnp.int32)
+        left, right = split_level(
+            flat([nodes[g] for g in live]),
+            flat([grid(degs[g], g) for g in live]),
+            flat([grid(rids[g], g) for g in live]),
+            flat([grid(index(g)[:, None], g) for g in live]), k, k0, k1)
+        off = 0
+        for g in live:
+            n, rows = nodes[g].shape
+            halves = [x[off:off + n * rows].reshape(n, rows)
+                      for x in (left, right)]
+            nodes[g] = jnp.stack(halves, axis=1).reshape(2 * n, rows)
+            off += n * rows
+    return nodes
 
 
 def sample_rows_math(counts, deg, rid, k0, k1, *, eps: float, width: int):
-    """Fused termination + conditional-binomial chain for a block of rows.
+    """Fused termination + binomial-tree split for a block of rows.
 
     counts/deg/rid: [R] int32. Returns T [R, width+1] int32 where column 0
     is the termination count and column 1+j the count sent down out-edge
     slot j. Rows with deg <= width conserve mass exactly: T.sum(1) ==
-    counts, because the last live slot draws p == 1 (endpoint-exact) and
-    every draw is clipped to [0, remaining].
+    counts, and nothing lands in a slot at or beyond the row's degree.
     """
-    deg = deg.astype(jnp.int32)
-    term, rem0 = termination(counts, deg, rid, k0, k1, eps=eps)
-    _, T = jax.lax.scan(lambda rem, j: chain_slot(rem, j, deg, rid, k0, k1),
-                        rem0, jnp.arange(width, dtype=jnp.int32))
-    return jnp.concatenate([term[:, None], T.T], axis=1)
+    term, rem = termination(counts, deg, rid, k0, k1, eps=eps)
+    (slots,) = split_tree([rem], [deg], [rid], [tree_depth(width)], k0, k1)
+    return jnp.concatenate([term[None, :], slots[:width]], axis=0).T
 
 
 def key_words(key):

@@ -8,8 +8,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.distributed_counts import distributed_pagerank_counts
-from repro.graphs import erdos_renyi
+from repro.core.distributed_counts import (distributed_pagerank_counts,
+                                           shard_graph_padded)
+from repro.graphs import barabasi_albert_hub, erdos_renyi
 from repro.runtime import tracing
 
 
@@ -119,6 +120,18 @@ def test_counts_job_records_build_rounds_and_finish():
     # everything but a few host statements of the job lies in its children
     covered = sum(r.end_ns - r.start_ns for r in kids)
     assert covered <= job.end_ns - job.start_ns
+
+
+def test_build_counts_the_sampler_depth():
+    """`counts.build` carries `sampler_depth`: the split levels a round
+    of the layout's deepest bucket, ceil(log2) of its widest bucket."""
+    g = barabasi_albert_hub(96, 3, seed=4)
+    distributed_pagerank_counts(g, 0.3, 4, jax.random.PRNGKey(4))
+    (build,) = _by_name(tracing.spans(), "counts.build")
+    layout = shard_graph_padded(g, jax.device_count()).layout
+    want = int(np.ceil(np.log2(max(layout.widths))))
+    assert build.counts == dict(sampler_depth=want)
+    assert want == int(np.ceil(np.log2(g.max_out_deg))) >= 4
 
 
 def test_supervised_rounds_are_spans_too(tmp_path):
