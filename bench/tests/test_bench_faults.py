@@ -14,7 +14,7 @@ import pytest
 
 import repro.core.distributed_counts as dc
 from bench import control, harness
-from bench.tests.tiny import tiny_root
+from bench.tests.tiny import cells, tiny_root
 
 SEED = 2**33 + 11
 
@@ -29,7 +29,7 @@ def _correct(root, cell):
     return out["correct"], out["checks"]
 
 
-@pytest.mark.parametrize("cell", ["batch.tiny"])
+@pytest.mark.parametrize("cell", cells())
 def test_sound_run_is_correct(root, cell):
     ok, checks = _correct(root, cell)
     assert ok, checks
@@ -80,6 +80,6 @@ def test_batch_control_and_faults_are_not_correct(root, monkeypatch, fault):
     real = dc.distributed_pagerank_counts
     monkeypatch.setattr(dc, "distributed_pagerank_counts",
                         lambda *a, **kw: fault(real, *a, **kw))
-    ok, checks = _correct(root, "batch.tiny")
+    ok, checks = _correct(root, "batch.g500")
     assert not ok, checks
 

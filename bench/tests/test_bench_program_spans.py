@@ -122,10 +122,11 @@ def root(tmp_path_factory):
 
 
 def test_tiny_cell_traced_reports_program_spans(root):
-    """A traced run reads the round driver's and the host build's spans
-    (a 1 s window ends before the traced session's offset, so all of
-    its jobs; the CPU has no device trace to read)."""
-    out = harness.run("batch.tiny", 7, 1.0, True, root=root,
+    """A traced run of `batch.g500` at its CPU-test size reads the round
+    driver's and the host build's spans (a 1 s window ends before the
+    traced session's offset, so all of its jobs; the CPU has no device
+    trace to read)."""
+    out = harness.run("batch.g500", 7, 1.0, True, root=root,
                       require_tpu=False)
     assert out["correct"], out["checks"]
     m = {k: v["value"] for k, v in out["metrics"].items()}

@@ -1,14 +1,19 @@
-"""A copy of the benchmark at CPU-test sizes, made only by adding files.
+"""A copy of the benchmark at CPU-test sizes, made from data alone.
 
-`tiny_root(dst)` copies `bench/` into `dst/bench`, writes a small
-configuration and traffic mix beside each real one (`<name>_tiny.json`)
-and a `BENCHMARK.json` whose cells run them: the same drivers, metrics
-and references, on graphs a CPU test can hold.
+Every configuration `bench/configs/<config>.json` has its CPU-test size
+in a file beside it, `bench/configs/<config>.tiny.json`:
 
-With fewer walks the sampling noise is wider than at the cells' own
-sizes, so the tiny configurations carry limits of their own, set like the
-real ones between the readings at this size: sound CPU runs read
-grouped_l1 up to 0.0103 (10 job keys), the control 0.037 (3 keys).
+    {"why": "<the readings its limits were set from>",
+     "overrides": {"graph.scale": 10, "limits": {...}, ...}}
+
+Each key of `overrides` is a dotted path to a key the configuration
+already has, and its value replaces that key's. `tiny_root(dst, src)`
+copies `bench/` and `BENCHMARK.json` from the tree `src` into `dst`,
+writes each configuration with its overrides applied as
+`<config>_tiny.json`, and points that configuration's `file` at it. Cell,
+configuration and traffic names stay as they are, so a test runs a cell
+by its own name: the same drivers, metrics and references, on graphs a
+CPU test can hold. A configuration joins by its files alone.
 """
 from __future__ import annotations
 
@@ -20,48 +25,63 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 
-TINY = {
-    "g500_batch_counts": dict(scale=10, limits=dict(grouped_l1=0.02),
-                              max_rounds=500),
-}
-CELLS = {"batch.g500": "batch.tiny"}
+
+class TinySizeError(ValueError):
+    """A configuration without its CPU-test size, or an override that
+    names no key of the configuration."""
 
 
-def tiny_config(name: str) -> dict:
-    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+def benchmark(root: str = ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def cells(root: str = ROOT) -> list:
+    """The names of the cells of `<root>/BENCHMARK.json`."""
+    return [w["name"] for w in benchmark(root)["workloads"]]
+
+
+def tiny_path(config_file: str) -> str:
+    """The CPU-test size beside a configuration's file."""
+    return os.path.splitext(config_file)[0] + ".tiny.json"
+
+
+def tiny_config(config_file: str) -> dict:
+    """The configuration in `config_file` with its CPU-test overrides."""
+    path = tiny_path(config_file)
+    if not os.path.isfile(path):
+        raise TinySizeError(f"{config_file} has no CPU-test size: "
+                            f"{path} is missing")
+    with open(config_file) as f:
         cfg = json.load(f)
-    t = TINY[name]
-    cfg["name"] = name + "_tiny"
-    cfg["graph"]["scale"] = t["scale"]
-    cfg["limits"] = t["limits"]
-    cfg["engine"]["max_rounds"] = t["max_rounds"]
+    with open(path) as f:
+        overrides = json.load(f).get("overrides")
+    if not isinstance(overrides, dict):
+        raise TinySizeError(f"{path}: no \"overrides\" object")
+    for dotted, value in overrides.items():
+        *parents, key = dotted.split(".")
+        node = cfg
+        for part in parents:
+            node = node.get(part) if isinstance(node, dict) else None
+        if not isinstance(node, dict) or key not in node:
+            raise TinySizeError(f"{path}: override {dotted!r} names no key "
+                                f"of {config_file}")
+        node[key] = value
     return cfg
 
 
-def tiny_root(dst: str) -> str:
-    shutil.copytree(BENCH, os.path.join(dst, "bench"),
+def tiny_root(dst: str, src: str = ROOT) -> str:
+    """`dst` made a benchmark checkout at CPU-test sizes, from the tree
+    `src`."""
+    shutil.copytree(os.path.join(src, "bench"), os.path.join(dst, "bench"),
                     ignore=shutil.ignore_patterns("tests", "_out",
                                                   "__pycache__"))
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    bench = benchmark(src)
     for c in bench["configs"]:
-        c["name"] += "_tiny"
-        c["file"] = c["file"].replace(".json", "_tiny.json")
+        cfg = tiny_config(os.path.join(src, c["file"]))
+        c["file"] = os.path.splitext(c["file"])[0] + "_tiny.json"
         with open(os.path.join(dst, c["file"]), "w") as f:
-            json.dump(tiny_config(c["name"][:-5]), f)
-    for w in bench["workloads"]:
-        old = w["name"]
-        w["name"] = CELLS[old]
-        w["config"] += "_tiny"
-        path = os.path.join(dst, "bench", "traffic", w["traffic"])
-        with open(path + ".json") as f:
-            traffic = json.load(f)
-        w["traffic"] += "_tiny"
-        with open(path + "_tiny.json", "w") as f:
-            json.dump(traffic, f)
-        for m in bench["end_to_end"] + bench["per_layer"]:
-            if old in m.get("workloads", []):
-                m["workloads"] = [CELLS[old]]
+            json.dump(cfg, f)
     with open(os.path.join(dst, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return dst
