@@ -35,13 +35,9 @@ a hub-heavy `barabasi_albert_hub` graph (forced hub of degree ~n/4 next
 to a median degree of ~3) twice — with the degree-bucketed aggregate
 sampler (the default) and with `bucketed=False` (the pre-bucketing
 single-bucket layout, same code path) — and the row reports both warm
-wall times, the improved engine's `sampler_us` telemetry (wall
-microseconds inside its Phase-1 sample program alone), and the per-bucket
-occupancy. The draws are bit-identical across the two layouts (counter
-RNG), so the improved engine's `sampler_speedup` column isolates exactly
-the O(max_deg) -> O(bucket width) chain-scan win the bucketing exists
-for; on hub-heavy graphs it should be >= 2x. The counts engine's rows
-compare warm wall times only (`flat_us`).
+wall times and the per-bucket occupancy. The draws are bit-identical
+across the two layouts (counter RNG), so `flat_us` against `us_per_call`
+isolates the layout.
 
 `--json [PATH]` additionally writes the raw rows to a machine-readable
 artifact (default BENCH_distributed.json) so the perf trajectory can be
@@ -124,9 +120,8 @@ out.append(dict(K=K, shards=rd.shards, directed=True,
                 dir_budget=rd.uniform_budget, dir_dropped=rd.dropped))
 
 # Power-law hub stress (8-shard leg): bucketed vs flat sampler layout.
-# Same keys -> bit-identical trajectories, so the wall-time and
-# sampler_us deltas are pure layout (O(max_deg) chain scan vs O(bucket
-# width)).
+# Same keys -> bit-identical trajectories, so the wall-time deltas are
+# pure layout (O(max_deg) vs O(bucket width)).
 if jax.device_count() >= 8:
     gh = barabasi_albert_hub(1024, 3, seed=7)
     K = 100
@@ -148,8 +143,6 @@ if jax.device_count() >= 8:
         count_dropped=rb.overflow + rf.overflow
         + abs(rb.residual) + abs(rf.residual),
         imp_us=tib, imp_cold_us=cib, imp_flat_us=tif,
-        imp_sampler_us=rib.sampler_us,
-        imp_flat_sampler_us=rif.sampler_us,
         imp_rounds=rib.rounds, imp_occupancy=list(rib.p1_occupancy),
         imp_dropped=rib.dropped + rif.dropped
         + abs(rib.residual) + abs(rif.residual)))
@@ -189,8 +182,6 @@ def report(rows):
             continue
         p, k = r["shards"], r["K"]
         if r.get("powerlaw"):
-            i_spd = (r["imp_flat_sampler_us"]
-                     / max(r["imp_sampler_us"], 1.0))
             print(f"dist_hubcount_P{p}_K{k},{r['count_us']:.0f},"
                   f"cold_us={r['count_cold_us']:.0f};"
                   f"flat_us={r['count_flat_us']:.0f};"
@@ -202,9 +193,6 @@ def report(rows):
                   f"cold_us={r['imp_cold_us']:.0f};"
                   f"flat_us={r['imp_flat_us']:.0f};"
                   f"rounds={r['imp_rounds']};"
-                  f"sampler_us={r['imp_sampler_us']:.0f};"
-                  f"flat_sampler_us={r['imp_flat_sampler_us']:.0f};"
-                  f"sampler_speedup={i_spd:.2f}x;"
                   f"occupancy={r['imp_occupancy']};"
                   f"dropped={r['imp_dropped']}")
             continue

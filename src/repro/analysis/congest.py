@@ -329,6 +329,7 @@ def _telemetry_three_phase(graph, mesh, spec, eps, K, use_pallas, *,
     res = run(graph, eps, walks_per_node=K, key=jax.random.PRNGKey(0),
               mesh=mesh, use_pallas=use_pallas)
     w = _site_widths(spec)
+    w.setdefault("phase1_rep", 0)   # one shard replies without a wire
     wire, ent = res.a2a_bytes_by_phase, res.a2a_entries_by_site
     checks = [
         dict(name="phase1", runtime_bytes=int(wire.get("phase1", 0)),

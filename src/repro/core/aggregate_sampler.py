@@ -231,20 +231,3 @@ def flatten_moves(samples) -> jnp.ndarray:
     (termination column dropped, each bucket's block slot-major)."""
     return jnp.concatenate([T[:, 1:].T.reshape(-1) for _, T in samples])
 
-
-def scatter_cells(samples, layout: BucketLayout, max_deg: int
-                  ) -> jnp.ndarray:
-    """Dense per-row outcome cells [n_rows * (max_deg + 1)] int32: cell
-    r*(max_deg+1) is row r's termination count, cell r*(max_deg+1)+1+j its
-    out-edge-j count (0 beyond the row's bucket width — structurally
-    count-free). This is the Phase-1 reply layout of the 3-phase engines.
-    """
-    size = layout.n_rows * (max_deg + 1)
-    out = jnp.zeros((size + 1,), jnp.int32)
-    for (rows_b, T_b), w in zip(samples, layout.widths):
-        base = jnp.where(rows_b < 0, size, rows_b * (max_deg + 1))
-        offs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                1 + jnp.arange(w, dtype=jnp.int32)])
-        idx = jnp.minimum(base[:, None] + offs[None, :], size)
-        out = out.at[idx].set(T_b, mode="drop")
-    return out[:size]

@@ -38,10 +38,10 @@ multiplicity, so a hub attracting the entire pool still costs ONE lane
 entry and no per-coupon slot exists anywhere (the old cap1 was
 sum(pool) ~ n*eta*log n slots per shard). The one per-walk surface left
 is the naive exhaustion tail, and there a directed hub still has no
-degree bound tying its load to 2*W/P — so `cap2` alone keeps the
-worst-case W sizing (W = n*K walk slots, orders of magnitude below the
-retired pool buffers), which makes `dropped == 0` structural: lane
-backpressure shows up as `waited`, never as a drop.
+degree bound tying its load to 2*W/P — so its buffer holds every tail
+walk on one shard (the shared driver's default, sized from the walks the
+tail holds), which makes `dropped == 0` structural: lane backpressure
+shows up as `waited`, never as a drop.
 
 Phase structure, wire accounting, conservation counters, the exhaustion
 fallback to naive distributed walking, and the host-float64 estimator
@@ -119,16 +119,13 @@ def distributed_directed_pagerank(
     eta, pool_np = coupon_pool_sizes(graph, eps, K, lam, eta=eta,
                                      eta_safety=eta_safety,
                                      degree_proportional=False, ell=ell)
-    # the naive tail is per-walk: worst-case W buffer (see module docstring)
-    if cap2 is None:
-        cap2 = n * K + int(mesh.devices.size) * 64
     return _run_three_phase(
-        graph, eps, K, key, mesh, pool_np=pool_np, eta=int(eta),
+        graph, eps, K, key, mesh, pools=lambda: pool_np, eta=int(eta),
         lam=int(lam), ell=int(ell), cap2=cap2, route_cap2=route_cap2,
         max_rounds=max_rounds, bandwidth_bits=bandwidth_bits,
         use_pallas=use_pallas, checkpoint_dir=checkpoint_dir,
         fail_at=fail_at, checkpoint_every=checkpoint_every,
-        max_restarts=max_restarts, resume=resume,
+        max_restarts=max_restarts, resume=resume, name="directed",
         result_cls=DirectedDistResult,
         uniform_budget=int(pool_np[0]),
         dangling_nodes=int((np.asarray(graph.out_deg) == 0).sum()))

@@ -11,9 +11,13 @@ travels as (vertex, count) pairs — the wire volume is bounded by the number
 of *distinct* (vertex, outcome) pairs, independent of how many walks move.
 
 Phase 1 — short-walk pre-computation. Shard p owns the coupons of its
-  vertices: vertex v gets pool_size(v) = d(v)*eta coupons (Lemma 2 sizing,
-  see `improved_pagerank.coupon_pool_sizes`), each a PageRank walk given
-  exactly lambda = ceil(sqrt(log n)) step opportunities. Coupons never
+  vertices, each a PageRank walk given exactly lambda = ceil(sqrt(log n))
+  step opportunities. Vertex v gets K + ceil(a + 4 sqrt(a) + 4) coupons,
+  a its expected stitch arrivals (`improved_pagerank.demand_pool_sizes`):
+  its own K walks each take their first coupon there. This departs from
+  Lemma 2's d(v)*eta (still available by an explicit `eta`), which leaves
+  a low-degree vertex fewer coupons than it starts walks, so a third of
+  the walks of a Graph500 graph fell to the naive tail. Coupons never
   migrate; slot s of shard p's pool table is its identity. Each round is
   one count-aggregated round trip:
 
@@ -33,15 +37,20 @@ Phase 1 — short-walk pre-computation. Shard p owns the coupons of its
       counter-based draws keyed on (round key words, globally-unique row
       id, slot) — see `kernels/multinomial_rows/_math` — so the results
       are independent of bucket layout and of `use_pallas`, and replay
-      stays bit-exact. The sample program is split out of the round so
-      the driver can clock it (`sampler_us`, `p1_occupancy` telemetry);
-    reply   — nonzero (vertex, outcome-class, count) cells go back to the
-      home shard (12 B/entry); outcome class 0 is "terminated", class j
-      is "moved to out-edge j" carrying the destination vertex id;
-    assign  — the home shard assigns its coupons at vertex v to the
-      returned outcome slots by a uniformly-random permutation (random
-      priorities + stable rank within the vertex group). A multiset of
-      iid outcomes dealt out in uniform-random order IS an iid draw per
+      stays bit-exact. The outcomes stay in the sampler's own layout:
+      per home, one reset count per bucket row and one count per bucket
+      slot (`aggregate_sampler.bucketize_csr` gives each slot's
+      destination), so no Phase-1 array grows with n_loc * max_deg;
+    reply   — nonzero (vertex, destination, offset) cells go back to the
+      home shard (12 B/entry), the destination -2 for "terminated" and
+      the offset where the cell's outcomes start within its row;
+    assign  — the home shard sorts its live coupons by (vertex, random
+      priority), so vertex v's coupons hold positions [base[v],
+      base[v] + c[v]) of that order, base the exclusive cumsum of its
+      per-vertex counts; a cell covers [base[v] + offset, + count). The
+      rows add up to c (residual 0), so the cells tile the positions and
+      every live coupon takes exactly one outcome. A multiset of iid
+      outcomes dealt out in uniform-random order IS an iid draw per
       coupon, so every coupon still walks the exact eps-reset chain.
 
   The per-coupon move is recorded in a home-local trajectory table
@@ -55,7 +64,7 @@ Phase 2 — stitching. The n*K long walks are anonymous too ("which coupon
   uniform-without-replacement because coupons are iid), marks them used,
   retires walks whose coupon recorded an eps-reset, and ships the rest as
   per-destination counts (`route_counts`, 8 B/entry). Walks at an
-  exhausted pool (eta undersized — the paper's whp bound violated)
+  exhausted pool (undersized — the paper's whp bound violated)
   accumulate in a per-vertex tail count for the naive fallback.
 
 Phase 3 — counting. One histogram of the used coupons' home-local
@@ -64,21 +73,28 @@ Phase 3 — counting. One histogram of the used coupons' home-local
   to a single aggregated round (the old implementation re-ran the whole
   Phase-1 schedule as a deterministic replay; the trajectory table makes
   that — and its per-walk wire — unnecessary). Tail walks then finish
-  naively through the Algorithm 1 superstep (`distributed._make_superstep`),
-  counting arrivals into the same sharded zeta; the estimator
+  naively through the Algorithm 1 superstep (`distributed._make_superstep`,
+  its walk buffer sized from the walks it holds), counting arrivals into
+  the same sharded zeta; the estimator
   pi = zeta * eps/(nK) is computed on the host in float64
   (`estimator.pagerank_from_visits`).
 
 Static shapes throughout; count lanes are sized so overflow is
 *structurally impossible* (`route_counts` caps lanes at n_loc distinct
-vertices; Phase-1 replies at min(n_loc*(max_deg+1), S_loc_pad) distinct
-cells), so `dropped` stays 0 by construction — only the naive tail keeps
-the Algorithm-1 `cap >= 2*W/P + P*route_cap` sizing rule.
+vertices; Phase-1 replies at min(n_loc + bucket slots, S_loc_pad)
+distinct cells), so `dropped` stays 0 by construction; the naive tail's
+buffer holds every tail walk.
+
+Each stage program has its own name in a device trace: `jit_p1_request`,
+`jit_p1_sample`, `jit_p1_assign`, `jit_p2_stitch`, `jit_p3_count` and
+`jit_tail_step`. `max_rounds` caps each stage's rounds: a stage that
+reaches it stops, and the result counts the walks still out
+(`unfinished`).
 
 The phases only ever see a per-node pool-size vector, so the whole driver
 lives in the budget-policy-agnostic `_run_three_phase`; this module's
-public `distributed_improved_pagerank` feeds it Lemma-2 degree-proportional
-pools, and `distributed_directed.distributed_directed_pagerank` feeds it
+public `distributed_improved_pagerank` feeds it demand-covering pools,
+and `distributed_directed.distributed_directed_pagerank` feeds it
 the Section-5 uniform/LOCAL pools — count aggregation removed the
 worst-case per-walk buffers that engine used to need.
 
@@ -102,9 +118,9 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
+from concurrent.futures import ThreadPoolExecutor
 from functools import lru_cache, partial
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -113,12 +129,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.accounting import CongestReport, RoundTrace, default_bandwidth
 from repro.core.aggregate_sampler import (build_layout_sharded,
-                                          sample_buckets, scatter_cells)
+                                          bucketize_csr, sample_buckets)
 from repro.core.distributed import (AXIS, DistState, _make_superstep,
                                     shard_graph, shard_map)
 from repro.core.estimator import pagerank_from_visits
 from repro.core.graph import CSRGraph
-from repro.core.improved_pagerank import coupon_pool_sizes
+from repro.core.improved_pagerank import (coupon_pool_sizes,
+                                          demand_pool_sizes)
 from repro.core.routing import (entry_nbytes, exchange_stacked, lane_slots,
                                 pack_lanes, route_counts, vertex_histogram)
 from repro.checkpoint import LayoutSpec
@@ -126,19 +143,69 @@ from repro.core.simple_pagerank import walks_per_node_for
 from repro.kernels import resolve_use_pallas
 from repro.kernels.multinomial_rows._math import key_words
 from repro.runtime import Stage, StagedState, StageSchedule, run_staged
-
-_INT32_MAX = 2 ** 31 - 1
-
+from repro.runtime import tracing
 
 # ---------------------------------------------------------------------------
 # Phase 1: count-aggregated short-walk pre-computation
 # ---------------------------------------------------------------------------
+#
+# Reply layout. Every owner holds, for each home, one reply cell per bucket
+# row (its reset count) and one per bucket slot (its count on that
+# out-edge): [total_rows + total_edges] cells, the rows in `bperm` order and
+# the slots in `bnbr` order (`aggregate_sampler.bucketize_csr`), so a
+# cell's vertex and destination are static. Nothing in Phase 1 is as wide
+# as n_loc * max_deg.
+
+def _row_cells(x, layout):
+    """Per-row values [..., total_rows] (bucket-row order) -> per reply
+    cell [..., total_rows + total_edges]: each row's value on its reset
+    cell and on every slot of its bucket block (slot-major)."""
+    lead = x.shape[:-1]
+    parts = [x]
+    for s, cap, w in zip(layout.row_starts, layout.caps, layout.widths):
+        xb = x[..., None, s:s + cap]
+        parts.append(jnp.broadcast_to(xb, lead + (w, cap))
+                     .reshape(lead + (w * cap,)))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def _cell_offsets(cells, layout):
+    """Where each reply cell's outcomes start within its (home, vertex)
+    row: the reset cell at 0, then the out-edge slots in slot order."""
+    tr = layout.total_rows
+    lead = cells.shape[:-1]
+    term = cells[..., :tr]
+    parts, e = [jnp.zeros_like(term)], tr
+    for s, cap, w in zip(layout.row_starts, layout.caps, layout.widths):
+        mv = cells[..., e:e + w * cap].reshape(lead + (w, cap))
+        ex = jnp.cumsum(mv, axis=-2) - mv + term[..., None, s:s + cap]
+        parts.append(ex.reshape(lead + (w * cap,)))
+        e += w * cap
+    return jnp.concatenate(parts, axis=-1)
+
+
+def _group_order(group, key):
+    """The order that sorts `group` ascending, ties in a uniformly random
+    order: a stable sort by 31 random bits, then a stable sort by group.
+    Both passes run one compiled sort (a two-trip loop): the TPU compiler
+    takes minutes over a sort of two keys, and about a minute over one."""
+    rand = jax.lax.shift_right_logical(
+        jax.random.bits(key, group.shape, jnp.uint32), jnp.uint32(1))
+    keys = jnp.stack([rand.astype(jnp.int32), group])
+
+    def pass_(i, perm):
+        return perm[jnp.argsort(keys[i][perm], stable=True)]
+
+    return jax.lax.fori_loop(0, 2, pass_,
+                             jnp.arange(group.shape[0], dtype=jnp.int32))
+
 
 def _p1_request(pos, alive, *, n_loc: int, shards: int, use_pallas: bool,
                 count_bound: Optional[int] = None):
     """Phase-1 program 1 (request): per-vertex live-coupon counts to the
-    owners. Output row layout: c[home * n_loc + v] = coupons of `home`
-    currently at owned vertex v."""
+    owners. Returns c[home * n_loc + v] = coupons of `home` currently at
+    owned vertex v, and this home's own histogram over all n_pad vertices
+    (the assign's per-vertex offsets)."""
     pos, alive = pos[0], alive[0]
     shard_id = jax.lax.axis_index(AXIS)
     n_pad = shards * n_loc
@@ -149,18 +216,19 @@ def _p1_request(pos, alive, *, n_loc: int, shards: int, use_pallas: bool,
     c = c_by_home.reshape(-1)               # [P*n_loc], row = home*n_loc + v
     req_entries = jax.lax.psum(req_entries, AXIS)
     req_bytes = jax.lax.psum(req_bytes, AXIS)
-    return c[None], req_entries, req_bytes
+    return c[None], req[None], req_entries, req_bytes
 
 
 def _p1_sample(bperm, dg, c, key, *, eps: float, n_loc: int, shards: int,
-               md: int, layout, use_pallas: bool):
+               layout, use_pallas: bool):
     """Phase-1 program 2 (sample): the owner draws, independently for every
     (home, vertex) row, the fused Binomial(eps) termination + conditional-
     binomial edge split through the shared degree-bucketed sampler (a
-    dangling row terminates whole). Pure per-shard compute — the driver
-    clocks it for `sampler_us`. Returns the dense home-major outcome cells
-    f_cnt[(home*n_loc + v)*(md+1) + class] plus the advanced key, the
-    assignment key, per-bucket occupancy, and the conservation residual.
+    dangling row terminates whole). Pure per-shard compute. Returns the
+    reply cells [P, total_rows + total_edges] (home-major: per home, the
+    reset count of every bucket row, then the count on every bucket
+    slot), the advanced key, the assignment key, per-bucket occupancy,
+    and the conservation residual.
 
     RNG contract: every draw is a pure counter-based function of the
     per-round key words, rid = owner*n_pad + home*n_loc + v (globally
@@ -189,92 +257,84 @@ def _p1_sample(bperm, dg, c, key, *, eps: float, n_loc: int, shards: int,
     samples, occ, residual = sample_buckets(
         c, deg_row, rid, key_words(k_sample), perm_t, layout_t,
         eps=eps, use_pallas=use_pallas)
-    f_cnt = scatter_cells(samples, layout_t, md)
+    blocks = [T.reshape(shards, cap, w + 1)
+              for (_, T), cap, w in zip(samples, layout.caps, layout.widths)]
+    cells = jnp.concatenate(
+        [b[:, :, 0] for b in blocks]
+        + [b[:, :, 1:].transpose(0, 2, 1).reshape(shards, -1)
+           for b in blocks], axis=1)
     occ = jax.lax.psum(occ, AXIS)
     residual = jax.lax.psum(residual, AXIS)
-    return f_cnt[None], key[None], k_perm[None], occ, residual
+    return cells.reshape(1, -1), key[None], k_perm[None], occ, residual
 
 
-def _p1_assign(rp, ci, pos, alive, traj, f_cnt, k_perm, t, *,
-               n_loc: int, shards: int, md: int, rep_cap: int,
+def _p1_assign(bperm, bnbr, hist, cells, pos, alive, traj, k_perm, t, *,
+               n_loc: int, shards: int, layout, rep_cap: int,
                S_loc_pad: int):
-    """Phase-1 program 3 (reply + assign): route the nonzero outcome cells
-    back to the home shards and deal them out to the coupons by a
-    uniform-random within-vertex permutation (see module docstring)."""
-    rp, ci, pos, alive, traj, f_cnt, k_perm = (
-        rp[0], ci[0], pos[0], alive[0], traj[0], f_cnt[0], k_perm[0])
+    """Phase-1 program 3 (reply + assign). Each home sorts its live coupons
+    by (vertex, random priority), so vertex v's coupons hold positions
+    [base[v], base[v] + c[v]) of that order, base the exclusive cumsum of
+    the home's per-vertex counts. The owner replies with each nonzero
+    cell's (vertex, destination, offset within its row); the cell covers
+    positions [base[v] + offset, + its count) — a row's cells add up to
+    c[v] (residual 0), so the cells tile the positions exactly and every
+    live coupon gets one outcome. A multiset of iid outcomes dealt out in
+    uniform-random order IS an iid draw per coupon."""
+    bperm, bnbr, hist, cells, pos, alive, traj, k_perm = (
+        bperm[0], bnbr[0], hist[0], cells[0], pos[0], alive[0], traj[0],
+        k_perm[0])
     shard_id = jax.lax.axis_index(AXIS)
     n_pad = shards * n_loc
-    C = S_loc_pad + 1
-    cells = n_loc * (md + 1)
+    i32 = jnp.int32
+    cells = cells.reshape(shards, -1)                    # [home, cell]
+    off = _cell_offsets(cells, layout)
+    dst = jnp.concatenate([jnp.full((layout.total_rows,), -2, i32), bnbr])
+    base = jnp.cumsum(hist) - hist                       # [n_pad]
+
+    # the own home's cells: no wire, per-row offsets broadcast per slot
+    own_base = jnp.where(bperm >= 0,
+                         base[shard_id * n_loc + jnp.maximum(bperm, 0)], 0)
+    starts = [off[shard_id] + _row_cells(own_base, layout)]
+    dsts, oks = [dst], [cells[shard_id] > 0]
+    overflow = rep_entries = rep_bytes = jnp.int32(0)
+    if shards > 1:
+        # ---- reply: nonzero (vertex, destination, offset) cells home ----
+        home = jnp.repeat(jnp.arange(shards, dtype=i32), cells.shape[1])
+        remote = (cells.reshape(-1) > 0) & (home != shard_id)
+        vid = shard_id * n_loc + _row_cells(bperm, layout)
+        sendable, flat_idx = lane_slots(home, remote, shards, rep_cap)
+        lanes = [pack_lanes(flat_idx, x, sendable, shards, rep_cap, fill)
+                 for x, fill in ((jnp.tile(vid, shards), -1),
+                                 (jnp.tile(dst, shards), 0),
+                                 (off.reshape(-1), 0))]
+        r_vid, r_dst, r_off = exchange_stacked(lanes, AXIS, shards, rep_cap)
+        got = r_vid >= 0
+        starts.append(base[jnp.where(got, r_vid, 0)] + r_off)
+        dsts.append(r_dst)
+        oks.append(got)
+        # rep_cap = min(n_loc + slots, S_loc_pad) bounds the distinct
+        # cells one home can receive, so this stays 0; psum'd into
+        # dropped as a tripwire
+        overflow = jnp.sum(remote & ~sendable)
+        rep_entries = jnp.sum(lanes[0] >= 0)
+        rep_bytes = rep_entries * entry_nbytes(*lanes)
+
+    # ---- deal: each position takes the cell whose range holds it ----
+    ok = jnp.concatenate(oks)
+    at = jnp.full((S_loc_pad,), -1, i32).at[
+        jnp.where(ok, jnp.concatenate(starts), S_loc_pad)].set(
+            jnp.concatenate(dsts), mode="drop")
+    first = jax.lax.cummax(
+        jnp.where(at != -1, jnp.arange(S_loc_pad, dtype=i32), 0), axis=0)
     elig = alive > 0
-
-    eidx = jnp.clip(rp[:n_loc, None] + jnp.arange(md)[None, :], 0,
-                    ci.shape[0] - 1)
-    edge_dst = ci[eidx]                     # [n_loc, md] global dst per edge
-    dst = jnp.concatenate(
-        [jnp.full((shards * n_loc, 1), -2, jnp.int32),   # class 0: reset
-         jnp.tile(edge_dst, (shards, 1))], axis=1)
-    vid = jnp.tile(shard_id * n_loc + jnp.arange(n_loc, dtype=jnp.int32),
-                   shards)
-
-    # ---- reply: nonzero (vertex, class, count) cells to the home ----
-    f_vid = jnp.repeat(vid, md + 1)
-    f_dst = dst.reshape(-1)
-    home = jnp.arange(shards * cells, dtype=jnp.int32) // cells
-    remote = (f_cnt > 0) & (home != shard_id)
-    sendable, flat_idx = lane_slots(home, remote, shards, rep_cap)
-    l_vid = pack_lanes(flat_idx, f_vid, sendable, shards, rep_cap, fill=-1)
-    l_dst = pack_lanes(flat_idx, f_dst, sendable, shards, rep_cap, fill=0)
-    l_cnt = pack_lanes(flat_idx, f_cnt, sendable, shards, rep_cap, fill=0)
-    r_vid, r_dst, r_cnt = exchange_stacked([l_vid, l_dst, l_cnt], AXIS,
-                                           shards, rep_cap)
-    # rep_cap = min(n_loc*(md+1), S_loc_pad) bounds the distinct cells one
-    # home can receive, so this stays 0; psum'd into dropped as a tripwire
-    overflow = jnp.sum(remote & ~sendable)
-    rep_entries = jnp.sum(l_vid >= 0)
-    rep_bytes = rep_entries * entry_nbytes(l_vid, l_dst, l_cnt)
-
-    own_start = shard_id * cells            # own home's block, wire-free
-    o_vid = jax.lax.dynamic_slice(f_vid, (own_start,), (cells,))
-    o_dst = jax.lax.dynamic_slice(f_dst, (own_start,), (cells,))
-    o_cnt = jax.lax.dynamic_slice(f_cnt, (own_start,), (cells,))
-
-    # ---- home: segmented outcome intervals, keyed v*C + start-rank ----
-    e_vid = jnp.concatenate([o_vid, r_vid])
-    e_dst = jnp.concatenate([o_dst, r_dst])
-    e_cnt = jnp.concatenate([o_cnt, jnp.where(r_vid >= 0, r_cnt, 0)])
-    evid = jnp.where((e_cnt > 0) & (e_vid >= 0), e_vid, n_pad)
-    order = jnp.argsort(evid, stable=True)
-    evid_s, cnt_s, dst_s = evid[order], e_cnt[order], e_dst[order]
-    s = jnp.cumsum(cnt_s) - cnt_s           # exclusive cumsum (nonneg cnt)
-    idx = jnp.arange(evid_s.shape[0])
-    is_start = jnp.concatenate([jnp.ones((1,), bool),
-                                evid_s[1:] != evid_s[:-1]])
-    base = jax.lax.associative_scan(jnp.maximum, jnp.where(is_start, s, 0))
-    sw = (s - base).astype(jnp.int32)       # rank interval start within v
-    keys_s = jnp.where(evid_s < n_pad, evid_s * C + sw, _INT32_MAX)
-
-    # ---- assign: uniform-random permutation of coupons within vertex ----
-    u = jax.random.uniform(k_perm, (S_loc_pad,))
-    gkey = jnp.where(elig, pos, n_pad)
-    ord2 = jnp.lexsort((u, gkey))           # by vertex, random within
-    gs = gkey[ord2]
-    idx2 = jnp.arange(S_loc_pad)
-    is_st2 = jnp.concatenate([jnp.ones((1,), bool), gs[1:] != gs[:-1]])
-    rst = jax.lax.associative_scan(jnp.maximum,
-                                   jnp.where(is_st2, idx2, 0))
-    rank = jnp.zeros((S_loc_pad,), jnp.int32).at[ord2].set(
-        (idx2 - rst).astype(jnp.int32))
-    q = jnp.where(elig, pos * C + rank, 0)
-    loc = jnp.clip(jnp.searchsorted(keys_s, q, side="right") - 1, 0,
-                   keys_s.shape[0] - 1)
-    out = dst_s[loc]                        # -2 = reset, >=0 = destination
+    order = _group_order(jnp.where(elig, pos, n_pad), k_perm)
+    out = jnp.zeros((S_loc_pad,), i32).at[order].set(
+        at[first], unique_indices=True)   # -2 = reset, >=0 = destination
     survive = elig & (out >= 0)
     new_pos = jnp.where(survive, out, pos)  # dead coupons keep final vertex
-    new_alive = survive.astype(jnp.int32)
+    new_alive = survive.astype(i32)
     traj = jax.lax.dynamic_update_slice(
-        traj, jnp.where(survive, out, -1).astype(jnp.int32)[:, None],
+        traj, jnp.where(survive, out, -1).astype(i32)[:, None],
         (jnp.int32(0), t))
 
     pending = jax.lax.psum(jnp.sum(survive), AXIS)
@@ -292,31 +352,43 @@ def _p1_assign(rp, ci, pos, alive, traj, f_cnt, k_perm, t, *,
 # the same devices hit the cache even when the caller rebuilds the mesh.
 @lru_cache(maxsize=64)
 def _make_p1_steps(mesh: Mesh, *, eps: float, n_loc: int, shards: int,
-                   md: int, rep_cap: int, S_loc_pad: int,
-                   layout, use_pallas: bool,
+                   rep_cap: int, S_loc_pad: int, layout, use_pallas: bool,
                    count_bound: Optional[int] = None):
     """Returns (request, sample, assign): the three jitted programs of one
-    Phase-1 round. Split so the driver can time the sampler alone.
-    `count_bound` is the declared upper bound on any routed count (the
-    coupon-pool total) — forwarded to the count reductions so the f32
-    segment kernel is bypassed when it could truncate (> 2^24)."""
+    Phase-1 round, named in a device trace `jit_p1_request`,
+    `jit_p1_sample` and `jit_p1_assign`. `count_bound` is the declared
+    upper bound on any routed count (the coupon-pool total) — forwarded
+    to the count reductions so the f32 segment kernel is bypassed when it
+    could truncate (> 2^24)."""
     req_sh = shard_map(
         partial(_p1_request, n_loc=n_loc, shards=shards,
                 use_pallas=use_pallas, count_bound=count_bound),
         mesh, in_specs=(P(AXIS), P(AXIS)),
-        out_specs=(P(AXIS), P(), P()))
+        out_specs=(P(AXIS), P(AXIS), P(), P()))
     samp_sh = shard_map(
-        partial(_p1_sample, eps=eps, n_loc=n_loc, shards=shards, md=md,
+        partial(_p1_sample, eps=eps, n_loc=n_loc, shards=shards,
                 layout=layout, use_pallas=use_pallas),
         mesh, in_specs=(P(AXIS),) * 4,
         out_specs=(P(AXIS),) * 3 + (P(),) * 2)
     asn_sh = shard_map(
-        partial(_p1_assign, n_loc=n_loc, shards=shards, md=md,
+        partial(_p1_assign, n_loc=n_loc, shards=shards, layout=layout,
                 rep_cap=rep_cap, S_loc_pad=S_loc_pad),
-        mesh, in_specs=(P(AXIS),) * 7 + (P(),),
+        mesh, in_specs=(P(AXIS),) * 8 + (P(),),
         out_specs=(P(AXIS),) * 3 + (P(),) * 4)
 
-    return jax.jit(req_sh), jax.jit(samp_sh), jax.jit(asn_sh)
+    @jax.jit
+    def p1_request(pos, alive):
+        return req_sh(pos, alive)
+
+    @jax.jit
+    def p1_sample(bperm, dg, c, key):
+        return samp_sh(bperm, dg, c, key)
+
+    @jax.jit
+    def p1_assign(bperm, bnbr, hist, cells, pos, alive, traj, k_perm, t):
+        return asn_sh(bperm, bnbr, hist, cells, pos, alive, traj, k_perm, t)
+
+    return p1_request, p1_sample, p1_assign
 
 
 # ---------------------------------------------------------------------------
@@ -324,7 +396,7 @@ def _make_p1_steps(mesh: Mesh, *, eps: float, n_loc: int, shards: int,
 # ---------------------------------------------------------------------------
 
 def _p2_local(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
-              slot_v, *, n_loc: int, shards: int, S_loc_pad: int,
+              *, n_loc: int, shards: int, S_loc_pad: int,
               use_pallas: bool, count_bound: Optional[int] = None):
     """One stitch superstep. Long walks are anonymous, so the state is a
     per-owned-vertex count: allocate the next unused coupons of each
@@ -333,17 +405,21 @@ def _p2_local(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
     coupons are iid), retire walks whose coupon recorded an eps-reset,
     route the movers as per-destination counts, and bank pool-exhausted
     walks in `tail_cnt` for the naive fallback."""
-    (walks, next_c, used, tail_cnt, dest, cterm, psize, pstart, slot_v) = (
+    (walks, next_c, used, tail_cnt, dest, cterm, psize, pstart) = (
         walks[0], next_c[0], used[0], tail_cnt[0], dest[0], cterm[0],
-        psize[0], pstart[0], slot_v[0])
+        psize[0], pstart[0])
     shard_id = jax.lax.axis_index(AXIS)
     n_pad = shards * n_loc
 
     a = jnp.minimum(walks, psize - next_c)        # coupons allocatable now
     exh = walks - a                               # pool empty: naive tail
-    off = jnp.arange(S_loc_pad, dtype=jnp.int32) - pstart[slot_v]
-    nc = next_c[slot_v]
-    alloc = (off >= nc) & (off < nc + a[slot_v])  # this round's used slots
+    # this round's used slots: vertex v's [pstart + next_c, + a), marked
+    # at both ends per vertex and summed along the slots (the ranges are
+    # disjoint), not looked up per slot
+    lo = pstart + next_c
+    edges = (jnp.zeros((S_loc_pad + 1,), jnp.int32)
+             .at[lo].add(1).at[lo + a].add(-1))
+    alloc = jnp.cumsum(edges[:S_loc_pad]) > 0
     used = jnp.maximum(used, alloc.astype(jnp.int32))
     next_c = next_c + a
     term_now = alloc & (cterm > 0)      # coupon's eps-reset fired: walk done
@@ -371,16 +447,15 @@ def _make_p2_step(mesh: Mesh, *, n_loc: int, shards: int, S_loc_pad: int,
                  S_loc_pad=S_loc_pad, use_pallas=use_pallas,
                  count_bound=count_bound)
     sharded = shard_map(fn, mesh,
-                        in_specs=(P(AXIS),) * 9,
+                        in_specs=(P(AXIS),) * 8,
                         out_specs=(P(AXIS),) * 4 + (P(),) * 6)
 
     @jax.jit
-    def step(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
-             slot_v):
+    def p2_stitch(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart):
         return sharded(walks, next_c, used, tail_cnt, dest, cterm, psize,
-                       pstart, slot_v)
+                       pstart)
 
-    return step
+    return p2_stitch
 
 
 # ---------------------------------------------------------------------------
@@ -390,12 +465,20 @@ def _make_p2_step(mesh: Mesh, *, n_loc: int, shards: int, S_loc_pad: int,
 def _p3_local(traj, used, zeta, *, n_loc: int, shards: int,
               use_pallas: bool, count_bound: Optional[int] = None):
     """Histogram the used coupons' recorded moves and deliver the counts
-    to the owner shards in ONE `route_counts` exchange."""
+    to the owner shards in ONE `route_counts` exchange. The histogram
+    runs one step column at a time: a histogram of the whole table at
+    once needs ~4 GB of scratch on the TPU at 7.8M coupons."""
     traj, used, zeta = traj[0], used[0], zeta[0]
     shard_id = jax.lax.axis_index(AXIS)
     n_pad = shards * n_loc
-    ids = jnp.where(used[:, None] > 0, traj, -1).reshape(-1)
-    part = vertex_histogram(ids, ids >= 0, n_pad, use_pallas=use_pallas)
+
+    def add_step(t, part):
+        col = jax.lax.dynamic_index_in_dim(traj, t, axis=1, keepdims=False)
+        return part + vertex_histogram(col, (used > 0) & (col >= 0), n_pad,
+                                       use_pallas=use_pallas)
+
+    part = jax.lax.fori_loop(0, traj.shape[1], add_step,
+                             jnp.zeros((n_pad,), jnp.int32))
     arrivals, sent_entries, sent_bytes = route_counts(
         part, axis=AXIS, shard_id=shard_id, n_loc=n_loc, shards=shards,
         use_pallas=use_pallas, count_bound=count_bound)
@@ -414,10 +497,37 @@ def _make_p3_step(mesh: Mesh, *, n_loc: int, shards: int,
                         out_specs=(P(AXIS), P(), P()))
 
     @jax.jit
-    def step(traj, used, zeta):
+    def p3_count(traj, used, zeta):
         return sharded(traj, used, zeta)
 
-    return step
+    return p3_count
+
+
+@lru_cache(maxsize=64)
+def _make_tail_step(mesh: Mesh, eps: float, n_loc: int, shards: int,
+                    route_cap: int, use_pallas: bool):
+    """The naive tail: the Algorithm-1 superstep, under its own name in a
+    device trace (`jit_tail_step`)."""
+    step = _make_superstep(mesh, eps, n_loc, shards, route_cap, 0,
+                           use_pallas=use_pallas)
+
+    @jax.jit
+    def tail_step(rp, ci, dg, state):
+        return step(rp, ci, dg, state)
+
+    return tail_step
+
+
+def _tail_sizes(walks: int, shards: int, cap2: Optional[int],
+                route_cap2: Optional[int]):
+    """(walk buffer per shard, lanes per shard pair) of a naive tail that
+    holds `walks` walks: unless given, the buffer is their count rounded
+    up to a power of two, at least 1,024 — room for every tail walk on
+    one shard, so the merge never drops one — and the lanes follow the
+    documented rule route_cap >= ceil(W/P) for that many walks."""
+    if cap2 is None:
+        cap2 = max(1024, 1 << max(int(walks) - 1, 0).bit_length())
+    return int(cap2), _lane_cap(route_cap2, cap2, shards)
 
 
 # ---------------------------------------------------------------------------
@@ -455,13 +565,15 @@ class ThreePhasePlan:
     S_loc_pad: int
     S_total: int
     rep_cap: int               # phase-1 reply lanes per shard pair
-    route_cap2: int            # naive-tail walk lanes per shard pair
-    cap2: int                  # naive-tail walk buffer per shard
+    route_cap2: Optional[int]  # naive-tail lanes per shard pair, or None:
+    cap2: Optional[int]        # naive-tail walk buffer per shard, or None:
+                               # both sized from the tail's walks
     pool_pad: np.ndarray
     psize_sh: np.ndarray
     pstart_sh: np.ndarray
     layout: object             # aggregate_sampler.BucketLayout
     bperm_np: np.ndarray
+    bnbr_np: np.ndarray        # [P, layout.total_edges] slot destinations
 
 
 def plan_three_phase(graph: CSRGraph, shards: int, pool_np: np.ndarray,
@@ -483,31 +595,32 @@ def plan_three_phase(graph: CSRGraph, shards: int, pool_np: np.ndarray,
     S_loc = psize_sh.sum(axis=1)
     S_loc_pad = max(int(S_loc.max()), 1)
     S_total = int(pool_np.sum())
+    # coupon slot ids, and the assign's keys: a slot's position in its
+    # home's (vertex, priority) order, below S_loc_pad
     if shards * S_loc_pad >= 2 ** 31:
         raise ValueError("coupon pool too large for int32 ids")
-    if (shards * n_loc + 1) * (S_loc_pad + 1) >= 2 ** 31:
-        raise ValueError("vertex*rank outcome keys overflow int32")
-
-    # Phase-1 reply lanes: a home can receive at most one cell per
-    # (owned-vertex, outcome-class) pair and at most one per coupon
-    rep_cap = min(n_loc * (md + 1), S_loc_pad)
-    # tail (naive fallback) keeps the Algorithm-1 CONGEST sizing rule
-    route_cap2 = _lane_cap(route_cap2, n * K, shards)
-    if cap2 is None:
-        cap2 = max(2 * n * K // shards, n_loc * K) + shards * 64
 
     deg_np = np.ascontiguousarray(
         np.asarray(sg.out_deg, np.int32).reshape(shards, n_loc))
     layout, bperm_np = build_layout_sharded(deg_np, md, bucketed=bucketed)
+    # an owner's reply cells: a reset cell per bucket row and a cell per
+    # bucket slot, for every home
+    if shards * (layout.total_rows + layout.total_edges) >= 2 ** 31:
+        raise ValueError("phase-1 reply cells overflow int32 indices")
+    bnbr_np = bucketize_csr(graph.row_ptr, graph.col_idx, deg_np, bperm_np,
+                            layout)
+    # Phase-1 reply lanes: a home can receive at most one cell per owned
+    # vertex's reset and per bucket slot, and at most one per coupon
+    rep_cap = min(n_loc + layout.total_edges, S_loc_pad)
     return ThreePhasePlan(sg=sg, n_loc=n_loc, md=md, S_loc_pad=S_loc_pad,
                           S_total=S_total, rep_cap=rep_cap,
-                          route_cap2=int(route_cap2), cap2=int(cap2),
+                          route_cap2=route_cap2, cap2=cap2,
                           pool_pad=pool_pad, psize_sh=psize_sh,
                           pstart_sh=pstart_sh, layout=layout,
-                          bperm_np=bperm_np)
+                          bperm_np=bperm_np, bnbr_np=bnbr_np)
 
 
-def _three_phase_layouts(n: int, pool_np: np.ndarray, cap2: int):
+def _three_phase_layouts(n: int, pool_np: np.ndarray, cap2: Optional[int]):
     """Elastic layout schema per stage — shared by the phase-machine and
     the CONGEST auditor's schema lint. Declared per stage so snapshots are
     mesh-size-agnostic: a resume onto a different device count re-homes
@@ -534,6 +647,11 @@ def _three_phase_layouts(n: int, pool_np: np.ndarray, cap2: int):
     )
 
 
+def short_walk_length(n: int) -> int:
+    """Algorithm 2's lambda = ceil(sqrt(log n)): the steps of a coupon."""
+    return max(1, int(math.ceil(math.sqrt(math.log(max(n, 2))))))
+
+
 @dataclasses.dataclass
 class ImprovedDistResult:
     zeta: jnp.ndarray            # [n] global visit counts
@@ -542,7 +660,8 @@ class ImprovedDistResult:
     walks_per_node: int
     eps: float
     lam: int
-    eta: int
+    eta: int                     # Lemma 2's d(v)*eta, or 0: pools from
+                                 # the expected demand
     ell: int
     rounds: int                  # total supersteps across all phases
     phase1_rounds: int
@@ -570,10 +689,11 @@ class ImprovedDistResult:
     total_visits: int = 0
     restarts: int = 0            # supervisor recoveries (fault injection)
     checkpoints_written: int = 0
-    sampler_us: float = 0.0      # total wall time in the Phase-1 sampler
     p1_occupancy: tuple = ()     # per-bucket rows-with-coupons, summed over
                                  # rounds and shards (len = #buckets)
     residual: int = 0            # sampler conservation leak — must stay 0
+    unfinished: int = 0          # walks still out when a stage reached
+                                 # max_rounds (0 for a complete run)
 
 
 def distributed_improved_pagerank(
@@ -585,7 +705,6 @@ def distributed_improved_pagerank(
     mesh: Optional[Mesh] = None,
     lam: Optional[int] = None,
     eta: Optional[int] = None,
-    eta_safety: float = 2.0,
     cap2: Optional[int] = None,
     route_cap2: Optional[int] = None,
     max_rounds: int = 100_000,
@@ -600,25 +719,31 @@ def distributed_improved_pagerank(
 ) -> ImprovedDistResult:
     """Run Algorithm 2 across all devices of `mesh` (default: all devices).
 
-    `cap2`/`route_cap2` size only the naive-tail buffers (Phases 1-3 are
-    count-aggregated and size themselves). With `checkpoint_dir` and/or
-    `fail_at` set, the phase-machine runs under the checkpoint-restart
-    supervisor (see `_run_three_phase`). `bucketed=False` keeps the
-    single-bucket max_deg-wide Phase-1 sampler layout (the pre-bucketing
-    shape, for benchmarking); the draws are layout-independent."""
+    Coupon pools cover the expected demand
+    (`improved_pagerank.demand_pool_sizes`); an explicit `eta` gives
+    Lemma 2's d(v)*eta instead. `cap2`/`route_cap2` size only the
+    naive-tail buffers (by default from the walks it holds; Phases 1-3
+    are count-aggregated and size themselves). `max_rounds` caps the
+    rounds of each stage: one that reaches it stops, and the result
+    counts the walks still out (`unfinished`). With `checkpoint_dir`
+    and/or `fail_at` set, the phase-machine runs under the
+    checkpoint-restart supervisor (see `_run_three_phase`).
+    `bucketed=False` keeps the single-bucket max_deg-wide Phase-1 sampler
+    layout; the draws are layout-independent."""
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()), (AXIS,))
     key = key if key is not None else jax.random.PRNGKey(0)
     n = graph.n
     K = walks_per_node or walks_per_node_for(n, eps)
-    log_n = math.log(max(n, 2))
     if lam is None:
-        lam = max(1, int(math.ceil(math.sqrt(log_n))))
-    ell = max(lam + 1, int(math.ceil(log_n / eps)))
-    eta, pool_np = coupon_pool_sizes(graph, eps, K, lam, eta=eta,
-                                     eta_safety=eta_safety)
+        lam = short_walk_length(n)
+    ell = max(lam + 1, int(math.ceil(math.log(max(n, 2)) / eps)))
+    if eta is None:
+        pools = partial(demand_pool_sizes, graph, eps, K, lam)
+    else:
+        pools = lambda: coupon_pool_sizes(graph, eps, K, lam, eta=eta)[1]
     return _run_three_phase(
-        graph, eps, K, key, mesh, pool_np=pool_np, eta=int(eta),
+        graph, eps, K, key, mesh, pools=pools, eta=int(eta or 0),
         lam=int(lam), ell=int(ell), cap2=cap2, route_cap2=route_cap2,
         max_rounds=max_rounds, bandwidth_bits=bandwidth_bits,
         use_pallas=use_pallas, checkpoint_dir=checkpoint_dir,
@@ -633,7 +758,7 @@ def _run_three_phase(
     key: jnp.ndarray,
     mesh: Mesh,
     *,
-    pool_np: np.ndarray,
+    pools: Callable[[], np.ndarray],
     eta: int,
     lam: int,
     ell: int,
@@ -648,6 +773,7 @@ def _run_three_phase(
     max_restarts: int = 16,
     resume: bool = False,
     bucketed: bool = True,
+    name: str = "improved",
     result_cls: type = ImprovedDistResult,
     **extra_fields,
 ):
@@ -657,12 +783,20 @@ def _run_three_phase(
     The whole engine — Phase-1 count-aggregated short walks, Phase-2
     count-aggregated stitching, the Phase-3 one-shot counting exchange,
     the naive tail, and the host-float64 estimator — only ever sees the
-    per-node pool-size vector `pool_np`, never the policy that produced
-    it. `distributed_improved_pagerank` (Lemma 2, d(v)*eta) and
+    per-node pool-size vector `pools()` returns, never the policy that
+    produced it. `distributed_improved_pagerank` (expected demand, or
+    Lemma 2's d(v)*eta) and
     `distributed_directed.distributed_directed_pagerank` (Section 5,
     uniform budgets in the LOCAL model) are thin frontends over this core.
     `result_cls`/`extra_fields` let a frontend return a telemetry subclass
     of ImprovedDistResult.
+
+    A job is one `<name>.job` span of `runtime.tracing` (counts `rounds`,
+    `phase1_rounds`, `phase2_rounds`, `tail_rounds`, `tail_walks` and
+    `coupons`): `<name>.build` (pools, plan, placement, device puts),
+    one `round.<stage>` per round — a Phase-1 round holds `p1.request`,
+    `p1.sample`, `p1.assign` (the three dispatches) and `p1.sync` — then
+    `<name>.finish` (the estimator).
 
     Each phase is a `runtime.Stage` over a `StagedState` whose `arrays`
     hold the phase's device buffers and whose `host` dict holds the
@@ -689,18 +823,108 @@ def _run_three_phase(
     shards = int(mesh.devices.size)
     n = graph.n
     use_pallas = resolve_use_pallas(use_pallas)
+    with tracing.span(f"{name}.job"):
+        with tracing.span(f"{name}.build"):
+            pool_np = pools()
+            ms, schedule, _put, plan = _build_three_phase(
+                graph, eps, K, key, mesh, pool_np=pool_np, lam=lam,
+                cap2=cap2, route_cap2=route_cap2, max_rounds=max_rounds,
+                use_pallas=use_pallas, bucketed=bucketed)
 
+        # global rounds sum over the four stages, each bounded by
+        # max_rounds
+        ms, restarts, checkpoints_written = run_staged(
+            schedule, ms, _put, checkpoint_dir=checkpoint_dir,
+            fail_at=fail_at, checkpoint_every=checkpoint_every,
+            max_restarts=max_restarts, resume=resume,
+            max_rounds=(len(schedule.stages) * max_rounds
+                        + len(schedule.stages)),
+            tmp_prefix="pr3p_ckpt_")
+        h = ms.host
+        rounds = (h["phase1_rounds"] + h["report_rounds"]
+                  + h["phase2_rounds"] + h["phase3_rounds"]
+                  + h["tail_rounds"])
+        for k, v in dict(rounds=rounds, phase1_rounds=h["phase1_rounds"],
+                         phase2_rounds=h["phase2_rounds"],
+                         tail_rounds=h["tail_rounds"],
+                         tail_walks=h["tail_walks"],
+                         coupons=plan.S_total).items():
+            tracing.count(k, v)
+
+        with tracing.span(f"{name}.finish"):
+            # ------------- estimator: host float64 scaling -------------
+            zeta = ms.arrays["zeta"].reshape(-1)[:n]
+            pi = pagerank_from_visits(zeta, n, K, eps)
+            total_visits = int(np.asarray(zeta, dtype=np.int64).sum())
+            wire = h["wire"]
+            traces = [RoundTrace(active_walks=a, messages=m,
+                                 max_edge_count=1, total_count=m)
+                      for a, m in h["traces"]]
+            report = CongestReport(traces=traces, n=n,
+                                   bandwidth_bits=bandwidth_bits
+                                   or default_bandwidth(n))
+            return result_cls(
+                zeta=zeta, pi=pi, shards=shards, walks_per_node=K, eps=eps,
+                lam=int(lam), eta=int(eta), ell=int(ell), rounds=rounds,
+                phase1_rounds=h["phase1_rounds"],
+                report_rounds=h["report_rounds"],
+                phase2_rounds=h["phase2_rounds"],
+                phase3_rounds=h["phase3_rounds"],
+                tail_rounds=h["tail_rounds"],
+                stitch_iterations=h["phase2_rounds"],
+                exhausted_walks=h["exhausted"],
+                terminated_by_coupon=h["terminated"],
+                tail_walks=h["tail_walks"], coupons_created=plan.S_total,
+                coupons_used=h["coupons_used"], dropped=h["dropped"],
+                waited=h["waited"], a2a_bytes_total=sum(wire.values()),
+                a2a_bytes_by_phase=wire,
+                a2a_entries_by_site=dict(h["wire_entries"]),
+                phase2_records=h["phase2_records"], report=report,
+                total_visits=total_visits, restarts=restarts,
+                checkpoints_written=checkpoints_written,
+                p1_occupancy=tuple(h["p1_occupancy"]),
+                residual=int(h["residual"]), unfinished=h["unfinished"],
+                **extra_fields)
+
+
+_compiled: set = set()
+
+
+def _compile_concurrently(programs) -> None:
+    """Compile jitted programs for their arguments' shapes on threads of
+    their own: the compiler then works on them side by side (at 7.8M
+    coupons the five stage programs take about three minutes to compile
+    for a TPU v5e one after another, 75 s side by side), and each later
+    call with those shapes finds its program compiled. A program
+    compiled before is left alone."""
+    def sig(args):
+        return tuple((a.shape, str(a.dtype)) for a in args)
+
+    todo = [(f, args) for f, args in programs
+            if (f, sig(args)) not in _compiled]
+    if not todo:
+        return
+    with ThreadPoolExecutor(len(todo)) as pool:
+        list(pool.map(lambda fa: fa[0].lower(*fa[1]).compile(), todo))
+    _compiled.update((f, sig(args)) for f, args in todo)
+
+
+def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
+                       route_cap2, max_rounds, use_pallas, bucketed):
+    """The phase-machine of one job: (initial state, schedule, the
+    snapshot `put`, plan)."""
+    shards = int(mesh.devices.size)
+    n = graph.n
     # all static sizing comes from the shared plan (also what the CONGEST
     # auditor rebuilds — see ThreePhasePlan)
     plan = plan_three_phase(graph, shards, pool_np, K,
                             route_cap2=route_cap2, cap2=cap2,
                             bucketed=bucketed)
-    sg, n_loc, md = plan.sg, plan.n_loc, plan.md
+    sg, n_loc = plan.sg, plan.n_loc
     S_loc_pad, S_total = plan.S_loc_pad, plan.S_total
-    rep_cap, route_cap2, cap2 = plan.rep_cap, plan.route_cap2, plan.cap2
     pool_pad, psize_sh, pstart_sh = (plan.pool_pad, plan.psize_sh,
                                      plan.pstart_sh)
-    spec = NamedSharding(mesh, P(AXIS))
+    spec, rep = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
     sg_rp = jax.device_put(sg.row_ptr, spec)
     sg_ci = jax.device_put(sg.col_idx, spec)
     sg_dg = jax.device_put(sg.out_deg, spec)
@@ -708,13 +932,11 @@ def _run_three_phase(
     # ---- Phase-1 placement: slot s of shard p = p's s-th coupon, at its
     # source vertex; slots beyond S_loc[p] are padding (never allocated) --
     pos0 = np.full((shards, S_loc_pad), -1, dtype=np.int32)
-    slot_v_np = np.zeros((shards, S_loc_pad), dtype=np.int32)
     for p in range(shards):
         owned = pool_pad[p * n_loc:(p + 1) * n_loc]
         src = np.repeat(np.arange(p * n_loc, (p + 1) * n_loc,
                                   dtype=np.int32), owned)
         pos0[p, : len(src)] = src
-        slot_v_np[p, : len(src)] = src - p * n_loc
     # ---- Phase-2 placement: K long walks per real vertex (counts) ----
     walks0_np = np.zeros((shards, n_loc), dtype=np.int32)
     zeta3_np = np.zeros((shards, n_loc), np.int32)
@@ -730,22 +952,37 @@ def _run_three_phase(
     # ---- Phase-1 degree-bucketed sampler layout (static, memoized) ----
     layout = plan.layout
     bperm_j = jax.device_put(jnp.asarray(plan.bperm_np), spec)
+    bnbr_j = jax.device_put(jnp.asarray(plan.bnbr_np), spec)
 
     # ---- jitted per-phase step functions (shared by fresh + resumed) ----
     p1_req, p1_samp, p1_asn = _make_p1_steps(
-        mesh, eps=float(eps), n_loc=n_loc, shards=shards, md=md,
-        rep_cap=rep_cap, S_loc_pad=S_loc_pad, layout=layout,
+        mesh, eps=float(eps), n_loc=n_loc, shards=shards,
+        rep_cap=plan.rep_cap, S_loc_pad=S_loc_pad, layout=layout,
         use_pallas=use_pallas, count_bound=S_total)
     p2_step = _make_p2_step(mesh, n_loc=n_loc, shards=shards,
                             S_loc_pad=S_loc_pad, use_pallas=use_pallas,
                             count_bound=n * K)
     p3_step = _make_p3_step(mesh, n_loc=n_loc, shards=shards,
                             use_pallas=use_pallas, count_bound=S_total)
-    tail_step = _make_superstep(mesh, float(eps), n_loc, shards,
-                                int(route_cap2), 0, use_pallas=use_pallas)
     psize_j = jax.device_put(jnp.asarray(psize_sh, dtype=jnp.int32), spec)
     pstart_j = jax.device_put(jnp.asarray(pstart_sh, dtype=jnp.int32), spec)
-    slot_v_j = jax.device_put(jnp.asarray(slot_v_np), spec)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=spec)
+    vert, slot = sds((shards, n_loc)), sds((shards, S_loc_pad))
+    traj = sds((shards, S_loc_pad, lam))
+    _compile_concurrently([
+        (p1_req, (slot, slot)),
+        (p1_samp, (bperm_j, sg_dg, sds((shards, shards * n_loc)),
+                   sds((shards, 2), jnp.uint32))),
+        (p1_asn, (bperm_j, bnbr_j, sds((shards, shards * n_loc)),
+                  sds((shards, shards * (layout.total_rows
+                                         + layout.total_edges))),
+                  slot, slot, traj, sds((shards, 2), jnp.uint32),
+                  jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))),
+        (p2_step, (vert, vert, slot, vert, slot, slot, vert, vert)),
+        (p3_step, (traj, slot, vert)),
+    ])
 
     # ---------------- stage step functions + host transitions ----------
     # Telemetry lives in the JSON-able `host` dict so a restored snapshot
@@ -753,28 +990,29 @@ def _run_three_phase(
 
     def _phase1(ms: StagedState):
         a = ms.arrays
-        t = jnp.int32(ms.host["phase1_rounds"])
-        c, req_entries, req_bytes = p1_req(a["pos"], a["alive"])
-        t0 = time.perf_counter()
-        f_cnt, key1, k_perm, occ, residual = p1_samp(
-            bperm_j, sg_dg, c, a["key"])
-        jax.block_until_ready(f_cnt)
-        t1 = time.perf_counter()
-        pos, alive, traj, pending, overflow, rep_entries, rep_bytes = \
-            p1_asn(sg_rp, sg_ci, a["pos"], a["alive"], a["traj"],
-                   f_cnt, k_perm, t)
+        t = jax.device_put(np.int32(ms.host["phase1_rounds"]), rep)
+        with tracing.span("p1.request"):
+            c, hist, req_entries, req_bytes = p1_req(a["pos"], a["alive"])
+        with tracing.span("p1.sample"):
+            cells, key1, k_perm, occ, residual = p1_samp(
+                bperm_j, sg_dg, c, a["key"])
+        with tracing.span("p1.assign"):
+            pos, alive, traj, pending, overflow, rep_entries, rep_bytes = \
+                p1_asn(bperm_j, bnbr_j, hist, cells, a["pos"], a["alive"],
+                       a["traj"], k_perm, t)
         a.update(pos=pos, alive=alive, traj=traj, key=key1)
         # one device sync for all the round's telemetry, not one per value
-        (pending, overflow, req_e, req_b, rep_e, rep_b, occ_v,
-         res) = jax.device_get((pending, overflow, req_entries, req_bytes,
-                                rep_entries, rep_bytes, occ, residual))
+        with tracing.span("p1.sync"):
+            (pending, overflow, req_e, req_b, rep_e, rep_b, occ_v,
+             res) = jax.device_get((pending, overflow, req_entries,
+                                    req_bytes, rep_entries, rep_bytes, occ,
+                                    residual))
         h = ms.host
         h["phase1_rounds"] += 1
         h["dropped"] += int(overflow)
         h["wire"]["phase1"] += int(req_b) + int(rep_b)
         h["wire_entries"]["phase1_req"] += int(req_e)
         h["wire_entries"]["phase1_rep"] += int(rep_e)
-        h["sampler_us"] += (t1 - t0) * 1e6
         h["p1_occupancy"] = [int(x) + int(y)
                              for x, y in zip(h["p1_occupancy"], occ_v)]
         h["residual"] += int(res)
@@ -804,7 +1042,7 @@ def _run_three_phase(
         (walks, next_c, used, tail_cnt, active, stitched, terminated,
          exhausted, entries, nbytes) = p2_step(
             a["walks"], a["next_c"], a["used"], a["tail_cnt"], a["dest"],
-            a["cterm"], psize_j, pstart_j, slot_v_j)
+            a["cterm"], psize_j, pstart_j)
         a.update(walks=walks, next_c=next_c, used=used, tail_cnt=tail_cnt)
         # one device sync for all six telemetry scalars, not six
         active, stitched, terminated, exhausted, entries, nbytes = (
@@ -821,15 +1059,14 @@ def _run_three_phase(
             active=active, stitched=stitched,
             terminated=terminated, exhausted=exhausted))
         h["traces"].append([active, entries])
-        if active == 0:
+        if active and h["phase2_rounds"] >= max_rounds:
+            h["unfinished"] += active    # cut: these walks end here
             return ms, True
-        if h["phase2_rounds"] >= max_rounds:
-            raise RuntimeError("phase 2 did not converge within max_rounds")
-        return ms, False
+        return ms, active == 0
 
     def _after_phase2(ms: StagedState) -> StagedState:
         a = ms.arrays
-        ms.host["coupons_used"] = int(np.asarray(a["used"]).sum())
+        ms.host["coupons_used"] = int(jnp.sum(a["used"]))
         ms.arrays = dict(traj=a["traj"], used=a["used"], zeta=a["zeta"],
                          tail_cnt=a["tail_cnt"])
         return ms
@@ -851,15 +1088,16 @@ def _run_three_phase(
         a = ms.arrays
         h = ms.host
         tail_np = np.asarray(a["tail_cnt"])
-        pos_tail = np.full((shards, cap2), -1, dtype=np.int32)
+        h["tail_walks"] = int(tail_np.sum())
+        h["tail_active"] = h["tail_walks"]
+        buf, _ = _tail_sizes(h["tail_walks"], shards, cap2, route_cap2)
+        pos_tail = np.full((shards, buf), -1, dtype=np.int32)
         for p in range(shards):
             vids = np.repeat(
                 np.arange(p * n_loc, (p + 1) * n_loc, dtype=np.int32),
                 tail_np[p])
-            assert len(vids) <= cap2, "cap2 too small for tail placement"
+            assert len(vids) <= buf, "cap2 too small for tail placement"
             pos_tail[p, : len(vids)] = vids
-        h["tail_walks"] = int(tail_np.sum())
-        h["tail_active"] = h["tail_walks"]
         ms.arrays = dict(
             pos=jax.device_put(jnp.asarray(pos_tail), spec),
             zeta=a["zeta"],
@@ -870,10 +1108,11 @@ def _run_three_phase(
     def _tail(ms: StagedState):
         a = ms.arrays
         h = ms.host
-        if h["tail_active"]:
-            if h["tail_rounds"] >= max_rounds:
-                raise RuntimeError(
-                    "tail walks did not converge in max_rounds")
+        if h["tail_active"] and h["tail_rounds"] < max_rounds:
+            _, lanes = _tail_sizes(h["tail_walks"], shards, cap2,
+                                   route_cap2)
+            tail_step = _make_tail_step(mesh, float(eps), n_loc, shards,
+                                        lanes, use_pallas)
             tstate = DistState(pos=a["pos"], zeta=a["zeta"], key=a["key"],
                                round=a["round"], dropped=a["dropped"],
                                waited=a["waited"])
@@ -889,8 +1128,9 @@ def _run_three_phase(
             h["wire_entries"]["tail"] += entries
             h["traces"].append([active, entries])
             h["tail_active"] = active
-        if h["tail_active"]:
-            return ms, False
+            if active and h["tail_rounds"] < max_rounds:
+                return ms, False
+        h["unfinished"] += h["tail_active"]   # cut at max_rounds
         h["dropped"] += int(a["dropped"])
         h["waited"] += int(a["waited"])
         return ms, True
@@ -917,16 +1157,14 @@ def _run_three_phase(
         host=dict(phase1_rounds=0, report_rounds=0, phase2_rounds=0,
                   phase3_rounds=0, tail_rounds=0, dropped=0, waited=0,
                   stitches=0, terminated=0, exhausted=0, coupons_used=0,
-                  tail_walks=0, tail_active=0,
+                  tail_walks=0, tail_active=0, unfinished=0,
                   wire=dict(phase1=0, report=0, phase2=0, phase3=0, tail=0),
                   wire_entries=dict(phase1_req=0, phase1_rep=0, phase2=0,
                                     phase3=0, tail=0),
-                  sampler_us=0.0, p1_occupancy=[0] * len(layout.caps),
-                  residual=0,
+                  p1_occupancy=[0] * len(layout.caps), residual=0,
                   traces=[], phase2_records=[]),
         layouts=layouts, shards=shards)
 
-    # ---------------- drive: plain loop or checkpointing supervisor ----
     _scalar_keys = ("round", "dropped", "waited")
 
     def _put(name: str, arr: np.ndarray):
@@ -934,47 +1172,7 @@ def _run_three_phase(
             return jnp.asarray(arr)              # replicated scalars
         return jax.device_put(jnp.asarray(arr), spec)
 
-    # global rounds sum over the four stages, each bounded by max_rounds
-    # (the per-stage guards raise on divergence)
-    ms, restarts, checkpoints_written = run_staged(
-        schedule, ms, _put, checkpoint_dir=checkpoint_dir, fail_at=fail_at,
-        checkpoint_every=checkpoint_every, max_restarts=max_restarts,
-        resume=resume,
-        max_rounds=len(schedule.stages) * max_rounds + len(schedule.stages),
-        tmp_prefix="pr3p_ckpt_")
-
-    # ---------------- estimator: host float64 scaling ------------------
-    zeta = ms.arrays["zeta"].reshape(-1)[:n]
-    pi = pagerank_from_visits(zeta, n, K, eps)
-    total_visits = int(np.asarray(zeta, dtype=np.int64).sum())
-
-    h = ms.host
-    wire = h["wire"]
-    rounds = (h["phase1_rounds"] + h["report_rounds"] + h["phase2_rounds"]
-              + h["phase3_rounds"] + h["tail_rounds"])
-    traces = [RoundTrace(active_walks=a, messages=m, max_edge_count=1,
-                         total_count=m) for a, m in h["traces"]]
-    report = CongestReport(traces=traces, n=n,
-                           bandwidth_bits=bandwidth_bits
-                           or default_bandwidth(n))
-    return result_cls(
-        zeta=zeta, pi=pi, shards=shards, walks_per_node=K, eps=eps,
-        lam=int(lam), eta=int(eta), ell=int(ell), rounds=rounds,
-        phase1_rounds=h["phase1_rounds"], report_rounds=h["report_rounds"],
-        phase2_rounds=h["phase2_rounds"], phase3_rounds=h["phase3_rounds"],
-        tail_rounds=h["tail_rounds"], stitch_iterations=h["phase2_rounds"],
-        exhausted_walks=h["exhausted"],
-        terminated_by_coupon=h["terminated"], tail_walks=h["tail_walks"],
-        coupons_created=S_total, coupons_used=h["coupons_used"],
-        dropped=h["dropped"], waited=h["waited"],
-        a2a_bytes_total=sum(wire.values()), a2a_bytes_by_phase=wire,
-        a2a_entries_by_site=dict(h["wire_entries"]),
-        phase2_records=h["phase2_records"], report=report,
-        total_visits=total_visits, restarts=restarts,
-        checkpoints_written=checkpoints_written,
-        sampler_us=float(h["sampler_us"]),
-        p1_occupancy=tuple(h["p1_occupancy"]),
-        residual=int(h["residual"]), **extra_fields)
+    return ms, schedule, _put, plan
 
 
 # ---------------------------------------------------------------------------
@@ -1001,22 +1199,22 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
     shards = int(mesh.devices.size)
     n = graph.n
     plan = plan_three_phase(graph, shards, pool_np, K, bucketed=bucketed)
-    n_loc, md = plan.n_loc, plan.md
+    n_loc, layout = plan.n_loc, plan.layout
     S_loc_pad, S_total = plan.S_loc_pad, plan.S_total
     rep_cap = plan.rep_cap
 
     p1_req, p1_samp, p1_asn = _make_p1_steps(
-        mesh, eps=float(eps), n_loc=n_loc, shards=shards, md=md,
-        rep_cap=rep_cap, S_loc_pad=S_loc_pad, layout=plan.layout,
-        use_pallas=use_pallas, count_bound=S_total)
+        mesh, eps=float(eps), n_loc=n_loc, shards=shards, rep_cap=rep_cap,
+        S_loc_pad=S_loc_pad, layout=layout, use_pallas=use_pallas,
+        count_bound=S_total)
     p2_step = _make_p2_step(mesh, n_loc=n_loc, shards=shards,
                             S_loc_pad=S_loc_pad, use_pallas=use_pallas,
                             count_bound=n * K)
     p3_step = _make_p3_step(mesh, n_loc=n_loc, shards=shards,
                             use_pallas=use_pallas, count_bound=S_total)
     tail_cap = n_loc                       # auditor-pinned (walk-class)
-    tail_step = _make_superstep(mesh, float(eps), n_loc, shards,
-                                tail_cap, 0, use_pallas=use_pallas)
+    tail_step = _make_tail_step(mesh, float(eps), n_loc, shards, tail_cap,
+                                use_pallas)
 
     sds = jax.ShapeDtypeStruct
     i32, u32 = jnp.int32, jnp.uint32
@@ -1029,8 +1227,11 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
     traj = sds((shards, S_loc_pad, int(lam)), i32)
     key = sds((shards, 2), u32)
     bperm = sds(plan.bperm_np.shape, plan.bperm_np.dtype)
+    bnbr = sds(plan.bnbr_np.shape, plan.bnbr_np.dtype)
     c = sds((shards, shards * n_loc), i32)
-    f_cnt = sds((shards, shards * n_loc * (md + 1)), i32)
+    hist = sds((shards, shards * n_loc), i32)
+    cells = sds((shards, shards * (layout.total_rows + layout.total_edges)),
+                i32)
     t = sds((), i32)
     vert = sds((shards, n_loc), i32)
     slot = sds((shards, S_loc_pad), i32)
@@ -1044,11 +1245,12 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
     rep_site = ExchangeSite(
         site="phase1_rep", entry_nbytes=12,
         lane_entries=shards * rep_cap,
-        budget_entries=shards * n_loc * (md + 1),
-        budget_formula=("P * min(n_loc*(max_deg+1), S_loc_pad) distinct "
-                        "(vertex, class, count) cells <= P*n_loc*(md+1)"),
+        budget_entries=shards * (n_loc + layout.total_edges),
+        budget_formula=("P * min(n_loc + slots, S_loc_pad) distinct "
+                        "(vertex, destination, offset) cells <= "
+                        "P * (n_loc + slots), slots = bucketed out-edges"),
         wire_class="count",
-        note="stacked F=3 lanes (vertex, outcome class, count)")
+        note="stacked F=3 lanes (vertex, destination, offset in its row)")
     tail_site = ExchangeSite(
         site="tail", entry_nbytes=4, lane_entries=shards * tail_cap,
         budget_entries=shards * n_loc,
@@ -1064,12 +1266,15 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
         StageProgram(stage="phase1", program="sample", fn=p1_samp,
                      example_args=(bperm, dg, c, key), sites=(),
                      count_bound=S_total),
+        # one shard replies to itself: no wire (see `_p1_assign`)
         StageProgram(stage="phase1", program="assign", fn=p1_asn,
-                     example_args=(rp, ci, pos, alive, traj, f_cnt, key, t),
-                     sites=(rep_site,), count_bound=S_total),
+                     example_args=(bperm, bnbr, hist, cells, pos, alive,
+                                   traj, key, t),
+                     sites=(rep_site,) if shards > 1 else (),
+                     count_bound=S_total),
         StageProgram(stage="phase2", program="stitch", fn=p2_step,
                      example_args=(vert, vert, slot, vert, slot, slot,
-                                   vert, vert, slot),
+                                   vert, vert),
                      sites=(ExchangeSite(site="phase2", **_count),),
                      count_bound=n * K),
         StageProgram(stage="phase3", program="count", fn=p3_step,
@@ -1090,21 +1295,20 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
             "tail": ("pos", "zeta", "key", "round", "dropped", "waited"),
         },
         layouts=_three_phase_layouts(n, pool_np, plan.cap2),
-        meta=dict(shards=shards, n=graph.n, K=K, lam=int(lam), md=md,
-                  rep_cap=rep_cap, S_loc_pad=S_loc_pad, S_total=S_total))
+        meta=dict(shards=shards, n=graph.n, K=K, lam=int(lam),
+                  md=plan.md, rep_cap=rep_cap, S_loc_pad=S_loc_pad,
+                  S_total=S_total))
 
 
 def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
                walks_per_node: int = 2, use_pallas: bool = False,
                bucketed: bool = True):
-    """Lemma-2 (degree-proportional pools) frontend of the 3-phase audit
-    spec — mirrors `distributed_improved_pagerank`'s sizing exactly."""
-    n = graph.n
+    """Frontend of the 3-phase audit spec with the pools
+    `distributed_improved_pagerank` uses by default (expected demand) —
+    mirrors its sizing exactly."""
     K = walks_per_node
-    log_n = math.log(max(n, 2))
-    lam = max(1, int(math.ceil(math.sqrt(log_n))))
-    _, pool_np = coupon_pool_sizes(graph, eps, K, lam)
-    return three_phase_audit_spec(graph, mesh, eps=eps, K=K,
-                                  pool_np=pool_np, lam=lam,
-                                  engine="improved", use_pallas=use_pallas,
-                                  bucketed=bucketed)
+    lam = short_walk_length(graph.n)
+    return three_phase_audit_spec(
+        graph, mesh, eps=eps, K=K,
+        pool_np=demand_pool_sizes(graph, eps, K, lam), lam=lam,
+        engine="improved", use_pallas=use_pallas, bucketed=bucketed)

@@ -86,12 +86,16 @@ available on the returned `ImprovedDistResult`/`DirectedDistResult`):
                  `waited` counts tail-lane carry-overs.
   budget         (`directed` only) the uniform per-node coupon budget and
                  the dangling-node count (out-degree 0, immediate reset).
-  sampler        (`counts`, `improved`, `directed`) degree-bucketed
-                 aggregate-sampler telemetry: total and per-round wall
-                 microseconds inside the sample program, per-bucket
-                 occupancy (rows holding coupons, summed over rounds and
-                 shards; bucket b covers degrees in (2^(b-1), 2^b]), and
-                 the conservation residual (must be 0).
+  spans          (`counts`, `improved`, `directed`) each program span
+                 inside the job (`runtime.tracing`), with its count and
+                 mean milliseconds: for the 3-phase engines the build,
+                 `round.<stage>` per round, a Phase-1 round's
+                 `p1.request` / `p1.sample` / `p1.assign` / `p1.sync`,
+                 and the finish; then the sampler's per-bucket occupancy
+                 (rows holding coupons, summed over rounds and shards;
+                 bucket b covers degrees in (2^(b-1), 2^b]), the
+                 conservation residual (must be 0), and the walks a stage
+                 cut at max_rounds (`unfinished`, 0 for a complete run).
 
 `--algo ppr` runs the batched Personalized-PageRank engine
 (`repro.core.personalized_batch`): `--queries` seed-derived multi-source
@@ -119,7 +123,8 @@ the RNG / dtype / elastic-schema lints run over the same traces, the
 engines execute on fixture graphs to cross-check the static widths
 against runtime telemetry, and AUDIT.json is written next to the table.
 Non-zero exit on any violation. Per-engine wire budgets (P = shards,
-n_loc = ceil(n/P), md = max degree, Q = PPR query slots; every entry is
+n_loc = ceil(n/P), slots = the degree-bucketed adjacency's out-edge
+slots per shard, Q = PPR query slots; every entry is
 a Lemma-1 (vertex, count) cell except the walk-class lanes, whose caps
 the auditor pins at n_loc so the checked capacity stays W-free):
 
@@ -127,7 +132,7 @@ the auditor pins at n_loc so the checked capacity stays W-free):
   walks     route          4      P * n_loc walk slots       [walk-class]
   counts    counts         4      P * min(cut_max, n_loc) cells
   improved  phase1_req     8      P * n_loc cells
-            phase1_rep    12      P * n_loc * (md+1) (vertex,class,count)
+            phase1_rep    12      P * (n_loc + slots) (vertex,dest,offset)
             phase2         8      P * n_loc cells
             phase3         8      P * n_loc cells
             tail           4      P * n_loc walk slots       [walk-class]
@@ -303,10 +308,10 @@ def run_ppr(g, eps: float, walks_per_query: int, num_queries: int,
     return res.ppr
 
 
-def _span_totals(records) -> str:
+def _span_totals(records, job: str = "counts.job") -> str:
     """Count and mean milliseconds of each span name inside the newest
-    `counts.job` span, in the order the names first open."""
-    job = [r for r in records if r.name == "counts.job"][-1]
+    `job` span, in the order the names first open."""
+    job = [r for r in records if r.name == job][-1]
     inside, totals = {job.id}, {}
     for r in records:          # recorded as they open: parents first
         if r.parent in inside:
@@ -385,10 +390,10 @@ def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
               f"{res.exhausted_walks} tail_walks={res.tail_walks}")
         print(f"[pagerank] wire by phase: {res.a2a_bytes_by_phase} "
               f"dropped={res.dropped} waited={res.waited}")
-        print(f"[pagerank] p1 sampler: {res.sampler_us:.0f} us total "
-              f"({res.sampler_us / max(res.phase1_rounds, 1):.0f} us/round)"
-              f" bucket_occupancy={list(res.p1_occupancy)} "
-              f"residual={res.residual}")
+        print(f"[pagerank] spans: "
+              f"{_span_totals(tracing.spans(), algo + '.job')}; "
+              f"bucket_occupancy={list(res.p1_occupancy)} "
+              f"residual={res.residual} unfinished={res.unfinished}")
         if algo == "directed":
             print(f"[pagerank] uniform budget={res.uniform_budget} "
                   f"coupons/node dangling_nodes={res.dangling_nodes}")

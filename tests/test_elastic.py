@@ -364,21 +364,19 @@ ELASTIC_CODE = textwrap.dedent("""
                                          np.asarray(r.pi))))
     out["counts"] = res
 
-    # improved: eta_safety=8.0 drives tail_walks to 0, so the run past
-    # Phase 1 is RNG-free — a mid-Phase-2 kill resumed on a shrunk mesh
-    # must reproduce the unfailed run bit-exactly
+    # improved: the default pools cover the walks, so tail_walks is 0 and
+    # the run past Phase 1 is RNG-free — a mid-Phase-2 kill resumed on a
+    # shrunk mesh must reproduce the unfailed run bit-exactly
     g2 = erdos_renyi(96, 5.0, seed=1)
-    ref2 = distributed_improved_pagerank(g2, 0.25, 40, jax.random.PRNGKey(0),
-                                         eta_safety=8.0)
+    ref2 = distributed_improved_pagerank(g2, 0.25, 40, jax.random.PRNGKey(0))
     mid_p2 = (ref2.phase1_rounds + ref2.report_rounds
               + max(ref2.phase2_rounds // 2, 1))
     d2 = tempfile.mkdtemp(prefix="elastic_improved_")
     died2 = kill(distributed_improved_pagerank, g2, 40, jax.random.PRNGKey(0),
-                 d2, [mid_p2], eta_safety=8.0)
+                 d2, [mid_p2])
     r2 = distributed_improved_pagerank(g2, 0.25, 40, jax.random.PRNGKey(0),
                                        mesh=submesh(4), checkpoint_dir=d2,
-                                       resume=True, checkpoint_every=2,
-                                       eta_safety=8.0)
+                                       resume=True, checkpoint_every=2)
     out["improved"] = dict(
         died=died2, fail_at=mid_p2, tail_walks=ref2.tail_walks,
         shards=r2.shards, restarts=r2.restarts, dropped=r2.dropped,
@@ -513,8 +511,9 @@ def test_counts_elastic_resume_bit_exact(target, payload):
 
 
 def test_improved_midphase2_elastic_resume_bit_exact(payload):
-    """Phase 2 is RNG-free (and tail empty at eta_safety=8): a mid-Phase-2
-    kill resumed on 4 shards reproduces the 8-shard run bit for bit."""
+    """Phase 2 is RNG-free (and the tail empty under pools that cover the
+    walks): a mid-Phase-2 kill resumed on 4 shards reproduces the 8-shard
+    run bit for bit."""
     r = payload["improved"]
     assert r["died"], r
     assert r["tail_walks"] == 0, r       # precondition for exactness
