@@ -18,6 +18,9 @@ Per super-step, per shard:
      (exact Multinomial — same sampler as engine_counts)
   3. per-edge counts aggregated per destination *vertex* and exchanged with
      one all_to_all of (vertex, count) lanes               (Lemma 1 wire)
+     — the aggregation is a sort of the counts by their static
+     destination, a cumsum, and a take at each vertex's segment end
+     (fixed at shard time), not a scatter-add
   4. arrivals summed into counts + visit counters zeta
 
 Steps 1-2 run through the shared degree-bucketed aggregate sampler
@@ -37,8 +40,9 @@ round's counters); a profiler trace times each on the device
 telemetry dict next to the wire counters (`occupancy`).
 
 A job is one `counts.job` span of `runtime.tracing` (count `rounds`):
-`counts.build` (host build and placement; count `sampler_depth`, the
-sampler's sequential split levels a round), one `round.counts` per round
+`counts.build` (host build and placement; counts `sampler_depth`, the
+sampler's sequential split levels a round, and `exchange_sort_entries`,
+the slots the exchange sorts a round), one `round.counts` per round
 holding `counts.sample`, `counts.exchange` (the two dispatches) and
 `counts.sync` (count `active`, walks alive after the round), then
 `counts.finish`.
@@ -85,7 +89,11 @@ class ShardedPaddedGraph:
     layout: BucketLayout    # shard-uniform bucket caps/widths (static)
     bperm: np.ndarray       # [P, layout.total_rows] bucket-grouped local
                             # row ids (-1 = padding slot)
-    bnbr: np.ndarray        # [P, layout.total_edges] flat bucketed dst
+    bnbr: np.ndarray        # [P, layout.total_edges] flat bucketed global
+                            # dst (n_pad = padding slot)
+    bends: np.ndarray       # [P, n_pad + 1] int32: bends[p, v] = shard p's
+                            # real slots with dst < v, so vertex v's slots
+                            # are [bends[v], bends[v + 1]) in dst order
 
 
 def shard_graph_padded(graph: CSRGraph, shards: int, *,
@@ -105,11 +113,28 @@ def shard_graph_padded(graph: CSRGraph, shards: int, *,
     # lanes hold (vertex,count) pairs: at most min(cut, n_loc) distinct
     lane_cap = int(min(cut.max(initial=0), n_loc)) or 1
     layout, bperm = build_layout_sharded(deg_sh, md, bucketed=bucketed)
-    bnbr = bucketize_csr(graph.row_ptr, col, deg_sh, bperm, layout)
+    # padding slots key past the last vertex: they sort last and fall in
+    # no vertex's segment
+    bnbr = bucketize_csr(graph.row_ptr, col, deg_sh, bperm, layout,
+                         pad_dst=n_pad)
+    bends = np.zeros((shards, n_pad + 1), np.int32)
+    for p in range(shards):
+        bends[p, 1:] = np.cumsum(np.bincount(bnbr[p], minlength=n_pad + 1)
+                                 [:n_pad])
     return ShardedPaddedGraph(
         n=graph.n, n_pad=n_pad, n_loc=n_loc, shards=shards, max_deg=md,
         deg=deg_sh, lane_cap=lane_cap, layout=layout, bperm=bperm,
-        bnbr=bnbr)
+        bnbr=bnbr, bends=bends)
+
+
+def check_sum_exact(total_walks: int) -> None:
+    """The exchange's int32 cumsum over a round's per-slot counts is exact
+    while the walks in flight, at most n * walks, stay below 2^31; a job
+    that could wrap is refused rather than run."""
+    if total_walks >= 1 << 31:
+        raise ValueError(
+            f"the counts exchange sums in int32 and needs n * walks < 2^31; "
+            f"got n * walks = {total_walks}")
 
 
 # Packed lanes carry (local vid:16b | count:15b) in one int32, plus one
@@ -131,6 +156,16 @@ def resolve_packed(packed: Optional[bool], n_loc: int,
             f"packed count lanes need n_loc <= 65536 and n * walks <= "
             f"{2 * _PACK_CMAX}; got n_loc={n_loc}, walks={total_walks}")
     return bool(packed)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class ExchangeIndex:
+    """The exchange's static first argument, fixed for a job: each slot's
+    global destination (`ShardedPaddedGraph.bnbr`) and each vertex's
+    segment ends in destination order (`ShardedPaddedGraph.bends`)."""
+    bnbr: jnp.ndarray     # [P, layout.total_edges]
+    ends: jnp.ndarray     # [P, n_pad + 1]
 
 
 @jax.tree_util.register_dataclass
@@ -165,35 +200,43 @@ def _sample_step(bperm, deg, counts, key, *, eps: float, n_loc: int,
     return flat_T[None], key[None], occ, residual
 
 
-def _exchange_step(bnbr, flat_T, zeta, *, n_loc: int, shards: int,
-                   lane_cap: int, packed: bool = True):
+def sorted_segment_sums(dst, ends, values):
+    """Per-vertex sums of per-slot `values`: sort them by their static
+    destination `dst`, cumsum, and difference the prefix sums at each
+    vertex's segment `ends` ([n + 1], vertex v's slots are
+    [ends[v], ends[v + 1]) in dst order). Slots keyed past the last
+    vertex sort after every segment and count for none. Integer sums do
+    not depend on order, so this equals a scatter-add exactly; on the chip
+    a sort moves data several times faster than a scatter or a gather."""
+    _, v = jax.lax.sort((dst, values), num_keys=1)
+    csum = jnp.cumsum(v)
+    at = jnp.where(ends > 0, csum[jnp.maximum(ends - 1, 0)], 0)
+    return at[1:] - at[:-1]
+
+
+def _exchange_step(index: ExchangeIndex, flat_T, zeta, *, n_loc: int,
+                   shards: int, lane_cap: int, packed: bool = True):
     """Program 2 of the super-step: aggregate per destination vertex and
     run the Lemma-1 (vertex, count) lane exchange."""
-    bnbr, flat_T, zeta = bnbr[0], flat_T[0], zeta[0]
+    bnbr, ends = index.bnbr[0], index.ends[0]
+    flat_T, zeta = flat_T[0], zeta[0]
     shard_id = jax.lax.axis_index(AXIS)
 
-    flat_dst = bnbr
-    owner = flat_dst // n_loc
-    local_mask = owner == shard_id
-    # local arrivals: direct segment-sum
-    arrive = jax.ops.segment_sum(
-        jnp.where(local_mask, flat_T, 0),
-        jnp.clip(flat_dst - shard_id * n_loc, 0, n_loc - 1),
-        num_segments=n_loc)
+    # this shard's counts summed per global destination vertex
+    per_vertex = sorted_segment_sums(bnbr, ends, flat_T)
     if shards == 1:
         # one shard owns every vertex: no lane can fill, so skip the lane
         # packing below (a stable sort and scatters over every vertex)
         zero = jnp.int32(0)
-        return (arrive[None], (zeta + arrive)[None],
-                jax.lax.psum(jnp.sum(arrive), AXIS), zero, zero, zero)
+        return (per_vertex[None], (zeta + per_vertex)[None],
+                jax.lax.psum(jnp.sum(per_vertex), AXIS), zero, zero, zero)
 
-    # cross-shard: aggregate counts per destination vertex, then lane-pack
-    # (vertex, count) per target shard. Aggregate first so the lane bound
-    # is #distinct vertices, not #edges.
-    remote_T = jnp.where(local_mask, 0, flat_T)
-    per_vertex = jax.ops.segment_sum(remote_T, flat_dst,
-                                     num_segments=n_loc * shards)
+    # local arrivals are the own slice; the rest is lane-packed as
+    # (vertex, count) per target shard. Aggregating first bounds the lanes
+    # by #distinct vertices, not #edges.
+    arrive = jax.lax.dynamic_slice(per_vertex, (shard_id * n_loc,), (n_loc,))
     vid = jnp.arange(n_loc * shards, dtype=jnp.int32)
+    per_vertex = jnp.where(vid // n_loc == shard_id, 0, per_vertex)
     if packed:
         # 4B lanes: (local vid:16b | count:15b) — 15-bit count keeps the
         # packed int32 non-negative (-1 stays the empty sentinel); larger
@@ -285,9 +328,9 @@ def make_count_superstep(mesh: Mesh, eps: float, *, n_loc: int, shards: int,
         return sample_sh(bperm, deg, state.counts, state.key)
 
     @jax.jit
-    def exchange(bnbr, flat_T, key, state: CountDistState):
+    def exchange(index: ExchangeIndex, flat_T, key, state: CountDistState):
         counts, zeta, active, entries, a2a, overflow = exch_sh(
-            bnbr, flat_T, state.zeta)
+            index, flat_T, state.zeta)
         return (CountDistState(counts=counts, zeta=zeta, key=key,
                                round=state.round + 1),
                 active, entries, a2a, overflow)
@@ -357,8 +400,10 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
     shards = mesh.devices.size
     with tracing.span("counts.job"):
         with tracing.span("counts.build"):
+            check_sum_exact(graph.n * walks_per_node)
             sg = shard_graph_padded(graph, shards, bucketed=bucketed)
             tracing.count("sampler_depth", sg.layout.depth)
+            tracing.count("exchange_sort_entries", sg.layout.total_edges)
             packed = resolve_packed(packed, sg.n_loc,
                                     graph.n * walks_per_node)
             spec = NamedSharding(mesh, P(AXIS))
@@ -371,7 +416,8 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
             keys = np.tile(np.asarray(key)[None], (shards, 1))
             deg = jax.device_put(sg.deg, spec)
             bperm = jax.device_put(sg.bperm, spec)
-            bnbr = jax.device_put(sg.bnbr, spec)
+            index = ExchangeIndex(bnbr=jax.device_put(sg.bnbr, spec),
+                                  ends=jax.device_put(sg.bends, spec))
             arrays = dict(counts=jax.device_put(counts0, spec),
                           zeta=jax.device_put(counts0, spec),
                           key=jax.device_put(keys, spec),
@@ -388,8 +434,8 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
             with tracing.span("counts.sample"):
                 flat_T, key2, occ, residual = sample(bperm, deg, st)
             with tracing.span("counts.exchange"):
-                st, active, entries, a2a, ovf = exchange(bnbr, flat_T, key2,
-                                                         st)
+                st, active, entries, a2a, ovf = exchange(index, flat_T,
+                                                         key2, st)
             a.update(counts=st.counts, zeta=st.zeta, key=st.key,
                      round=st.round)
             with tracing.span("counts.sync"):
@@ -463,7 +509,8 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
                            round=sds((), i32))
     bperm = sds((shards, sg.bperm.shape[1]), sg.bperm.dtype)
     deg = sds((shards, n_loc), sg.deg.dtype)
-    bnbr = sds((shards, sg.bnbr.shape[1]), sg.bnbr.dtype)
+    index = ExchangeIndex(bnbr=sds(sg.bnbr.shape, sg.bnbr.dtype),
+                          ends=sds(sg.bends.shape, sg.bends.dtype))
     flat_T = sds((shards, sg.layout.total_edges), i32)
     key = sds((shards, 2), u32)
     width = 4 if packed else 8
@@ -482,7 +529,7 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
                      count_bound=graph.n * walks_per_node),
         # a one-shard exchange has no wire (see `_exchange_step`)
         StageProgram(stage="counts", program="exchange", fn=exchange,
-                     example_args=(bnbr, flat_T, key, state),
+                     example_args=(index, flat_T, key, state),
                      sites=(site,) if shards > 1 else (),
                      count_bound=graph.n * walks_per_node),
     ]

@@ -124,13 +124,15 @@ def test_counts_job_records_build_rounds_and_finish():
 
 def test_build_counts_the_sampler_depth():
     """`counts.build` carries `sampler_depth`: the split levels a round
-    of the layout's deepest bucket, ceil(log2) of its widest bucket."""
+    of the layout's deepest bucket, ceil(log2) of its widest bucket (and
+    the exchange's sorted slots, `exchange_sort_entries`)."""
     g = barabasi_albert_hub(96, 3, seed=4)
     distributed_pagerank_counts(g, 0.3, 4, jax.random.PRNGKey(4))
     (build,) = _by_name(tracing.spans(), "counts.build")
     layout = shard_graph_padded(g, jax.device_count()).layout
     want = int(np.ceil(np.log2(max(layout.widths))))
-    assert build.counts == dict(sampler_depth=want)
+    assert build.counts == dict(sampler_depth=want,
+                                exchange_sort_entries=layout.total_edges)
     assert want == int(np.ceil(np.log2(g.max_out_deg))) >= 4
 
 
