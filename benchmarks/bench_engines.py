@@ -1,4 +1,4 @@
-"""Engine throughput: counts vs walk-array vs Pallas-fused vs power-iter.
+"""Engine throughput: counts vs walk-array vs power-iter.
 
 Walks/second (steady-state, jit-compiled) for the faithful count engine and
 the TPU-native walk engine; power-iteration L1-convergence wall time as the
@@ -36,13 +36,6 @@ def run(n=512, eps=0.2, K=100):
         g.row_ptr, g.col_idx, g.out_deg, eps, s)[0])
     dt = _time(lambda: jax.block_until_ready(step(state).zeta))
     rows.append(dict(name="walk_array_step", us=dt * 1e6,
-                     walks_per_s=W / dt))
-
-    # walk-array engine with Pallas fused step + histogram
-    step_p = jax.jit(lambda s: engine_walks._step_core(
-        g.row_ptr, g.col_idx, g.out_deg, eps, s, use_pallas=True)[0])
-    dt = _time(lambda: jax.block_until_ready(step_p(state).zeta), iters=2)
-    rows.append(dict(name="walk_array_step_pallas_interp", us=dt * 1e6,
                      walks_per_s=W / dt))
 
     # count engine, one round

@@ -124,7 +124,7 @@ print(json.dumps(dict(
 def run(shard_counts=(1, 8), smoke=False):
     rows = []
     for p in shard_counts:
-        env = dict(os.environ)  # REPRO_USE_PALLAS etc. propagate
+        env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
         env["PYTHONPATH"] = SRC
         res = subprocess.run(

@@ -13,7 +13,6 @@ CSV:
   bench_engines     — engine throughput (counts vs walk-array vs baseline)
   bench_distributed — multi-shard wire volume: walk-routing vs count lanes
   bench_serve       — PPR query serving: Poisson traffic qps + latency
-  bench_kernels     — Pallas kernel micro-benches + TPU roofline estimates
   roofline_report   — dry-run roofline aggregation (all cells)
 """
 import os
@@ -28,7 +27,6 @@ MODULES = [
     "benchmarks.bench_engines",
     "benchmarks.bench_distributed",
     "benchmarks.bench_serve",
-    "benchmarks.bench_kernels",
     "benchmarks.roofline_report",
 ]
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")

@@ -14,11 +14,7 @@ Phases, in one process (a chip belongs to one process at a time):
      answers 16 seeded queries of 1 to 3 sources through `submit` and
      `drain` on the same kind of graph cut to n = 2^20 (see
      `SERVE_LOG2_N`); each answer is checked against a plain personalized
-     power iteration run on the device;
-  4. kernels: the counts engine at n = 2^14 with `use_pallas=True` and
-     `False`; both pass the gate, the compiled sample program must hold
-     the Pallas kernels as `tpu_custom_call`s, and whether the two runs
-     are bit-identical is reported.
+     power iteration run on the device.
 
 `--four-chips` runs only the counts engine on `Mesh(devices[:4])` and on
 `Mesh(devices[:1])` with one seed and graph (n = 2^20), and requires
@@ -47,7 +43,6 @@ from jax.sharding import Mesh  # noqa: E402
 
 from repro.core import l1_error, normalized, topk_overlap  # noqa: E402
 from repro.core.distributed import AXIS  # noqa: E402
-from repro.core.distributed_counts import audit_spec  # noqa: E402
 from repro.graphs import directed_web  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.pagerank import L1_TOL, TOPK_MIN, run  # noqa: E402
@@ -65,7 +60,6 @@ NUM_QUERIES = 16
 # chip, over the script's 1200 s limit: every superstep routes and sorts
 # n * SLOTS entries, so the phase scales with n. At 2^20 it is a quarter.
 SERVE_LOG2_N = 20
-KERNEL_LOG2_N = 14
 # 4 chips cost four times as much per second; 2^20 keeps the comparison
 # of the two meshes short
 FOUR_CHIP_LOG2_N = 20
@@ -108,13 +102,13 @@ def build_graph(log2_n: int):
     return g
 
 
-def _counts(g, walks_per_node: int, *, shards: int, use_pallas=None):
+def _counts(g, walks_per_node: int, *, shards: int):
     """One gated counts run through the launch entry point; returns the
     run and its time net of the power-iteration reference."""
     t0 = time.perf_counter()
     r = run(g.n, EPS, walks_per_node, "directed_web", None, [], seed=SEED,
             algo="counts", avg_deg=AVG_DEG, check=True, shards=shards,
-            use_pallas=use_pallas, graph=g)
+            graph=g)
     seconds = time.perf_counter() - t0 - r.accuracy["seconds"]
     _require(r.engine.overflow == 0, f"overflow={r.engine.overflow}")
     _require(r.engine.residual == 0, f"residual={r.engine.residual}")
@@ -215,24 +209,6 @@ def serve_phase(g, *, slots: int = SLOTS,
     return out
 
 
-def kernel_phase(log2_n: int = KERNEL_LOG2_N,
-                 walks_per_node: int = WALKS_PER_NODE) -> dict:
-    g = build_graph(log2_n)
-    zeta = {}
-    for use_pallas in (True, False):
-        r, _ = _counts(g, walks_per_node, shards=1, use_pallas=use_pallas)
-        zeta[use_pallas] = np.asarray(r.engine.zeta, np.int64)
-    diff = np.abs(zeta[True] - zeta[False]).sum() / zeta[False].sum()
-    mesh = Mesh(np.array(jax.devices()[:1]), (AXIS,))
-    sample = audit_spec(g, mesh, eps=EPS, walks_per_node=walks_per_node,
-                        use_pallas=True).programs[0]
-    hlo = sample.fn.lower(*sample.example_args).compile().as_text()
-    out = dict(n=g.n, bit_identical=bool(diff == 0), zeta_l1=float(diff),
-               tpu_custom_calls=hlo.count('custom_call_target="tpu_custom_call"'))
-    print(f"[smoke] kernels: {out}", flush=True)
-    return out
-
-
 def four_chip_phase(g, walks_per_node: int = WALKS_PER_NODE) -> dict:
     devs = jax.devices()
     _require(len(devs) >= 4, f"--four-chips needs 4 devices, have "
@@ -269,9 +245,6 @@ def main(argv=None) -> None:
         _phase_done("batch", t0)
         serve_phase(build_graph(SERVE_LOG2_N))
         _phase_done("serve", t0)
-        kernels = kernel_phase()
-        _require(kernels["tpu_custom_calls"] > 0,
-                 "no tpu_custom_call in the compiled sample program")
     _phase_done("all phases", t0)
     print(json.dumps({"ok": True, "device": device}))
 

@@ -28,8 +28,6 @@ def main() -> int:
                     help="forced host device count (shards)")
     ap.add_argument("--engines", nargs="*", default=None,
                     help="subset of engines (default: all five)")
-    ap.add_argument("--use-pallas", action="store_true",
-                    help="audit the pallas variants of the hot paths")
     ap.add_argument("--no-telemetry", action="store_true",
                     help="static checks only (skip the fixture runs)")
     ap.add_argument("--eps", type=float, default=0.2)
@@ -46,7 +44,6 @@ def main() -> int:
     from repro.analysis.congest import audit_all_engines, format_wire_table
 
     report = audit_all_engines(
-        use_pallas=args.use_pallas,
         run_telemetry=not args.no_telemetry,
         eps=args.eps, walks_per_node=args.walks_per_node,
         engines=tuple(args.engines) if args.engines else None)

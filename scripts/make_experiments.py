@@ -203,9 +203,8 @@ def main():
         "",
         "Hardware target: TPU v5e pods (256 chips/pod; 512 across 2 pods). "
         "This container is CPU-only: dry-run compiles use "
-        "`--xla_force_host_platform_device_count=512`; Pallas kernels "
-        "validate in interpret mode; CONGEST claims validated by the "
-        "accounting layer (DESIGN.md §2).",
+        "`--xla_force_host_platform_device_count=512`; CONGEST claims "
+        "validated by the accounting layer (DESIGN.md §2).",
         "",
     ]
     if os.path.exists(os.path.join(ROOT, "results", "paper_validation.md")):

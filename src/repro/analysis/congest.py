@@ -300,26 +300,23 @@ def _site_widths(spec: EngineAuditSpec) -> Dict[str, int]:
     return {s.site: s.entry_nbytes for p in spec.programs for s in p.sites}
 
 
-def _telemetry_walks(graph, mesh, spec, eps, K, use_pallas):
+def _telemetry_walks(graph, mesh, spec, eps, K):
     from repro.core.distributed import distributed_pagerank
     res = distributed_pagerank(graph, eps, walks_per_node=K,
-                               key=jax.random.PRNGKey(0), mesh=mesh,
-                               use_pallas=use_pallas)
+                               key=jax.random.PRNGKey(0), mesh=mesh)
     w = _site_widths(spec)["route"]
     return [_check("route", res.a2a_bytes_total, res.a2a_entries_total, w)]
 
 
-def _telemetry_counts(graph, mesh, spec, eps, K, use_pallas):
+def _telemetry_counts(graph, mesh, spec, eps, K):
     from repro.core.distributed_counts import distributed_pagerank_counts
     res = distributed_pagerank_counts(graph, eps, walks_per_node=K,
-                                      key=jax.random.PRNGKey(0), mesh=mesh,
-                                      use_pallas=use_pallas)
+                                      key=jax.random.PRNGKey(0), mesh=mesh)
     w = _site_widths(spec)["counts"]
     return [_check("counts", res.a2a_bytes_total, res.a2a_entries_total, w)]
 
 
-def _telemetry_three_phase(graph, mesh, spec, eps, K, use_pallas, *,
-                           directed: bool):
+def _telemetry_three_phase(graph, mesh, spec, eps, K, *, directed: bool):
     if directed:
         from repro.core.distributed_directed import \
             distributed_directed_pagerank as run
@@ -327,7 +324,7 @@ def _telemetry_three_phase(graph, mesh, spec, eps, K, use_pallas, *,
         from repro.core.distributed_improved import \
             distributed_improved_pagerank as run
     res = run(graph, eps, walks_per_node=K, key=jax.random.PRNGKey(0),
-              mesh=mesh, use_pallas=use_pallas)
+              mesh=mesh)
     w = _site_widths(spec)
     w.setdefault("phase1_rep", 0)   # one shard replies without a wire
     wire, ent = res.a2a_bytes_by_phase, res.a2a_entries_by_site
@@ -352,12 +349,12 @@ def _telemetry_three_phase(graph, mesh, spec, eps, K, use_pallas, *,
     return checks
 
 
-def _telemetry_ppr(graph, mesh, spec, eps, K, use_pallas):
+def _telemetry_ppr(graph, mesh, spec, eps, K):
     from repro.core.personalized_batch import batched_personalized_pagerank
     res = batched_personalized_pagerank(
         graph, eps, queries=[([0], None), ([1, 2], None)],
         walks_per_query=spec.meta["walks_per_query"],
-        key=jax.random.PRNGKey(1), mesh=mesh, use_pallas=use_pallas)
+        key=jax.random.PRNGKey(1), mesh=mesh)
     w = _site_widths(spec)["ppr"]
     return [_check("ppr", res.a2a_bytes, res.a2a_entries, w)]
 
@@ -376,34 +373,28 @@ def _fixture_for(engine: str):
     return erdos_renyi(96, 5.0, seed=1), "erdos_renyi(96, 5.0, seed=1)"
 
 
-def _spec_for(engine: str, graph, mesh, *, eps: float, K: int,
-              use_pallas: bool) -> EngineAuditSpec:
+def _spec_for(engine: str, graph, mesh, *, eps: float,
+              K: int) -> EngineAuditSpec:
     if engine == "walks":
         from repro.core.distributed import audit_spec
-        return audit_spec(graph, mesh, eps=eps, walks_per_node=K,
-                          use_pallas=use_pallas)
+        return audit_spec(graph, mesh, eps=eps, walks_per_node=K)
     if engine == "counts":
         from repro.core.distributed_counts import audit_spec
-        return audit_spec(graph, mesh, eps=eps, walks_per_node=K,
-                          use_pallas=use_pallas)
+        return audit_spec(graph, mesh, eps=eps, walks_per_node=K)
     if engine == "improved":
         from repro.core.distributed_improved import audit_spec
-        return audit_spec(graph, mesh, eps=eps, walks_per_node=K,
-                          use_pallas=use_pallas)
+        return audit_spec(graph, mesh, eps=eps, walks_per_node=K)
     if engine == "directed":
         from repro.core.distributed_directed import audit_spec
-        return audit_spec(graph, mesh, eps=eps, walks_per_node=K,
-                          use_pallas=use_pallas)
+        return audit_spec(graph, mesh, eps=eps, walks_per_node=K)
     if engine == "ppr":
         from repro.core.personalized_batch import audit_spec
-        return audit_spec(graph, mesh, eps=eps, walks_per_query=4 * K,
-                          use_pallas=use_pallas)
+        return audit_spec(graph, mesh, eps=eps, walks_per_query=4 * K)
     raise ValueError(f"unknown engine '{engine}' (one of {ENGINES})")
 
 
-def audit_all_engines(mesh=None, *, use_pallas: bool = False,
-                      run_telemetry: bool = True, eps: float = 0.2,
-                      walks_per_node: int = 2,
+def audit_all_engines(mesh=None, *, run_telemetry: bool = True,
+                      eps: float = 0.2, walks_per_node: int = 2,
                       engines: Optional[Tuple[str, ...]] = None
                       ) -> Dict[str, Any]:
     """Audit every distributed engine; returns the AUDIT.json dict.
@@ -420,36 +411,31 @@ def audit_all_engines(mesh=None, *, use_pallas: bool = False,
         mesh = Mesh(np.array(jax.devices()), (AXIS,))
     shards = int(mesh.devices.size)
     K = walks_per_node
-    report: Dict[str, Any] = dict(devices=shards, use_pallas=use_pallas,
-                                  eps=eps, walks_per_node=K, engines={})
+    report: Dict[str, Any] = dict(devices=shards, eps=eps,
+                                  walks_per_node=K, engines={})
     total = 0
     for engine in (engines or ENGINES):
         graph, fixture = _fixture_for(engine)
-        spec = _spec_for(engine, graph, mesh, eps=eps, K=K,
-                         use_pallas=use_pallas)
+        spec = _spec_for(engine, graph, mesh, eps=eps, K=K)
         entry = audit_engine_spec(spec)
         entry["fixture"] = fixture
 
-        spec_hi = _spec_for(engine, graph, mesh, eps=eps, K=2 * K,
-                            use_pallas=use_pallas)
+        spec_hi = _spec_for(engine, graph, mesh, eps=eps, K=2 * K)
         w_violations = check_w_independence(spec, spec_hi)
         entry["w_independent"] = not w_violations
         entry["violations"].extend(v.to_dict() for v in w_violations)
 
         if run_telemetry:
             if engine == "walks":
-                checks = _telemetry_walks(graph, mesh, spec, eps, K,
-                                          use_pallas)
+                checks = _telemetry_walks(graph, mesh, spec, eps, K)
             elif engine == "counts":
-                checks = _telemetry_counts(graph, mesh, spec, eps, K,
-                                           use_pallas)
+                checks = _telemetry_counts(graph, mesh, spec, eps, K)
             elif engine in ("improved", "directed"):
                 checks = _telemetry_three_phase(
-                    graph, mesh, spec, eps, K, use_pallas,
+                    graph, mesh, spec, eps, K,
                     directed=engine == "directed")
             else:
-                checks = _telemetry_ppr(graph, mesh, spec, eps, K,
-                                        use_pallas)
+                checks = _telemetry_ppr(graph, mesh, spec, eps, K)
             entry["telemetry"] = dict(checks=checks,
                                       ok=all(c["ok"] for c in checks))
             for c in checks:

@@ -14,12 +14,9 @@ property the paper's CONGEST model assumes. The grouping is a STATIC
 permutation computed on the host at shard/build time and memoized (like
 the engines' step makers).
 
-The split is the binomial tree of `kernels/multinomial_rows/_math`. The
-XLA path runs each level of it once for all buckets
-(`_math.split_tree`), so a round is `BucketLayout.depth` =
-ceil(log2 max_deg) sequential levels; the Pallas path calls the
-`kernels.multinomial_rows` kernel once per bucket. Both draw the same
-counter-RNG tree, so `use_pallas` never changes the draws.
+The split is the binomial tree of `core/binomial_tree`, each level run
+once for all buckets (`binomial_tree.split_tree`), so a round is
+`BucketLayout.depth` = ceil(log2 max_deg) sequential levels.
 
 Sharded engines run ONE traced program on every shard, so bucket
 capacities must be shard-uniform: `build_layout_sharded` takes the max
@@ -39,9 +36,7 @@ from typing import List, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.multinomial_rows import multinomial_rows
-from repro.kernels.multinomial_rows._math import (split_tree, termination,
-                                                  tree_depth)
+from repro.core.binomial_tree import split_tree, termination, tree_depth
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,8 +71,8 @@ class BucketLayout:
 
     @property
     def depth(self) -> int:
-        """Sequential split levels a round on the XLA path: the deepest
-        bucket's tree, ceil(log2 max width)."""
+        """Sequential split levels a round: the deepest bucket's tree,
+        ceil(log2 max width)."""
         return tree_depth(max(self.widths))
 
     def tile(self, copies: int) -> "BucketLayout":
@@ -189,7 +184,7 @@ def bucketize_csr(row_ptr: np.ndarray, col_idx: np.ndarray,
 
 
 def sample_buckets(counts, deg, rid, key_words, perm, layout: BucketLayout,
-                   *, eps: float, use_pallas: bool
+                   *, eps: float
                    ) -> Tuple[List[Tuple[jnp.ndarray, jnp.ndarray]],
                               jnp.ndarray, jnp.ndarray]:
     """Run the fused sampler over every bucket of `layout`.
@@ -210,17 +205,12 @@ def sample_buckets(counts, deg, rid, key_words, perm, layout: BucketLayout,
     c, d, r = (jnp.where(ok, x[safe], 0) for x in (counts, deg, rid))
     cut = lambda x: [x[s:s + cap] for s, cap in zip(layout.row_starts,
                                                      layout.caps)]
-    if use_pallas:
-        Ts = [multinomial_rows(c_b, d_b, r_b, key_words, eps=eps, width=w)
-              for c_b, d_b, r_b, w in zip(cut(c), cut(d), cut(r),
-                                          layout.widths)]
-    else:
-        k0, k1 = key_words[0], key_words[1]
-        term, rem = termination(c, d, r, k0, k1, eps=eps)
-        slots = split_tree(cut(rem), cut(d), cut(r),
-                           [tree_depth(w) for w in layout.widths], k0, k1)
-        Ts = [jnp.concatenate([t[None, :], s[:w]], axis=0).T
-              for t, s, w in zip(cut(term), slots, layout.widths)]
+    k0, k1 = key_words[0], key_words[1]
+    term, rem = termination(c, d, r, k0, k1, eps=eps)
+    slots = split_tree(cut(rem), cut(d), cut(r),
+                       [tree_depth(w) for w in layout.widths], k0, k1)
+    Ts = [jnp.concatenate([t[None, :], s[:w]], axis=0).T
+          for t, s, w in zip(cut(term), slots, layout.widths)]
     occ = jnp.stack([jnp.sum(c_b > 0) for c_b in cut(c)]).astype(jnp.int32)
     residual = jnp.sum(c) - sum(jnp.sum(T) for T in Ts)
     return list(zip(cut(rows), Ts)), occ, residual

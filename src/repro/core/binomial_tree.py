@@ -1,10 +1,7 @@
-"""Shared sampling math for the fused aggregate-multinomial kernel.
-
-Everything here is plain jnp on arrays, so the SAME functions run inside
-the Pallas kernel body and in the pure-jnp oracle — `use_pallas` switches
-only the execution path, never the draws, which keeps the engines
-bit-identical across the flag (the repo-wide kernel contract, see
-`tests/test_kernels.py::test_engine_pallas_bit_parity`).
+"""The aggregate-multinomial sampler's math: a Binomial(eps) termination
+and a binomial-tree split of the survivors over each row's out-edges, as
+plain jnp on whole row vectors (`core/aggregate_sampler` runs it over
+the degree buckets).
 
 RNG contract — counter-based, per row:
   u(row, t) = u01(fmix32(fmix32((rid * C1) ^ k0) + ((t * C2) ^ k1)))
@@ -14,8 +11,7 @@ PRNG key. t = 0 is the eps-termination draw; the split of the slot
 interval [i * 2^k, (i + 1) * 2^k) draws t = 32 * i + k (k >= 1), a
 function of the interval alone. Draws are pure functions of
 (k0, k1, rid, t): no split-chain threading, so rows sample independently
-in any blocking/order — exactly what a row-blocked kernel needs — and
-replay/checkpoint-recovery stays bit-exact.
+in any blocking/order, and replay/checkpoint-recovery stays bit-exact.
 
 Survivors split over a row's out-edge slots by a binomial tree: the
 width is padded to a power of two and every dyadic interval sends
@@ -71,7 +67,7 @@ def counter_u01(rid, t, k0, k1):
     h = _fmix32((_u32(rid) * _u32(0x9E3779B1)) ^ _u32(k0))
     h = _fmix32(h + ((_u32(t) * _u32(0x85EBCA77)) ^ _u32(k1)))
     # 24 mantissa bits, offset half a ulp: strictly inside (0, 1)
-    # via int32 (exact below 2**24): Mosaic has no uint32 -> float32 cast
+    # via int32, exact below 2**24
     return (((h >> _u32(8)).astype(jnp.int32).astype(jnp.float32) + 0.5)
             * jnp.float32(2.0 ** -24))
 

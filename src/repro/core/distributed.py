@@ -39,7 +39,6 @@ from repro.core.graph import CSRGraph
 from repro.core.routing import (advance_owned, count_owned_arrivals,
                                 merge_walks, rank_within, route_walks,
                                 shard_map)
-from repro.kernels import resolve_use_pallas
 
 AXIS = "shards"
 
@@ -98,8 +97,7 @@ class DistState:
 
 
 def _superstep_local(rp, ci, dg, pos, key, zeta, eps: float, n_loc: int,
-                     shards: int, route_cap: int, work_cap: int,
-                     use_pallas: bool = False):
+                     shards: int, route_cap: int, work_cap: int):
     """One super-step on a single shard (runs under shard_map).
 
     Inputs arrive with a leading size-1 shard dim (shard_map blocks);
@@ -114,8 +112,7 @@ def _superstep_local(rp, ci, dg, pos, key, zeta, eps: float, n_loc: int,
         route_cap=route_cap)
     arrived = recv >= 0
     # count arrivals (they are owned by me by construction)
-    zeta = zeta + count_owned_arrivals(arrived, recv, shard_id, n_loc,
-                                       use_pallas=use_pallas)
+    zeta = zeta + count_owned_arrivals(arrived, recv, shard_id, n_loc)
 
     # ---- merge buffer: kept walks + arrivals, compact into cap slots ----
     pos, _, dropped = merge_walks(kept, {}, recv, {}, pos.shape[0])
@@ -128,13 +125,11 @@ def _superstep_local(rp, ci, dg, pos, key, zeta, eps: float, n_loc: int,
     owned_rank, _ = rank_within(jnp.where(owned, 0, 1).astype(jnp.int32))
     stepped = owned & (owned_rank < work_cap) if work_cap else owned
     survive, dst = advance_owned(rp, ci, dg, pos, stepped, k_term, k_edge,
-                                 eps, shard_id, n_loc,
-                                 use_pallas=use_pallas)
+                                 eps, shard_id, n_loc)
     new_pos = jnp.where(survive, dst, jnp.where(stepped, -1, pos))
     # intra-shard arrivals counted immediately
     local_arrival = survive & (dst // n_loc == shard_id)
-    zeta = zeta + count_owned_arrivals(local_arrival, dst, shard_id, n_loc,
-                                       use_pallas=use_pallas)
+    zeta = zeta + count_owned_arrivals(local_arrival, dst, shard_id, n_loc)
 
     # global (replicated) scalar stats
     active = jax.lax.psum(jnp.sum(new_pos >= 0), AXIS)
@@ -152,11 +147,9 @@ def _superstep_local(rp, ci, dg, pos, key, zeta, eps: float, n_loc: int,
 # when callers rebuild the mesh over the same devices)
 @lru_cache(maxsize=64)
 def _make_superstep(mesh: Mesh, eps: float, n_loc: int, shards: int,
-                    route_cap: int, work_cap: int,
-                    use_pallas: bool = False):
+                    route_cap: int, work_cap: int):
     fn = partial(_superstep_local, eps=eps, n_loc=n_loc, shards=shards,
-                 route_cap=route_cap, work_cap=work_cap,
-                 use_pallas=use_pallas)
+                 route_cap=route_cap, work_cap=work_cap)
     sharded = shard_map(
         fn, mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
@@ -201,14 +194,8 @@ def distributed_pagerank(
     route_cap: Optional[int] = None,
     work_cap: int = 0,
     max_rounds: int = 100_000,
-    use_pallas: Optional[bool] = None,
 ) -> DistributedResult:
-    """Run Algorithm 1 across all devices of `mesh` (default: all devices).
-
-    `use_pallas=None` defers to the REPRO_USE_PALLAS env var; True routes
-    the per-shard walk advancement and visit histograms through the Pallas
-    kernels (bit-identical to the jnp path, interpret mode off-TPU)."""
-    use_pallas = resolve_use_pallas(use_pallas)
+    """Run Algorithm 1 across all devices of `mesh` (default: all devices)."""
     if mesh is None:
         devs = np.array(jax.devices())
         mesh = Mesh(devs, (AXIS,))
@@ -246,8 +233,7 @@ def distributed_pagerank(
     sg_dg = jax.device_put(sg.out_deg, spec)
 
     step = _make_superstep(mesh, float(eps), sg.n_loc, shards,
-                           int(route_cap), int(work_cap),
-                           use_pallas=use_pallas)
+                           int(route_cap), int(work_cap))
     a2a_total = 0
     entries_total = 0
     rounds = 0
@@ -296,8 +282,7 @@ def state_from_host(d: dict, mesh: Mesh) -> DistState:
 # --------------------------------------------------------------------------
 
 def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
-               walks_per_node: int = 2, work_cap: int = 0,
-               use_pallas: bool = False):
+               walks_per_node: int = 2, work_cap: int = 0):
     """The walk engine's `EngineAuditSpec` for the CONGEST auditor.
 
     The runtime `route_cap` scales with W/P, so this engine's lanes are
@@ -315,7 +300,7 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
     route_cap = n_loc
     cap = n_loc
     step = _make_superstep(mesh, float(eps), n_loc, shards, route_cap,
-                           int(work_cap), use_pallas=use_pallas)
+                           int(work_cap))
     sds = jax.ShapeDtypeStruct
     i32, u32 = jnp.int32, jnp.uint32
     state = DistState(pos=sds((shards, cap), i32),

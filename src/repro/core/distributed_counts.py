@@ -30,9 +30,8 @@ the step makers), and each bucket's tree spans the bucket width instead
 of the global max degree — per-round sampler FLOPs ~ sum_v deg(v), not
 n_loc * max_deg. Sampler RNG contract: draws are a pure counter-based
 function of (per-round key words, global row id = padded vertex id, slot
-index) — see `kernels/multinomial_rows/_math` — so rows sample
-independently of bucket order and blocking, `use_pallas` (kernel vs jnp
-ref) never changes the draws, and checkpoint replay stays bit-exact.
+index) — see `core/binomial_tree` — so rows sample independently of
+bucket order and blocking, and checkpoint replay stays bit-exact.
 The super-step is two jitted programs, sample then exchange, dispatched
 back to back with one host sync per round (the `device_get` of the
 round's counters); a profiler trace times each on the device
@@ -62,13 +61,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.aggregate_sampler import (BucketLayout, build_layout_sharded,
                                           bucketize_csr, flatten_moves,
                                           sample_buckets)
+from repro.core.binomial_tree import key_words
 from repro.core.distributed import AXIS, shard_map
 from repro.core.estimator import pagerank_from_visits
 from repro.core.graph import CSRGraph
 from repro.core.routing import entry_nbytes, lane_slots
 from repro.checkpoint import LayoutSpec
-from repro.kernels import resolve_use_pallas
-from repro.kernels.multinomial_rows._math import key_words
 from repro.runtime import Stage, StagedState, StageSchedule, run_staged
 from repro.runtime import tracing
 
@@ -178,7 +176,7 @@ class CountDistState:
 
 
 def _sample_step(bperm, deg, counts, key, *, eps: float, n_loc: int,
-                 shards: int, layout: BucketLayout, use_pallas: bool):
+                 shards: int, layout: BucketLayout):
     """Program 1 of the super-step: the degree-bucketed aggregate draw.
 
     Pure per-shard compute (no collectives beyond the telemetry psums).
@@ -192,8 +190,7 @@ def _sample_step(bperm, deg, counts, key, *, eps: float, n_loc: int,
     # rid: globally-unique padded vertex id -> draws independent per vertex
     rid = shard_id * n_loc + jnp.arange(n_loc, dtype=jnp.int32)
     samples, occ, residual = sample_buckets(
-        counts, deg, rid, key_words(k_sample), bperm, layout,
-        eps=eps, use_pallas=use_pallas)
+        counts, deg, rid, key_words(k_sample), bperm, layout, eps=eps)
     flat_T = flatten_moves(samples)
     occ = jax.lax.psum(occ, AXIS)
     residual = jax.lax.psum(residual, AXIS)
@@ -304,13 +301,13 @@ def _exchange_step(index: ExchangeIndex, flat_T, zeta, *, n_loc: int,
 @lru_cache(maxsize=64)
 def make_count_superstep(mesh: Mesh, eps: float, *, n_loc: int, shards: int,
                          layout: BucketLayout, lane_cap: int,
-                         packed: bool = True, use_pallas: bool = False):
+                         packed: bool = True):
     """Returns (sample, exchange): the two jitted halves of the super-step.
     Their names are what a profiler trace calls them (`jit_sample`,
     `jit_exchange`)."""
     sample_sh = shard_map(
         partial(_sample_step, eps=eps, n_loc=n_loc, shards=shards,
-                layout=layout, use_pallas=use_pallas),
+                layout=layout),
         mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS), P(), P()),
@@ -374,7 +371,7 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
                                 checkpoint_every: int = 10,
                                 max_restarts: int = 16,
                                 resume: bool = False,
-                                use_pallas=None,
+                                use_pallas: bool = False,
                                 bucketed: bool = True) -> CountDistResult:
     """Count-aggregated Algorithm 1 across all devices of `mesh`.
 
@@ -393,10 +390,16 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
     independent), and the state declares its layout schema, so
     `resume=True` onto a mesh with a different device count re-layouts
     the snapshot and continues BIT-EXACTLY — same zeta/pi as the
-    uninterrupted run at the original shard count."""
+    uninterrupted run at the original shard count.
+
+    `use_pallas` does nothing and a true value is refused: the engine has
+    one implementation per stage. The keyword stays only until the
+    benchmark's configurations and drivers stop passing `use_pallas`."""
+    if use_pallas:
+        raise ValueError("use_pallas=True: the Pallas kernels were removed; "
+                         "every stage runs its XLA implementation")
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()), (AXIS,))
-    use_pallas = resolve_use_pallas(use_pallas)
     shards = mesh.devices.size
     with tracing.span("counts.job"):
         with tracing.span("counts.build"):
@@ -424,8 +427,7 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
                           round=jnp.int32(0))
             sample, exchange = make_count_superstep(
                 mesh, float(eps), n_loc=sg.n_loc, shards=sg.shards,
-                layout=sg.layout, lane_cap=sg.lane_cap, packed=packed,
-                use_pallas=use_pallas)
+                layout=sg.layout, lane_cap=sg.lane_cap, packed=packed)
 
         def _step(ms: StagedState):
             a = ms.arrays
@@ -488,7 +490,7 @@ def distributed_pagerank_counts(graph: CSRGraph, eps: float,
 
 def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
                walks_per_node: int = 2, packed: Optional[bool] = None,
-               use_pallas: bool = False, bucketed: bool = True):
+               bucketed: bool = True):
     """CONGEST-auditor spec: the exact memoized step programs the engine
     runs (same cache keys => same traced jaxprs), the declared wire budget
     for the single (vertex, count) all_to_all, and the elastic schema."""
@@ -500,7 +502,7 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
     n_loc = sg.n_loc
     sample, exchange = make_count_superstep(
         mesh, float(eps), n_loc=n_loc, shards=shards, layout=sg.layout,
-        lane_cap=sg.lane_cap, packed=packed, use_pallas=use_pallas)
+        lane_cap=sg.lane_cap, packed=packed)
     sds = jax.ShapeDtypeStruct
     i32, u32 = jnp.int32, jnp.uint32
     state = CountDistState(counts=sds((shards, n_loc), i32),

@@ -92,7 +92,6 @@ def distributed_directed_pagerank(
     route_cap2: Optional[int] = None,
     max_rounds: int = 100_000,
     bandwidth_bits: Optional[int] = None,
-    use_pallas: Optional[bool] = None,
     checkpoint_dir: Optional[str] = None,
     fail_at: Optional[Sequence[int]] = None,
     checkpoint_every: int = 10,
@@ -123,7 +122,7 @@ def distributed_directed_pagerank(
         graph, eps, K, key, mesh, pools=lambda: pool_np, eta=int(eta),
         lam=int(lam), ell=int(ell), cap2=cap2, route_cap2=route_cap2,
         max_rounds=max_rounds, bandwidth_bits=bandwidth_bits,
-        use_pallas=use_pallas, checkpoint_dir=checkpoint_dir,
+        checkpoint_dir=checkpoint_dir,
         fail_at=fail_at, checkpoint_every=checkpoint_every,
         max_restarts=max_restarts, resume=resume, name="directed",
         result_cls=DirectedDistResult,
@@ -132,8 +131,7 @@ def distributed_directed_pagerank(
 
 
 def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
-               walks_per_node: int = 2, use_pallas: bool = False,
-               bucketed: bool = True):
+               walks_per_node: int = 2, bucketed: bool = True):
     """Section-5 frontend of the 3-phase audit spec: identical supersteps,
     uniform (LOCAL-model) coupon pools and the longer Section-5 lam —
     mirrors `distributed_directed_pagerank`'s sizing exactly."""
@@ -146,5 +144,4 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
                                    degree_proportional=False, ell=ell)
     return three_phase_audit_spec(graph, mesh, eps=eps, K=K,
                                   pool_np=pool_np, lam=lam,
-                                  engine="directed",
-                                  use_pallas=use_pallas, bucketed=bucketed)
+                                  engine="directed", bucketed=bucketed)

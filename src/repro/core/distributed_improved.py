@@ -35,11 +35,11 @@ Phase 1 — short-walk pre-computation. Shard p owns the coupons of its
       spanning the bucket width instead of the global max degree, so
       Phase-1 sampler FLOPs are ~ sum_v deg(v) per round. RNG contract:
       counter-based draws keyed on (round key words, globally-unique row
-      id, slot) — see `kernels/multinomial_rows/_math` — so the results
-      are independent of bucket layout and of `use_pallas`, and replay
-      stays bit-exact. The outcomes stay in the sampler's own layout:
-      per home, one reset count per bucket row and one count per bucket
-      slot (`aggregate_sampler.bucketize_csr` gives each slot's
+      id, slot) — see `core/binomial_tree` — so the results are
+      independent of bucket layout, and replay stays bit-exact. The
+      outcomes stay in the sampler's own layout: per home, one reset
+      count per bucket row and one count per bucket slot
+      (`aggregate_sampler.bucketize_csr` gives each slot's
       destination), so no Phase-1 array grows with n_loc * max_deg;
     reply   — nonzero (vertex, destination, offset) cells go back to the
       home shard (12 B/entry), the destination -2 for "terminated" and
@@ -98,11 +98,6 @@ and `distributed_directed.distributed_directed_pagerank` feeds it
 the Section-5 uniform/LOCAL pools — count aggregation removed the
 worst-case per-walk buffers that engine used to need.
 
-`use_pallas` routes the histograms, the count reductions, and the tail's
-walk advancement through the Pallas kernels in `repro.kernels`
-(bit-identical decision logic, interpret mode off-TPU); `None` defers to
-the REPRO_USE_PALLAS env var.
-
 Fault tolerance — the driver is a *checkpointable phase-machine*: each
 phase (phase1, phase2, phase3, tail) is a named `runtime.Stage` whose
 snapshot is the stage's device buffers (coupon tables, trajectory table,
@@ -130,6 +125,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.accounting import CongestReport, RoundTrace, default_bandwidth
 from repro.core.aggregate_sampler import (build_layout_sharded,
                                           bucketize_csr, sample_buckets)
+from repro.core.binomial_tree import key_words
 from repro.core.distributed import (AXIS, DistState, _make_superstep,
                                     shard_graph, shard_map)
 from repro.core.estimator import pagerank_from_visits
@@ -140,8 +136,6 @@ from repro.core.routing import (entry_nbytes, exchange_stacked, lane_slots,
                                 pack_lanes, route_counts, vertex_histogram)
 from repro.checkpoint import LayoutSpec
 from repro.core.simple_pagerank import walks_per_node_for
-from repro.kernels import resolve_use_pallas
-from repro.kernels.multinomial_rows._math import key_words
 from repro.runtime import Stage, StagedState, StageSchedule, run_staged
 from repro.runtime import tracing
 
@@ -200,8 +194,7 @@ def _group_order(group, key):
                              jnp.arange(group.shape[0], dtype=jnp.int32))
 
 
-def _p1_request(pos, alive, *, n_loc: int, shards: int, use_pallas: bool,
-                count_bound: Optional[int] = None):
+def _p1_request(pos, alive, *, n_loc: int, shards: int):
     """Phase-1 program 1 (request): per-vertex live-coupon counts to the
     owners. Returns c[home * n_loc + v] = coupons of `home` currently at
     owned vertex v, and this home's own histogram over all n_pad vertices
@@ -209,10 +202,10 @@ def _p1_request(pos, alive, *, n_loc: int, shards: int, use_pallas: bool,
     pos, alive = pos[0], alive[0]
     shard_id = jax.lax.axis_index(AXIS)
     n_pad = shards * n_loc
-    req = vertex_histogram(pos, alive > 0, n_pad, use_pallas=use_pallas)
+    req = vertex_histogram(pos, alive > 0, n_pad)
     c_by_home, req_entries, req_bytes = route_counts(
         req, axis=AXIS, shard_id=shard_id, n_loc=n_loc, shards=shards,
-        by_source=True, use_pallas=use_pallas, count_bound=count_bound)
+        by_source=True)
     c = c_by_home.reshape(-1)               # [P*n_loc], row = home*n_loc + v
     req_entries = jax.lax.psum(req_entries, AXIS)
     req_bytes = jax.lax.psum(req_bytes, AXIS)
@@ -220,7 +213,7 @@ def _p1_request(pos, alive, *, n_loc: int, shards: int, use_pallas: bool,
 
 
 def _p1_sample(bperm, dg, c, key, *, eps: float, n_loc: int, shards: int,
-               layout, use_pallas: bool):
+               layout):
     """Phase-1 program 2 (sample): the owner draws, independently for every
     (home, vertex) row, the fused Binomial(eps) termination + conditional-
     binomial edge split through the shared degree-bucketed sampler (a
@@ -233,7 +226,7 @@ def _p1_sample(bperm, dg, c, key, *, eps: float, n_loc: int, shards: int,
     RNG contract: every draw is a pure counter-based function of the
     per-round key words, rid = owner*n_pad + home*n_loc + v (globally
     unique per row), and the slot index — independent of bucket order,
-    so bucketed/unbucketed layouts and kernel/ref paths are bit-identical.
+    so bucketed/unbucketed layouts are bit-identical.
     """
     bperm, dg, c, key = bperm[0], dg[0], c[0], key[0]
     shard_id = jax.lax.axis_index(AXIS)
@@ -255,8 +248,7 @@ def _p1_sample(bperm, dg, c, key, *, eps: float, n_loc: int, shards: int,
     deg_row = jnp.tile(dg, shards)
     rid = shard_id * n_pad + jnp.arange(n_pad, dtype=jnp.int32)
     samples, occ, residual = sample_buckets(
-        c, deg_row, rid, key_words(k_sample), perm_t, layout_t,
-        eps=eps, use_pallas=use_pallas)
+        c, deg_row, rid, key_words(k_sample), perm_t, layout_t, eps=eps)
     blocks = [T.reshape(shards, cap, w + 1)
               for (_, T), cap, w in zip(samples, layout.caps, layout.widths)]
     cells = jnp.concatenate(
@@ -352,22 +344,17 @@ def _p1_assign(bperm, bnbr, hist, cells, pos, alive, traj, k_perm, t, *,
 # the same devices hit the cache even when the caller rebuilds the mesh.
 @lru_cache(maxsize=64)
 def _make_p1_steps(mesh: Mesh, *, eps: float, n_loc: int, shards: int,
-                   rep_cap: int, S_loc_pad: int, layout, use_pallas: bool,
-                   count_bound: Optional[int] = None):
+                   rep_cap: int, S_loc_pad: int, layout):
     """Returns (request, sample, assign): the three jitted programs of one
     Phase-1 round, named in a device trace `jit_p1_request`,
-    `jit_p1_sample` and `jit_p1_assign`. `count_bound` is the declared
-    upper bound on any routed count (the coupon-pool total) — forwarded
-    to the count reductions so the f32 segment kernel is bypassed when it
-    could truncate (> 2^24)."""
+    `jit_p1_sample` and `jit_p1_assign`."""
     req_sh = shard_map(
-        partial(_p1_request, n_loc=n_loc, shards=shards,
-                use_pallas=use_pallas, count_bound=count_bound),
+        partial(_p1_request, n_loc=n_loc, shards=shards),
         mesh, in_specs=(P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS), P(), P()))
     samp_sh = shard_map(
         partial(_p1_sample, eps=eps, n_loc=n_loc, shards=shards,
-                layout=layout, use_pallas=use_pallas),
+                layout=layout),
         mesh, in_specs=(P(AXIS),) * 4,
         out_specs=(P(AXIS),) * 3 + (P(),) * 2)
     asn_sh = shard_map(
@@ -396,8 +383,7 @@ def _make_p1_steps(mesh: Mesh, *, eps: float, n_loc: int, shards: int,
 # ---------------------------------------------------------------------------
 
 def _p2_local(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
-              *, n_loc: int, shards: int, S_loc_pad: int,
-              use_pallas: bool, count_bound: Optional[int] = None):
+              *, n_loc: int, shards: int, S_loc_pad: int):
     """One stitch superstep. Long walks are anonymous, so the state is a
     per-owned-vertex count: allocate the next unused coupons of each
     vertex's pool to the walks waiting there (natural-order consumption —
@@ -424,10 +410,9 @@ def _p2_local(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
     next_c = next_c + a
     term_now = alloc & (cterm > 0)      # coupon's eps-reset fired: walk done
     go = alloc & (cterm == 0)           # walk continues at coupon's dest
-    dcnt = vertex_histogram(dest, go, n_pad, use_pallas=use_pallas)
+    dcnt = vertex_histogram(dest, go, n_pad)
     arrivals, sent_entries, sent_bytes = route_counts(
-        dcnt, axis=AXIS, shard_id=shard_id, n_loc=n_loc, shards=shards,
-        use_pallas=use_pallas, count_bound=count_bound)
+        dcnt, axis=AXIS, shard_id=shard_id, n_loc=n_loc, shards=shards)
     tail_cnt = tail_cnt + exh
 
     stitched = jax.lax.psum(jnp.sum(a), AXIS)
@@ -441,11 +426,8 @@ def _p2_local(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
 
 
 @lru_cache(maxsize=64)
-def _make_p2_step(mesh: Mesh, *, n_loc: int, shards: int, S_loc_pad: int,
-                  use_pallas: bool, count_bound: Optional[int] = None):
-    fn = partial(_p2_local, n_loc=n_loc, shards=shards,
-                 S_loc_pad=S_loc_pad, use_pallas=use_pallas,
-                 count_bound=count_bound)
+def _make_p2_step(mesh: Mesh, *, n_loc: int, shards: int, S_loc_pad: int):
+    fn = partial(_p2_local, n_loc=n_loc, shards=shards, S_loc_pad=S_loc_pad)
     sharded = shard_map(fn, mesh,
                         in_specs=(P(AXIS),) * 8,
                         out_specs=(P(AXIS),) * 4 + (P(),) * 6)
@@ -462,8 +444,7 @@ def _make_p2_step(mesh: Mesh, *, n_loc: int, shards: int, S_loc_pad: int,
 # Phase 3: one aggregated counting round over the trajectory table
 # ---------------------------------------------------------------------------
 
-def _p3_local(traj, used, zeta, *, n_loc: int, shards: int,
-              use_pallas: bool, count_bound: Optional[int] = None):
+def _p3_local(traj, used, zeta, *, n_loc: int, shards: int):
     """Histogram the used coupons' recorded moves and deliver the counts
     to the owner shards in ONE `route_counts` exchange. The histogram
     runs one step column at a time: a histogram of the whole table at
@@ -474,14 +455,12 @@ def _p3_local(traj, used, zeta, *, n_loc: int, shards: int,
 
     def add_step(t, part):
         col = jax.lax.dynamic_index_in_dim(traj, t, axis=1, keepdims=False)
-        return part + vertex_histogram(col, (used > 0) & (col >= 0), n_pad,
-                                       use_pallas=use_pallas)
+        return part + vertex_histogram(col, (used > 0) & (col >= 0), n_pad)
 
     part = jax.lax.fori_loop(0, traj.shape[1], add_step,
                              jnp.zeros((n_pad,), jnp.int32))
     arrivals, sent_entries, sent_bytes = route_counts(
-        part, axis=AXIS, shard_id=shard_id, n_loc=n_loc, shards=shards,
-        use_pallas=use_pallas, count_bound=count_bound)
+        part, axis=AXIS, shard_id=shard_id, n_loc=n_loc, shards=shards)
     zeta = zeta + arrivals
     entries = jax.lax.psum(sent_entries, AXIS)
     nbytes = jax.lax.psum(sent_bytes, AXIS)
@@ -489,10 +468,8 @@ def _p3_local(traj, used, zeta, *, n_loc: int, shards: int,
 
 
 @lru_cache(maxsize=64)
-def _make_p3_step(mesh: Mesh, *, n_loc: int, shards: int,
-                  use_pallas: bool, count_bound: Optional[int] = None):
-    fn = partial(_p3_local, n_loc=n_loc, shards=shards,
-                 use_pallas=use_pallas, count_bound=count_bound)
+def _make_p3_step(mesh: Mesh, *, n_loc: int, shards: int):
+    fn = partial(_p3_local, n_loc=n_loc, shards=shards)
     sharded = shard_map(fn, mesh, in_specs=(P(AXIS),) * 3,
                         out_specs=(P(AXIS), P(), P()))
 
@@ -505,11 +482,10 @@ def _make_p3_step(mesh: Mesh, *, n_loc: int, shards: int,
 
 @lru_cache(maxsize=64)
 def _make_tail_step(mesh: Mesh, eps: float, n_loc: int, shards: int,
-                    route_cap: int, use_pallas: bool):
+                    route_cap: int):
     """The naive tail: the Algorithm-1 superstep, under its own name in a
     device trace (`jit_tail_step`)."""
-    step = _make_superstep(mesh, eps, n_loc, shards, route_cap, 0,
-                           use_pallas=use_pallas)
+    step = _make_superstep(mesh, eps, n_loc, shards, route_cap, 0)
 
     @jax.jit
     def tail_step(rp, ci, dg, state):
@@ -709,7 +685,7 @@ def distributed_improved_pagerank(
     route_cap2: Optional[int] = None,
     max_rounds: int = 100_000,
     bandwidth_bits: Optional[int] = None,
-    use_pallas: Optional[bool] = None,
+    use_pallas: bool = False,
     checkpoint_dir: Optional[str] = None,
     fail_at: Optional[Sequence[int]] = None,
     checkpoint_every: int = 10,
@@ -729,7 +705,14 @@ def distributed_improved_pagerank(
     and/or `fail_at` set, the phase-machine runs under the
     checkpoint-restart supervisor (see `_run_three_phase`).
     `bucketed=False` keeps the single-bucket max_deg-wide Phase-1 sampler
-    layout; the draws are layout-independent."""
+    layout; the draws are layout-independent.
+
+    `use_pallas` does nothing and a true value is refused: the engine has
+    one implementation per stage. The keyword stays only until the
+    benchmark's configurations and drivers stop passing `use_pallas`."""
+    if use_pallas:
+        raise ValueError("use_pallas=True: the Pallas kernels were removed; "
+                         "every stage runs its XLA implementation")
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()), (AXIS,))
     key = key if key is not None else jax.random.PRNGKey(0)
@@ -746,7 +729,7 @@ def distributed_improved_pagerank(
         graph, eps, K, key, mesh, pools=pools, eta=int(eta or 0),
         lam=int(lam), ell=int(ell), cap2=cap2, route_cap2=route_cap2,
         max_rounds=max_rounds, bandwidth_bits=bandwidth_bits,
-        use_pallas=use_pallas, checkpoint_dir=checkpoint_dir,
+        checkpoint_dir=checkpoint_dir,
         fail_at=fail_at, checkpoint_every=checkpoint_every,
         max_restarts=max_restarts, resume=resume, bucketed=bucketed)
 
@@ -766,7 +749,6 @@ def _run_three_phase(
     route_cap2: Optional[int] = None,
     max_rounds: int = 100_000,
     bandwidth_bits: Optional[int] = None,
-    use_pallas: Optional[bool] = None,
     checkpoint_dir: Optional[str] = None,
     fail_at: Optional[Sequence[int]] = None,
     checkpoint_every: int = 10,
@@ -822,14 +804,13 @@ def _run_three_phase(
     """
     shards = int(mesh.devices.size)
     n = graph.n
-    use_pallas = resolve_use_pallas(use_pallas)
     with tracing.span(f"{name}.job"):
         with tracing.span(f"{name}.build"):
             pool_np = pools()
             ms, schedule, _put, plan = _build_three_phase(
                 graph, eps, K, key, mesh, pool_np=pool_np, lam=lam,
                 cap2=cap2, route_cap2=route_cap2, max_rounds=max_rounds,
-                use_pallas=use_pallas, bucketed=bucketed)
+                bucketed=bucketed)
 
         # global rounds sum over the four stages, each bounded by
         # max_rounds
@@ -910,7 +891,7 @@ def _compile_concurrently(programs) -> None:
 
 
 def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
-                       route_cap2, max_rounds, use_pallas, bucketed):
+                       route_cap2, max_rounds, bucketed):
     """The phase-machine of one job: (initial state, schedule, the
     snapshot `put`, plan)."""
     shards = int(mesh.devices.size)
@@ -957,13 +938,10 @@ def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
     # ---- jitted per-phase step functions (shared by fresh + resumed) ----
     p1_req, p1_samp, p1_asn = _make_p1_steps(
         mesh, eps=float(eps), n_loc=n_loc, shards=shards,
-        rep_cap=plan.rep_cap, S_loc_pad=S_loc_pad, layout=layout,
-        use_pallas=use_pallas, count_bound=S_total)
+        rep_cap=plan.rep_cap, S_loc_pad=S_loc_pad, layout=layout)
     p2_step = _make_p2_step(mesh, n_loc=n_loc, shards=shards,
-                            S_loc_pad=S_loc_pad, use_pallas=use_pallas,
-                            count_bound=n * K)
-    p3_step = _make_p3_step(mesh, n_loc=n_loc, shards=shards,
-                            use_pallas=use_pallas, count_bound=S_total)
+                            S_loc_pad=S_loc_pad)
+    p3_step = _make_p3_step(mesh, n_loc=n_loc, shards=shards)
     psize_j = jax.device_put(jnp.asarray(psize_sh, dtype=jnp.int32), spec)
     pstart_j = jax.device_put(jnp.asarray(pstart_sh, dtype=jnp.int32), spec)
 
@@ -1112,7 +1090,7 @@ def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
             _, lanes = _tail_sizes(h["tail_walks"], shards, cap2,
                                    route_cap2)
             tail_step = _make_tail_step(mesh, float(eps), n_loc, shards,
-                                        lanes, use_pallas)
+                                        lanes)
             tstate = DistState(pos=a["pos"], zeta=a["zeta"], key=a["key"],
                                round=a["round"], dropped=a["dropped"],
                                waited=a["waited"])
@@ -1182,7 +1160,6 @@ def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
 def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
                            K: int, pool_np: np.ndarray, lam: int,
                            engine: str = "improved",
-                           use_pallas: bool = False,
                            bucketed: bool = True):
     """CONGEST-auditor spec for the 3-phase engines (improved + directed
     frontends): all six stage programs rebuilt through the SAME memoized
@@ -1205,16 +1182,12 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
 
     p1_req, p1_samp, p1_asn = _make_p1_steps(
         mesh, eps=float(eps), n_loc=n_loc, shards=shards, rep_cap=rep_cap,
-        S_loc_pad=S_loc_pad, layout=layout, use_pallas=use_pallas,
-        count_bound=S_total)
+        S_loc_pad=S_loc_pad, layout=layout)
     p2_step = _make_p2_step(mesh, n_loc=n_loc, shards=shards,
-                            S_loc_pad=S_loc_pad, use_pallas=use_pallas,
-                            count_bound=n * K)
-    p3_step = _make_p3_step(mesh, n_loc=n_loc, shards=shards,
-                            use_pallas=use_pallas, count_bound=S_total)
+                            S_loc_pad=S_loc_pad)
+    p3_step = _make_p3_step(mesh, n_loc=n_loc, shards=shards)
     tail_cap = n_loc                       # auditor-pinned (walk-class)
-    tail_step = _make_tail_step(mesh, float(eps), n_loc, shards, tail_cap,
-                                use_pallas)
+    tail_step = _make_tail_step(mesh, float(eps), n_loc, shards, tail_cap)
 
     sds = jax.ShapeDtypeStruct
     i32, u32 = jnp.int32, jnp.uint32
@@ -1301,8 +1274,7 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
 
 
 def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
-               walks_per_node: int = 2, use_pallas: bool = False,
-               bucketed: bool = True):
+               walks_per_node: int = 2, bucketed: bool = True):
     """Frontend of the 3-phase audit spec with the pools
     `distributed_improved_pagerank` uses by default (expected demand) —
     mirrors its sizing exactly."""
@@ -1311,4 +1283,4 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
     return three_phase_audit_spec(
         graph, mesh, eps=eps, K=K,
         pool_np=demand_pool_sizes(graph, eps, K, lam), lam=lam,
-        engine="improved", use_pallas=use_pallas, bucketed=bucketed)
+        engine="improved", bucketed=bucketed)

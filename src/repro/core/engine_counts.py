@@ -14,10 +14,8 @@ degree-bucketed aggregate sampler (`core/aggregate_sampler`): each
 row's split spans its power-of-two bucket width instead of the global
 max degree, so per-round sampler FLOPs are
 sum_v O(deg(v)) — hubs no longer tax every low-degree vertex.
-`use_pallas` routes the draws through the `kernels/multinomial_rows`
-Pallas kernel (same counter-RNG math as the jnp ref, so results are
-bit-identical either way); `bucketed=False` keeps the single-bucket
-max_deg-wide layout for benchmarking the pre-bucketing shape.
+`bucketed=False` keeps the single-bucket max_deg-wide layout for
+benchmarking the pre-bucketing shape.
 """
 from __future__ import annotations
 
@@ -32,9 +30,8 @@ import numpy as np
 from repro.core.accounting import RoundTrace
 from repro.core.aggregate_sampler import (build_layout, bucketize_csr,
                                           flatten_moves, sample_buckets)
+from repro.core.binomial_tree import key_words
 from repro.core.graph import CSRGraph
-from repro.kernels import resolve_use_pallas
-from repro.kernels.multinomial_rows._math import key_words
 
 
 @jax.tree_util.register_dataclass
@@ -70,9 +67,8 @@ def _multinomial_split(key, survivors, deg, max_deg: int):
     return T.T, rem
 
 
-@partial(jax.jit, static_argnames=("eps", "n", "layout", "use_pallas"))
-def _step(bnbr, perm, deg, state: CountState, eps: float, n: int, layout,
-          use_pallas: bool):
+@partial(jax.jit, static_argnames=("eps", "n", "layout"))
+def _step(bnbr, perm, deg, state: CountState, eps: float, n: int, layout):
     """One super-step through the shared degree-bucketed sampler: each
     bucket draws its fused Binomial(eps) termination + conditional-binomial
     edge split (dangling rows terminate whole), then the per-edge counts
@@ -80,8 +76,7 @@ def _step(bnbr, perm, deg, state: CountState, eps: float, n: int, layout,
     key, k_sample = jax.random.split(state.key)
     rid = jnp.arange(n, dtype=jnp.int32)
     samples, _, residual = sample_buckets(
-        state.counts, deg, rid, key_words(k_sample), perm, layout,
-        eps=eps, use_pallas=use_pallas)
+        state.counts, deg, rid, key_words(k_sample), perm, layout, eps=eps)
     flat_T = flatten_moves(samples)
     # route: new_counts[u] = sum over bucketed edge slots with dst == u
     new_counts = jax.ops.segment_sum(flat_T, bnbr, num_segments=n)
@@ -103,9 +98,8 @@ def _step(bnbr, perm, deg, state: CountState, eps: float, n: int, layout,
 
 def run_traced(graph: CSRGraph, eps: float, walks_per_node: int,
                key: jnp.ndarray, *, max_rounds: int = 100_000,
-               use_pallas=None, bucketed: bool = True
+               bucketed: bool = True
                ) -> Tuple[CountState, List[RoundTrace]]:
-    use_pallas = resolve_use_pallas(use_pallas)
     deg = np.asarray(graph.out_deg)
     layout, perm_np = build_layout(deg, max(graph.max_out_deg, 1),
                                    bucketed=bucketed)
@@ -116,7 +110,7 @@ def run_traced(graph: CSRGraph, eps: float, walks_per_node: int,
     traces: List[RoundTrace] = []
     while int(jnp.sum(state.counts)) > 0 and int(state.round) < max_rounds:
         state, stats = _step(bnbr, perm, graph.out_deg, state, float(eps),
-                             graph.n, layout, use_pallas)
+                             graph.n, layout)
         assert int(stats["residual"]) == 0, "multinomial split leaked mass"
         traces.append(RoundTrace(
             active_walks=int(stats["active"]),

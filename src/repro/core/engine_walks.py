@@ -1,7 +1,7 @@
 """Walk-array engine — the TPU-native realization of Algorithm 1.
 
 A dense array of walk positions is advanced with vectorized gathers; visit
-counters are one-hot-MXU histograms. Mathematically identical to the paper's
+counters are segment-sum histograms. Mathematically identical to the paper's
 process (walks are iid PageRank random walks terminated at the first
 eps-reset); the CONGEST message structure (per-edge *counts*, Lemma 1) is
 recovered for accounting by histogramming the per-round edge transitions.
@@ -52,8 +52,7 @@ def init_state(graph: CSRGraph, walks_per_node: int, key: jnp.ndarray,
     )
 
 
-def _step_core(row_ptr, col_idx, out_deg, eps: float, state: WalkState,
-               *, use_pallas: bool = False):
+def _step_core(row_ptr, col_idx, out_deg, eps: float, state: WalkState):
     """One synchronous round. Returns (new_state, moving_mask, edge_ids)."""
     key, k_term, k_edge = jax.random.split(state.key, 3)
     u_term = jax.random.uniform(k_term, state.pos.shape)
@@ -66,14 +65,8 @@ def _step_core(row_ptr, col_idx, out_deg, eps: float, state: WalkState,
     edge_ids = row_ptr[state.pos] + j
     dst = col_idx[jnp.clip(edge_ids, 0, col_idx.shape[0] - 1)]
     new_pos = jnp.where(survive, dst, state.pos)
-    if use_pallas:
-        from repro.kernels.histogram import ops as hist_ops
-
-        arrivals = hist_ops.histogram(
-            jnp.where(survive, dst, jnp.int32(-1)), state.zeta.shape[0])
-    else:
-        arrivals = jax.ops.segment_sum(
-            survive.astype(jnp.int32), dst, num_segments=state.zeta.shape[0])
+    arrivals = jax.ops.segment_sum(
+        survive.astype(jnp.int32), dst, num_segments=state.zeta.shape[0])
     new_state = WalkState(
         pos=new_pos,
         alive=survive,
@@ -84,32 +77,31 @@ def _step_core(row_ptr, col_idx, out_deg, eps: float, state: WalkState,
     return new_state, survive, edge_ids
 
 
-@partial(jax.jit, static_argnames=("eps", "max_rounds", "use_pallas"))
+@partial(jax.jit, static_argnames=("eps", "max_rounds"))
 def _run_while(row_ptr, col_idx, out_deg, state: WalkState, eps: float,
-               max_rounds: int, use_pallas: bool) -> WalkState:
+               max_rounds: int) -> WalkState:
     def cond(s):
         return jnp.logical_and(jnp.any(s.alive), s.round < max_rounds)
 
     def body(s):
-        s2, _, _ = _step_core(row_ptr, col_idx, out_deg, eps, s,
-                              use_pallas=use_pallas)
+        s2, _, _ = _step_core(row_ptr, col_idx, out_deg, eps, s)
         return s2
 
     return jax.lax.while_loop(cond, body, state)
 
 
 def run(graph: CSRGraph, eps: float, walks_per_node: int, key: jnp.ndarray,
-        *, max_rounds: int = 100_000, use_pallas: bool = False) -> WalkState:
+        *, max_rounds: int = 100_000) -> WalkState:
     state = init_state(graph, walks_per_node, key)
     return _run_while(graph.row_ptr, graph.col_idx, graph.out_deg, state,
-                      float(eps), int(max_rounds), bool(use_pallas))
+                      float(eps), int(max_rounds))
 
 
-@partial(jax.jit, static_argnames=("eps", "n_edges", "use_pallas"))
+@partial(jax.jit, static_argnames=("eps", "n_edges"))
 def _step_traced(row_ptr, col_idx, out_deg, state: WalkState, eps: float,
-                 n_edges: int, use_pallas: bool):
+                 n_edges: int):
     new_state, survive, edge_ids = _step_core(
-        row_ptr, col_idx, out_deg, eps, state, use_pallas=use_pallas)
+        row_ptr, col_idx, out_deg, eps, state)
     # CONGEST payload: count of walks per edge this round (Lemma 1 messages)
     edge_counts = jax.ops.segment_sum(
         survive.astype(jnp.int32), edge_ids, num_segments=n_edges)
@@ -123,14 +115,14 @@ def _step_traced(row_ptr, col_idx, out_deg, state: WalkState, eps: float,
 
 
 def run_traced(graph: CSRGraph, eps: float, walks_per_node: int,
-               key: jnp.ndarray, *, max_rounds: int = 100_000,
-               use_pallas: bool = False) -> Tuple[WalkState, List[RoundTrace]]:
+               key: jnp.ndarray, *, max_rounds: int = 100_000
+               ) -> Tuple[WalkState, List[RoundTrace]]:
     state = init_state(graph, walks_per_node, key)
     traces: List[RoundTrace] = []
     while bool(jnp.any(state.alive)) and int(state.round) < max_rounds:
         state, stats = _step_traced(graph.row_ptr, graph.col_idx,
                                     graph.out_deg, state, float(eps),
-                                    graph.m, bool(use_pallas))
+                                    graph.m)
         traces.append(RoundTrace(
             active_walks=int(stats["active"]),
             messages=int(stats["messages"]),

@@ -355,7 +355,7 @@ def improved_pagerank(
         while bool(jnp.any(state.alive)):
             state, stats = _step_traced(graph.row_ptr, graph.col_idx,
                                         graph.out_deg, state, float(eps),
-                                        m, False)
+                                        m)
             traces_tail.append(RoundTrace(
                 active_walks=int(stats["active"]),
                 messages=int(stats["messages"]),

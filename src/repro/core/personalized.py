@@ -96,7 +96,7 @@ def personalized_pagerank(graph: CSRGraph, eps: float, sources,
     state = engine_walks.init_state(graph, 0, key, sources=starts)
     state = engine_walks._run_while(graph.row_ptr, graph.col_idx,
                                     graph.out_deg, state, float(eps),
-                                    int(max_rounds), False)
+                                    int(max_rounds))
     return state.zeta.astype(jnp.float32) * (eps / walks_total)
 
 

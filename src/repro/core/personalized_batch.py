@@ -14,10 +14,9 @@ walks move — and the receiving shard re-materializes walks from the
 delivered counts. That re-deal is sound because walks are anonymous
 WITHIN a query: Lemma 1 of the paper, extended by one lane.
 
-Hot paths reuse the seed kernels behind `use_pallas`: per-walk
-advancement via `walk_step` (`routing.advance_owned`) and the
-(vertex, query) aggregation / visit histograms via `histogram`
-(`routing.vertex_histogram`).
+Hot paths reuse the shared routing helpers: per-walk advancement
+(`routing.advance_owned`) and the (vertex, query) aggregation / visit
+histograms (`routing.vertex_histogram`).
 
 The engine is RESIDENT: the sharded graph and the walk/visit buffers stay
 on device across queries. `admit(slot, sources, ...)` installs a query
@@ -56,7 +55,6 @@ from repro.core.routing import (advance_owned, rank_within, route_counts,
                                 count_owned_arrivals, shard_map,
                                 vertex_histogram)
 from repro.checkpoint import LayoutSpec, relayout_arrays
-from repro.kernels import resolve_use_pallas
 
 
 @jax.tree_util.register_dataclass
@@ -79,8 +77,7 @@ def ppr_state_specs(n: int, cap: int):
 
 
 def _ppr_superstep(rp, ci, dg, pos, qid, zeta, key, *, eps: float,
-                   n_loc: int, shards: int, Q: int, use_pallas: bool,
-                   count_bound: Optional[int] = None):
+                   n_loc: int, shards: int, Q: int):
     """One batched PPR round on a single shard (runs under shard_map).
 
     All buffered walks are owned by this shard by construction (arrivals
@@ -94,14 +91,12 @@ def _ppr_superstep(rp, ci, dg, pos, qid, zeta, key, *, eps: float,
 
     valid = pos >= 0
     survive, dst = advance_owned(rp, ci, dg, pos, valid, k_term, k_edge,
-                                 eps, shard_id, n_loc,
-                                 use_pallas=use_pallas)
+                                 eps, shard_id, n_loc)
 
     # Lemma-1 aggregation with a query lane: movers collapse to counts per
     # virtual (vertex, query) id and ride ONE route_counts exchange.
     u = dst * Q + qid
-    per_virtual = vertex_histogram(u, survive, shards * n_loc * Q,
-                                   use_pallas=use_pallas)
+    per_virtual = vertex_histogram(u, survive, shards * n_loc * Q)
     if shards == 1:
         # one shard owns every lane: nothing crosses the wire, and
         # `route_counts` would sort and scatter all n * Q lanes only to
@@ -111,7 +106,7 @@ def _ppr_superstep(rp, ci, dg, pos, qid, zeta, key, *, eps: float,
     else:
         arrivals, sent_entries, sent_bytes = route_counts(
             per_virtual, axis=AXIS, shard_id=shard_id, n_loc=n_loc * Q,
-            shards=shards, use_pallas=use_pallas, count_bound=count_bound)
+            shards=shards)
 
     # every arrival is a visit to an owned vertex
     zeta = zeta + arrivals.reshape(n_loc, Q)
@@ -139,7 +134,7 @@ def _ppr_superstep(rp, ci, dg, pos, qid, zeta, key, *, eps: float,
 
 
 def _ppr_admit(pos, qid, zeta, starts, slot, *, n_loc: int, shards: int,
-               Q: int, use_pallas: bool):
+               Q: int):
     """Install a query into slot `slot`: place its start walks into free
     buffer slots of the shards owning the start vertices, and reset the
     slot's visit column to the start visits (a start counts as a visit,
@@ -155,8 +150,7 @@ def _ppr_admit(pos, qid, zeta, starts, slot, *, n_loc: int, shards: int,
 
     mine = (starts >= 0) & (starts // n_loc == shard_id)
     zeta = zeta.at[:, slot].set(
-        count_owned_arrivals(mine, starts, shard_id, n_loc,
-                             use_pallas=use_pallas))
+        count_owned_arrivals(mine, starts, shard_id, n_loc))
 
     # pack my starts into this shard's free buffer slots
     order = jnp.argsort(jnp.where(mine, 0, 1), stable=True)
@@ -182,8 +176,7 @@ class BatchedPPREngine:
 
     def __init__(self, graph: CSRGraph, eps: float, *, num_slots: int,
                  walks_per_query: int, mesh: Optional[Mesh] = None,
-                 cap: Optional[int] = None,
-                 use_pallas: Optional[bool] = None):
+                 cap: Optional[int] = None):
         if mesh is None:
             mesh = Mesh(np.array(jax.devices()), (AXIS,))
         self.graph = graph
@@ -192,7 +185,6 @@ class BatchedPPREngine:
         self.walks_per_query = int(walks_per_query)
         self.mesh = mesh
         self.shards = mesh.devices.size
-        self.use_pallas = resolve_use_pallas(use_pallas)
         self.sg: ShardedGraph = shard_graph(graph, self.shards)
         if cap is None:
             # worst case: every live walk of every slot on one shard
@@ -208,16 +200,13 @@ class BatchedPPREngine:
         n_loc = self.sg.n_loc
         step_sh = shard_map(
             partial(_ppr_superstep, eps=self.eps, n_loc=n_loc,
-                    shards=self.shards, Q=self.Q,
-                    use_pallas=self.use_pallas,
-                    count_bound=self.walks_per_query),
+                    shards=self.shards, Q=self.Q),
             mesh,
             in_specs=(P(AXIS),) * 7,
             out_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS),
                        P(), P(), P(), P()))
         admit_sh = shard_map(
-            partial(_ppr_admit, n_loc=n_loc, shards=self.shards, Q=self.Q,
-                    use_pallas=self.use_pallas),
+            partial(_ppr_admit, n_loc=n_loc, shards=self.shards, Q=self.Q),
             mesh,
             in_specs=(P(AXIS), P(AXIS), P(AXIS), P(), P()),
             out_specs=(P(AXIS), P(AXIS), P(AXIS), P()))
@@ -353,7 +342,6 @@ def batched_personalized_pagerank(
         queries: Sequence[Tuple[Sequence[int], Optional[Sequence[float]]]],
         walks_per_query: int, key: jnp.ndarray, *,
         mesh: Optional[Mesh] = None, cap: Optional[int] = None,
-        use_pallas: Optional[bool] = None,
         max_rounds: int = DEFAULT_MAX_ROUNDS) -> BatchPPRResult:
     """One-shot batch driver: admit every query up front, run every walk
     to termination in shared supersteps, extract all results.
@@ -364,7 +352,7 @@ def batched_personalized_pagerank(
     """
     engine = BatchedPPREngine(graph, eps, num_slots=len(queries),
                               walks_per_query=walks_per_query, mesh=mesh,
-                              cap=cap, use_pallas=use_pallas)
+                              cap=cap)
     engine.reset(jax.random.fold_in(key, 0xBA7C))
     for i, (sources, weights) in enumerate(queries):
         engine.admit(i, sources, weights, key=jax.random.fold_in(key, i))
@@ -382,8 +370,7 @@ def batched_personalized_pagerank(
 
 
 def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
-               num_slots: int = 2, walks_per_query: int = 8,
-               use_pallas: bool = False):
+               num_slots: int = 2, walks_per_query: int = 8):
     """CONGEST-auditor spec for the batched PPR engine: the resident
     engine's jitted superstep (built with an auditor-pinned walk cap — the
     virtual-lane wire bound is independent of the buffer size), its
@@ -393,7 +380,7 @@ def audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float = 0.2,
     shards = int(mesh.devices.size)
     engine = BatchedPPREngine(graph, eps, num_slots=num_slots,
                               walks_per_query=walks_per_query, mesh=mesh,
-                              cap=64, use_pallas=use_pallas)
+                              cap=64)
     n_loc, Q, cap = engine.sg.n_loc, engine.Q, engine.cap
     sds = jax.ShapeDtypeStruct
     i32, u32 = jnp.int32, jnp.uint32

@@ -3,9 +3,7 @@ against in the distributed setting. Implemented as a sharded sparse push:
 
     pi_{t+1} = eps/n + (1-eps) * (Q^T pi_t + dangling_mass/n)
 
-The push over the CSR edge list is a segment-sum; the hot loop can run
-through the `segment_spmv` Pallas kernel (TPU one-hot-MXU tiling) or the
-pure-jnp path (oracle / CPU).
+The push over the CSR edge list is a segment-sum.
 """
 from __future__ import annotations
 
@@ -18,34 +16,25 @@ import jax.numpy as jnp
 from repro.core.graph import CSRGraph
 
 
-def spmv_push(graph: CSRGraph, x: jnp.ndarray, *, use_pallas: bool = False) -> jnp.ndarray:
+def spmv_push(graph: CSRGraph, x: jnp.ndarray) -> jnp.ndarray:
     """y = Q^T x  where Q is the row-stochastic out-edge matrix.
 
     Each edge (v -> u) pushes x[v]/deg(v) into y[u].
     """
     src = graph.edge_src()
     contrib = x[src] / graph.out_deg[src].astype(x.dtype)
-    if use_pallas:
-        from repro.kernels.segment_spmv import ops as spmv_ops
-
-        return spmv_ops.segment_spmv(contrib, graph.col_idx, graph.n)
     return jax.ops.segment_sum(contrib, graph.col_idx, num_segments=graph.n)
 
 
-@partial(jax.jit, static_argnames=("graph_n", "max_iters", "use_pallas"))
+@partial(jax.jit, static_argnames=("graph_n", "max_iters"))
 def _power_iterate(row_ptr, col_idx, out_deg, edge_src, graph_n: int, eps: float,
-                   tol: float, max_iters: int, use_pallas: bool):
+                   tol: float, max_iters: int):
     deg_f = jnp.maximum(out_deg, 1).astype(jnp.float32)
     dangling = (out_deg == 0)
 
     def push(x):
         contrib = x[edge_src] / deg_f[edge_src]
-        if use_pallas:
-            from repro.kernels.segment_spmv import ops as spmv_ops
-
-            y = spmv_ops.segment_spmv(contrib, col_idx, graph_n)
-        else:
-            y = jax.ops.segment_sum(contrib, col_idx, num_segments=graph_n)
+        y = jax.ops.segment_sum(contrib, col_idx, num_segments=graph_n)
         dang_mass = jnp.sum(jnp.where(dangling, x, 0.0))
         return y + dang_mass / graph_n
 
@@ -65,10 +54,10 @@ def _power_iterate(row_ptr, col_idx, out_deg, edge_src, graph_n: int, eps: float
 
 
 def power_iteration(graph: CSRGraph, eps: float, *, tol: float = 1e-7,
-                    max_iters: int = 10_000, use_pallas: bool = False
+                    max_iters: int = 10_000
                     ) -> Tuple[jnp.ndarray, float, int]:
     """Returns (pi, final_l1_delta, iterations)."""
     x, err, iters = _power_iterate(
         graph.row_ptr, graph.col_idx, graph.out_deg, graph.edge_src(),
-        graph.n, float(eps), float(tol), int(max_iters), bool(use_pallas))
+        graph.n, float(eps), float(tol), int(max_iters))
     return x, float(err), int(iters)

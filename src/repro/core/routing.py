@@ -30,13 +30,6 @@ exchanged, and the routing helpers return `sent_bytes` computed with it, so
 an engine's wire telemetry cannot drift from its payload when a column is
 added or dropped.
 
-`advance_owned` and `count_owned_arrivals` accept `use_pallas` to run the
-per-walk advancement / histogram through the Pallas kernels in
-`repro.kernels` (`walk_step`, `histogram`); the kernels are bit-identical
-to the jnp paths (same uniforms, same decision logic) and run in
-interpret mode off-TPU; `walk_step` refuses to run compiled on TPU (see
-`kernels/walk_step/ops.py`).
-
 All helpers run *inside* shard_map: `jax.lax.axis_index`/`all_to_all` refer
 to the mesh axis passed as `axis`.
 """
@@ -46,10 +39,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.kernels import histogram as _histogram_kernel
-from repro.kernels import segment_spmv as _segment_spmv_kernel
-from repro.kernels import walk_step as _walk_step_kernel
 
 
 def shard_map(f, mesh, in_specs, out_specs):
@@ -157,34 +146,22 @@ def entry_nbytes(*columns) -> int:
     return int(total)
 
 
-def _seg_reduce(values: jnp.ndarray, seg: jnp.ndarray, num_segments: int,
-                use_pallas: bool, count_bound=None) -> jnp.ndarray:
-    """Sum `values` into `num_segments` buckets; out-of-range seg ids drop.
-
-    With `use_pallas` the reduction runs through the `segment_spmv` kernel
-    (fp32 accumulation — exact for integer counts below 2**24). Engines
-    declare the largest reachable count via `count_bound`; past 2**24 the
-    kernel wrapper widens to an exact integer reduction instead of
-    truncating (see `kernels/segment_spmv/ops.py`)."""
-    if use_pallas:
-        return _segment_spmv_kernel(values, seg, num_segments,
-                                    count_bound=count_bound
-                                    ).astype(values.dtype)
+def _seg_reduce(values: jnp.ndarray, seg: jnp.ndarray,
+                num_segments: int) -> jnp.ndarray:
+    """Sum `values` into `num_segments` buckets in their own dtype (exact
+    for integer counts); out-of-range seg ids drop."""
     return jax.ops.segment_sum(values, jnp.where(
         (seg >= 0) & (seg < num_segments), seg, num_segments),
         num_segments=num_segments + 1)[:num_segments]
 
 
-def vertex_histogram(v: jnp.ndarray, mask: jnp.ndarray, num_vertices: int,
-                     use_pallas: bool = False) -> jnp.ndarray:
-    """[num_vertices] histogram of `v[mask]` (any shape, flattened).
-
-    The per-vertex count builder feeding `route_counts`; `use_pallas`
-    runs it through the `histogram` kernel."""
+def vertex_histogram(v: jnp.ndarray, mask: jnp.ndarray,
+                     num_vertices: int) -> jnp.ndarray:
+    """[num_vertices] histogram of `v[mask]` (any shape, flattened);
+    ids outside [0, num_vertices) drop. The per-vertex count builder
+    feeding `route_counts`."""
     v = v.reshape(-1)
     mask = mask.reshape(-1)
-    if use_pallas:
-        return _histogram_kernel(jnp.where(mask, v, -1), num_vertices)
     return jax.ops.segment_sum(
         mask.astype(jnp.int32),
         jnp.where(mask & (v >= 0) & (v < num_vertices), v, num_vertices),
@@ -193,8 +170,7 @@ def vertex_histogram(v: jnp.ndarray, mask: jnp.ndarray, num_vertices: int,
 
 def route_counts(per_vertex: jnp.ndarray, *, axis: str,
                  shard_id: jnp.ndarray, n_loc: int, shards: int,
-                 by_source: bool = False, use_pallas: bool = False,
-                 count_bound=None):
+                 by_source: bool = False):
     """One Lemma-1 aggregated exchange: per-destination-vertex counts
     travel as (vertex, count) pairs — payload bounded by the number of
     distinct destination vertices, independent of how many walks move.
@@ -229,12 +205,11 @@ def route_counts(per_vertex: jnp.ndarray, *, axis: str,
     if by_source:
         src = jnp.arange(shards * n_loc, dtype=jnp.int32) // n_loc
         seg = jnp.where(got, src * n_loc + local_v, n_pad)
-        arrivals = _seg_reduce(cnt, seg, n_pad, use_pallas,
-                               count_bound).reshape(shards, n_loc)
+        arrivals = _seg_reduce(cnt, seg, n_pad).reshape(shards, n_loc)
         arrivals = arrivals.at[shard_id].add(own)
     else:
         seg = jnp.where(got, local_v, n_loc)
-        arrivals = _seg_reduce(cnt, seg, n_loc, use_pallas, count_bound) + own
+        arrivals = _seg_reduce(cnt, seg, n_loc) + own
     return arrivals, sent_entries, sent_bytes
 
 
@@ -296,13 +271,10 @@ def merge_walks(kept_pos: jnp.ndarray, kept_fields: Dict[str, jnp.ndarray],
 
 
 def count_owned_arrivals(mask: jnp.ndarray, v_global: jnp.ndarray,
-                         shard_id: jnp.ndarray, n_loc: int,
-                         use_pallas: bool = False) -> jnp.ndarray:
+                         shard_id: jnp.ndarray, n_loc: int) -> jnp.ndarray:
     """[n_loc] histogram of `v_global[mask]` rebased to this shard's range
     (masked entries dump into a discarded overflow segment)."""
     local = jnp.where(mask, v_global - shard_id * n_loc, -1)
-    if use_pallas:
-        return _histogram_kernel(local, n_loc)
     return jax.ops.segment_sum(
         mask.astype(jnp.int32), jnp.where(mask, local, n_loc),
         num_segments=n_loc + 1)[:n_loc]
@@ -311,24 +283,16 @@ def count_owned_arrivals(mask: jnp.ndarray, v_global: jnp.ndarray,
 def advance_owned(rp: jnp.ndarray, ci: jnp.ndarray, dg: jnp.ndarray,
                   pos: jnp.ndarray, eligible: jnp.ndarray,
                   k_term: jnp.ndarray, k_edge: jnp.ndarray, eps: float,
-                  shard_id: jnp.ndarray, n_loc: int,
-                  use_pallas: bool = False):
+                  shard_id: jnp.ndarray, n_loc: int):
     """One PageRank step for the `eligible` walks of this shard: terminate
     w.p. eps (or on a dangling vertex), else move along a uniform out-edge.
 
     Returns (survive, dst): `survive` marks walks that moved, `dst` their
-    new global vertex (meaningful only where `survive`). The `use_pallas`
-    path draws the SAME uniforms and applies the same decision logic inside
-    the `walk_step` kernel, so both paths are bit-identical."""
+    new global vertex (meaningful only where `survive`)."""
     cap = pos.shape[0]
     local = jnp.where(eligible, pos - shard_id * n_loc, 0)
     u_term = jax.random.uniform(k_term, (cap,))
     u_edge = jax.random.uniform(k_edge, (cap,))
-    if use_pallas:
-        new_pos, new_alive = _walk_step_kernel(
-            local, eligible.astype(jnp.int32), u_term, u_edge, rp, ci, dg,
-            eps=eps)
-        return new_alive != 0, new_pos
     deg = dg[local]
     survive = eligible & (u_term >= eps) & (deg > 0)
     j = jnp.minimum((u_edge * jnp.maximum(deg, 1)).astype(jnp.int32),

@@ -41,22 +41,20 @@ def walks_per_node_for(n: int, eps: float, delta_prime: float = 1.0) -> int:
 
 def simple_pagerank(graph: CSRGraph, eps: float, *, walks_per_node: int | None = None,
                     key: jnp.ndarray | None = None, engine: str = "walks",
-                    traced: bool = False, bandwidth_bits: int | None = None,
-                    use_pallas: bool = False) -> PageRankResult:
+                    traced: bool = False, bandwidth_bits: int | None = None
+                    ) -> PageRankResult:
     key = key if key is not None else jax.random.PRNGKey(0)
     K = walks_per_node or walks_per_node_for(graph.n, eps)
     traces: List[RoundTrace] = []
 
     if engine == "counts":
-        state, traces = engine_counts.run_traced(graph, eps, K, key,
-                                                 use_pallas=use_pallas)
+        state, traces = engine_counts.run_traced(graph, eps, K, key)
         zeta, rounds = state.zeta, int(state.round)
     elif engine == "walks" and traced:
-        state, traces = engine_walks.run_traced(graph, eps, K, key,
-                                                use_pallas=use_pallas)
+        state, traces = engine_walks.run_traced(graph, eps, K, key)
         zeta, rounds = state.zeta, int(state.round)
     elif engine == "walks":
-        state = engine_walks.run(graph, eps, K, key, use_pallas=use_pallas)
+        state = engine_walks.run(graph, eps, K, key)
         zeta, rounds = state.zeta, int(state.round)
     else:
         raise ValueError(f"unknown engine {engine!r}")

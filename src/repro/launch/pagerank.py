@@ -21,15 +21,6 @@ Engine selection (`--algo`):
             Pair it with `--graph directed_web` to exercise a power-law
             directed fixture.
 
-`--use-pallas` routes every engine's hot paths (walk stepping, arrival
-histograms, count reductions) through the Pallas kernels in
-`repro.kernels` — interpret mode on CPU, compiled on TPU. The kernels
-share decision logic and uniforms with the jnp fallbacks, so results are
-bit-identical either way in interpret mode; the REPRO_USE_PALLAS env var
-is the flagless default. On TPU `walk_step` does not compile, so engines
-that step single walks (walks, ppr, the 3-phase tail) raise there under
-`--use-pallas` rather than fall back.
-
 `main` keeps JAX's persistent compilation cache in
 `JAX_COMPILATION_CACHE_DIR`, or in `.jax_cache/` at the checkout's root
 (`repro.launch.compile_cache`).
@@ -169,7 +160,6 @@ from repro.core.distributed_directed import distributed_directed_pagerank
 from repro.core.distributed_improved import distributed_improved_pagerank
 from repro.core.graph import CSRGraph
 from repro.graphs import GENERATORS
-from repro.kernels import resolve_use_pallas
 from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import FailureSchedule, Supervisor, tracing
 
@@ -207,8 +197,7 @@ def _report_accuracy(pi, g, eps: float, check: bool = False,
 
 
 def run_walks(g, eps: float, walks_per_node: int, checkpoint_dir,
-              fail_at, seed: int, resume: bool = False,
-              use_pallas: Optional[bool] = None, mesh=None,
+              fail_at, seed: int, resume: bool = False, mesh=None,
               max_restarts: int = 16):
     if mesh is None:
         mesh = Mesh(np.array(jax.devices()), (AXIS,))
@@ -235,8 +224,7 @@ def run_walks(g, eps: float, walks_per_node: int, checkpoint_dir,
                       waited=jnp.int32(0))
     rp, ci, dg = (jax.device_put(x, spec)
                   for x in (sg.row_ptr, sg.col_idx, sg.out_deg))
-    step = _make_superstep(mesh, eps, sg.n_loc, shards, route_cap, 0,
-                           use_pallas=resolve_use_pallas(use_pallas))
+    step = _make_superstep(mesh, eps, sg.n_loc, shards, route_cap, 0)
 
     def step_fn(s):
         s2, active, _, _ = step(rp, ci, dg, s)
@@ -262,8 +250,7 @@ def run_walks(g, eps: float, walks_per_node: int, checkpoint_dir,
 
 
 def run_ppr(g, eps: float, walks_per_query: int, num_queries: int,
-            seed: int, check: bool = False,
-            use_pallas: Optional[bool] = None, l1_tol: float = L1_TOL,
+            seed: int, check: bool = False, l1_tol: float = L1_TOL,
             topk_min: float = TOPK_MIN, mesh=None):
     """Batched PPR: seed-derived multi-source queries, one shared engine.
 
@@ -282,7 +269,7 @@ def run_ppr(g, eps: float, walks_per_query: int, num_queries: int,
         queries.append((sources, None))
     res = batched_personalized_pagerank(
         g, eps, queries, walks_per_query, jax.random.PRNGKey(seed),
-        mesh=mesh, use_pallas=use_pallas)
+        mesh=mesh)
     peak = max(res.active_trace) if res.active_trace else 0
     print(f"[pagerank] algo=ppr n={g.n} shards={res.shards} "
           f"queries={num_queries} walks/query={walks_per_query} "
@@ -325,13 +312,11 @@ def _span_totals(records, job: str = "counts.job") -> str:
 def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
         checkpoint_dir: str | None, fail_at: list[int], seed: int = 0,
         algo: str = "walks", avg_deg: float = 6.0, resume: bool = False,
-        check: bool = False, use_pallas: Optional[bool] = None,
-        num_queries: int = 4, shards: int | None = None,
+        check: bool = False, num_queries: int = 4, shards: int | None = None,
         max_restarts: int = 16,
         graph: Optional[CSRGraph] = None) -> RunResult:
     """One run of `algo` on a `graph_kind` graph made from `seed` (or on
-    `graph`, when given, in place of generating one). `use_pallas=None`
-    defers to REPRO_USE_PALLAS; an explicit True/False wins."""
+    `graph`, when given, in place of generating one)."""
     if resume and not checkpoint_dir:
         raise SystemExit("[pagerank] --resume needs --checkpoint-dir "
                          "(there is no snapshot to cold-start from)")
@@ -354,16 +339,16 @@ def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
         # power-iteration report below does not apply to it
         return RunResult(pi=run_ppr(g, eps, walks_per_node * g.n,
                                     num_queries, seed, check=check,
-                                    use_pallas=use_pallas, mesh=mesh))
+                                    mesh=mesh))
     if algo == "walks":
         pi = run_walks(g, eps, walks_per_node, checkpoint_dir, fail_at,
-                       seed, resume=resume, use_pallas=use_pallas,
-                       mesh=mesh, max_restarts=max_restarts)
+                       seed, resume=resume, mesh=mesh,
+                       max_restarts=max_restarts)
     elif algo == "counts":
         res = distributed_pagerank_counts(
             g, eps, walks_per_node, jax.random.PRNGKey(seed), mesh=mesh,
             checkpoint_dir=checkpoint_dir, fail_at=fail_at, resume=resume,
-            max_restarts=max_restarts, use_pallas=use_pallas)
+            max_restarts=max_restarts)
         print(f"[pagerank] algo=counts n={g.n} shards={res.shards} "
               f"rounds={res.rounds} restarts={res.restarts} "
               f"lane_cap={res.lane_cap} "
@@ -378,7 +363,7 @@ def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
         res = engine(g, eps, walks_per_node, jax.random.PRNGKey(seed),
                      mesh=mesh, checkpoint_dir=checkpoint_dir,
                      fail_at=fail_at, resume=resume,
-                     max_restarts=max_restarts, use_pallas=use_pallas)
+                     max_restarts=max_restarts)
         print(f"[pagerank] algo={algo} n={g.n} shards={res.shards} "
               f"lam={res.lam} eta={res.eta} ell={res.ell} "
               f"rounds={res.rounds} restarts={res.restarts} "
@@ -443,10 +428,6 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="non-zero exit if the accuracy report misses "
                          "L1 < 0.15 / top-10 >= 0.6 (CI smoke gate)")
-    ap.add_argument("--use-pallas", action="store_true",
-                    help="route the hot paths through the Pallas kernels "
-                         "(bit-identical results; interpret mode on CPU). "
-                         "REPRO_USE_PALLAS=1 is the flagless equivalent")
     ap.add_argument("--audit", action="store_true",
                     help="run the CONGEST wire-budget + lint auditor over "
                          "every engine instead of a PageRank run: prints "
@@ -460,7 +441,7 @@ def main():
 
         from repro.analysis.congest import (audit_all_engines,
                                             format_wire_table)
-        report = audit_all_engines(use_pallas=args.use_pallas, eps=args.eps)
+        report = audit_all_engines(eps=args.eps)
         print(format_wire_table(report))
         with open("AUDIT.json", "w") as f:
             json.dump(report, f, indent=2, sort_keys=True)
@@ -470,9 +451,7 @@ def main():
         return
     run(args.n, args.eps, args.walks, args.graph, args.checkpoint_dir,
         args.fail_at, seed=args.seed, algo=args.algo, avg_deg=args.avg_deg,
-        resume=args.resume, check=args.check,
-        use_pallas=args.use_pallas or None,
-        num_queries=args.queries, shards=args.shards,
+        resume=args.resume, check=args.check, num_queries=args.queries, shards=args.shards,
         max_restarts=args.max_restarts)
 
 

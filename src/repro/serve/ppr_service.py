@@ -151,7 +151,6 @@ class ResultCache:
 class PPRService:
     def __init__(self, graph: CSRGraph, eps: float, *, slots: int,
                  walks_per_query: int, mesh=None, cap: Optional[int] = None,
-                 use_pallas: Optional[bool] = None,
                  cache_entries: int = 256, ttl: float = math.inf,
                  refresh_age: Optional[float] = None,
                  max_pending: Optional[int] = None,
@@ -160,7 +159,7 @@ class PPRService:
         self.eps = float(eps)
         self.engine = BatchedPPREngine(
             graph, eps, num_slots=slots, walks_per_query=walks_per_query,
-            mesh=mesh, cap=cap, use_pallas=use_pallas)
+            mesh=mesh, cap=cap)
         self.engine.reset(key if key is not None else jax.random.PRNGKey(0))
         self._master_key = (key if key is not None
                             else jax.random.PRNGKey(0))
@@ -245,8 +244,7 @@ class PPRService:
         old = self.engine
         new = BatchedPPREngine(
             self.graph, self.eps, num_slots=old.Q,
-            walks_per_query=old.walks_per_query, mesh=mesh,
-            use_pallas=old.use_pallas)
+            walks_per_query=old.walks_per_query, mesh=mesh)
         new.relayout_from(old)
         self.engine = new
 

@@ -62,13 +62,6 @@ def test_ppr_reference_matches_exact_ppr(graph):
                                    atol=1e-5)
 
 
-def test_kernel_phase():
-    out = chip_smoke.kernel_phase(log2_n=LOG2_N, walks_per_node=16)
-    # interpret mode on the CPU: the kernel path draws the same counts
-    assert out["bit_identical"] and out["zeta_l1"] == 0.0
-    assert out["tpu_custom_calls"] == 0
-
-
 def test_four_chip_phase():
     out = run_forced_devices(f"""
 import json, sys

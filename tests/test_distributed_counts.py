@@ -238,3 +238,48 @@ def test_a_job_that_could_wrap_the_int32_sum_is_refused_at_build():
         distributed_pagerank_counts(ring(4), 0.2, 1 << 29,
                                     jax.random.PRNGKey(0))
     assert not [r for r in tracing.spans() if r.name == "round.counts"]
+
+
+COUNTS_LAYOUT_PARITY_CODE = """
+import json
+import jax, numpy as np
+from repro.graphs import barabasi_albert_hub
+from repro.core.distributed_counts import distributed_pagerank_counts
+
+g = barabasi_albert_hub(96, 3, seed=4)
+a = distributed_pagerank_counts(g, 0.2, 100, jax.random.PRNGKey(3))
+rc = distributed_pagerank_counts(g, 0.2, 100, jax.random.PRNGKey(3),
+                                 bucketed=False)
+print(json.dumps(dict(
+    layout_equal=bool(np.array_equal(np.asarray(a.zeta),
+                                     np.asarray(rc.zeta))),
+    rounds=[a.rounds, rc.rounds],
+    residual=[a.residual, rc.residual],
+    overflow=[a.overflow, rc.overflow])))
+"""
+
+
+def test_counts_engine_pallas_and_layout_bit_parity():
+    """The count engine's draws are a pure function of (key, row id,
+    slot): the bucketed and the flat single-bucket layout must give
+    bit-identical trajectories on the hub fixture."""
+    from conftest import run_forced_devices
+    r = run_forced_devices(COUNTS_LAYOUT_PARITY_CODE)
+    assert r["layout_equal"]
+    assert len(set(r["rounds"])) == 1
+    assert r["residual"] == [0, 0]
+    assert r["overflow"] == [0, 0]
+
+
+@pytest.mark.parametrize("entry", ["counts", "improved"])
+def test_use_pallas_is_refused(entry):
+    """The two entry points the benchmark calls keep a `use_pallas`
+    keyword only to refuse a true value: there is no kernel path."""
+    import jax
+    from repro.core.distributed_counts import distributed_pagerank_counts
+    from repro.core.distributed_improved import distributed_improved_pagerank
+    from repro.graphs import ring
+    run = dict(counts=distributed_pagerank_counts,
+               improved=distributed_improved_pagerank)[entry]
+    with pytest.raises(ValueError, match="use_pallas"):
+        run(ring(8), 0.2, 2, jax.random.PRNGKey(0), use_pallas=True)

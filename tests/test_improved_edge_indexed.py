@@ -60,13 +60,11 @@ DEAL_CODE = textwrap.dedent("""
     pool = demand_pool_sizes(g, eps, K, lam)
     ms, _, _, plan = di._build_three_phase(
         g, eps, K, jax.random.PRNGKey(5), mesh, pool_np=pool, lam=lam,
-        cap2=None, route_cap2=None, max_rounds=100, use_pallas=False,
-        bucketed=True)
+        cap2=None, route_cap2=None, max_rounds=100, bucketed=True)
     L, n_loc = plan.layout, plan.n_loc
     req, samp, asn = di._make_p1_steps(
         mesh, eps=eps, n_loc=n_loc, shards=shards, rep_cap=plan.rep_cap,
-        S_loc_pad=plan.S_loc_pad, layout=L, use_pallas=False,
-        count_bound=plan.S_total)
+        S_loc_pad=plan.S_loc_pad, layout=L)
     spec = NamedSharding(mesh, P(di.AXIS))
     bperm = jax.device_put(plan.bperm_np, spec)
     bnbr = jax.device_put(plan.bnbr_np, spec)

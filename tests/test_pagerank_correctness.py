@@ -4,6 +4,8 @@ bias, Monte-Carlo scaling, round complexity, coupon accounting).
 Engine-vs-power-iteration equivalence checks live in ONE place now — the
 cross-engine gate in `test_engine_conformance.py` — not per-engine here."""
 import jax
+import numpy as np
+import pytest
 
 from repro.core import (exact_pagerank, improved_pagerank, l1_error,
                         normalized, power_iteration, simple_pagerank,
@@ -18,6 +20,36 @@ def test_power_iteration_matches_eigenvector(small_graphs):
         pi_exact = exact_pagerank(g, EPS)
         assert l1_error(pi, pi_exact) < 1e-4, name
         assert iters < 200
+
+
+def _dense_pagerank(g, eps):
+    """PageRank by one dense float64 linear solve of
+    pi = eps/n + (1 - eps) (Q^T pi + dangling mass / n), Q the
+    row-stochastic out-edge matrix: the fixed point power iteration
+    converges to, found without iterating."""
+    n = g.n
+    row_ptr, col = np.asarray(g.row_ptr), np.asarray(g.col_idx)
+    deg = np.diff(row_ptr)
+    A = np.zeros((n, n))               # A[u, v] = P(step v -> u)
+    for v in range(n):
+        if deg[v]:
+            np.add.at(A[:, v], col[row_ptr[v]:row_ptr[v + 1]], 1.0 / deg[v])
+        else:
+            A[:, v] = 1.0 / n
+    return np.linalg.solve(np.eye(n) - (1 - eps) * A, np.full(n, eps / n))
+
+
+@pytest.mark.parametrize("name", ["ring", "grid", "er", "ba", "ba_hub",
+                                  "dweb"])
+def test_power_iteration_matches_dense_solve(small_graphs, name):
+    """The segment-sum push converges to the dense solve's fixed point,
+    dangling vertices (the directed web graph has them) included."""
+    g = small_graphs[name]
+    pi, err, _ = power_iteration(g, EPS, tol=1e-7)
+    want = _dense_pagerank(g, EPS)
+    assert err <= 1e-7
+    np.testing.assert_allclose(np.asarray(pi, np.float64), want,
+                               rtol=1e-5, atol=1e-8)
 
 
 def test_simple_pagerank_unbiased_total_mass(small_graphs):

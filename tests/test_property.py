@@ -11,8 +11,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import engine_counts, routing
 from repro.core.graph import from_edges, padded_adjacency
-from repro.kernels.histogram import histogram
-from repro.kernels.histogram.ref import histogram_ref
+from repro.core.routing import vertex_histogram
 from repro.models.moe import _rank_within
 from repro.train.optimizer import dequantize_blockwise, quantize_blockwise
 
@@ -24,9 +23,14 @@ settings.load_profile("ci")
                 max_size=400),
        st.integers(min_value=1, max_value=50))
 def test_histogram_matches_ref(ids, n):
-    ids = jnp.asarray(ids, jnp.int32)
-    np.testing.assert_array_equal(np.asarray(histogram(ids, n)),
-                                  np.asarray(histogram_ref(ids, n)))
+    """`vertex_histogram` counts the ids in [0, n) and drops the rest
+    (-1 padding, ids past the last vertex), as `np.bincount` over the
+    in-range ids does."""
+    ids_np = np.asarray(ids, np.int32)
+    got = vertex_histogram(jnp.asarray(ids_np),
+                           jnp.ones(ids_np.shape, bool), n)
+    want = np.bincount(ids_np[(ids_np >= 0) & (ids_np < n)], minlength=n)
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 @given(st.lists(st.integers(min_value=0, max_value=9), min_size=1,
@@ -296,7 +300,7 @@ def test_residual_zero_at_bucket_boundary_degrees(k, count, seed):
     kw = jnp.asarray(np.array([seed, seed ^ 0xABCDEF], np.uint32))
     samples, occ, residual = sample_buckets(
         counts, jnp.asarray(degs), rid, kw, jnp.asarray(perm), layout,
-        eps=0.2, use_pallas=False)
+        eps=0.2)
     assert int(residual) == 0
     total = sum(int(T.sum()) for _, T in samples)
     assert total == int(counts.sum())

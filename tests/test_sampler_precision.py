@@ -5,7 +5,7 @@ per-round draws ran `jax.random.binomial(k, counts.astype(float32), p)`.
 float32 is integer-exact only up to 2**24, so a hub row whose aggregate
 coupon count passed ~16.7M silently truncated — coupons created or
 destroyed before the draw even happened. The shared sampler
-(`kernels/multinomial_rows`) keeps counts in int32 end to end: the
+(`core/binomial_tree`) keeps counts in int32 end to end: the
 Binomial endpoints p == 0 and p == 1 are computed in integer arithmetic
 and every split hands its halves r and c - r of an integer count c, so
 conservation (T.sum() == counts) holds bit-exactly at ANY count
@@ -14,19 +14,33 @@ float32 (a ~1e-7 relative statistical error, never a leak).
 
 The split over a row's out-edges is a binomial tree over dyadic slot
 intervals; the tests below also check that it draws the multinomial it
-stands for, and that a row's draws do not depend on the width it is
-padded to.
+stands for, that a row's draws do not depend on the width it is padded
+to, and that the degree-bucketed sampler (`aggregate_sampler`) draws
+what the tree draws over the whole row vector at once, in a bucket of
+any capacity.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.multinomial_rows._math import (_BINV_MEAN_MAX, key_words,
-                                                  sample_rows_math)
-from repro.kernels.multinomial_rows.ref import multinomial_rows_ref
+from repro.core.aggregate_sampler import BucketLayout, sample_buckets
+from repro.core.binomial_tree import (_BINV_MEAN_MAX, key_words,
+                                      sample_rows_math)
 
 KW = (np.uint32(0x12345678), np.uint32(0x9ABCDEF0))
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "width"))
+def multinomial_rows_ref(counts, deg, rid, key_words, *, eps: float,
+                         width: int):
+    """The oracle: T [R, width+1] int32, column 0 the terminations and
+    1+j the count on out-edge slot j, from the tree's math over the whole
+    row vector at once (one block, no buckets)."""
+    return sample_rows_math(counts, deg, rid, key_words[0], key_words[1],
+                            eps=eps, width=width)
 
 
 def _sample(counts, deg, *, eps=0.2, width=None):
@@ -144,3 +158,55 @@ def test_draws_do_not_depend_on_the_width(deg):
         np.testing.assert_array_equal(T[:, :deg + 1], Ts[0][:, :deg + 1])
         assert not T[:, deg + 1:].any()
     np.testing.assert_array_equal(Ts[0].sum(axis=1), np.asarray(counts))
+
+
+def _one_bucket(counts, deg, rid, kw, perm, *, cap, width, eps):
+    """`sample_buckets` over one bucket of `cap` rows: its T block."""
+    layout = BucketLayout(widths=(width,), caps=(cap,),
+                          n_rows=counts.shape[0])
+    run = jax.jit(functools.partial(sample_buckets, layout=layout, eps=eps))
+    samples, occ, residual = run(counts, deg, rid, kw,
+                                 jnp.asarray(perm, jnp.int32))
+    assert int(residual) == 0
+    ((rows, T),) = samples
+    np.testing.assert_array_equal(np.asarray(rows), perm)
+    return np.asarray(T)
+
+
+@pytest.mark.parametrize("R,width,eps", [(64, 4, 0.2), (1000, 8, 0.1),
+                                         (4096, 16, 0.5), (257, 1, 0.3),
+                                         (1, 32, 0.2)])
+def test_sample_buckets_matches_ref(R, width, eps, key):
+    """The bucketed sampler on one bucket draws what the oracle draws
+    over the same rows (counter RNG: the same draws in any grouping)."""
+    k1, k2 = jax.random.split(key)
+    counts = jax.random.randint(k1, (R,), 0, 5000)
+    deg = jax.random.randint(k2, (R,), 0, width + 1)
+    rid = jnp.arange(R, dtype=jnp.int32) * 3 + 11
+    kw = jnp.asarray(np.array([0xDEADBEEF, 0x12345678], np.uint32))
+    got = _one_bucket(counts, deg, rid, kw, np.arange(R), cap=R,
+                      width=width, eps=eps)
+    want = multinomial_rows_ref(counts, deg, rid, kw, eps=eps, width=width)
+    np.testing.assert_array_equal(got, np.asarray(want))
+    # conservation: rows whose degree fits the width leak nothing
+    np.testing.assert_array_equal(got.sum(axis=1), np.asarray(counts))
+
+
+@pytest.mark.parametrize("cap", [256, 1024])
+def test_draws_do_not_depend_on_bucket_capacity(cap, key):
+    """A row draws the same counts whichever bucket holds it and however
+    many rows (and -1 padding slots) that bucket has."""
+    R, width = 3000, 8
+    counts = jax.random.randint(key, (R,), 0, 300)
+    deg = jnp.full((R,), 5, jnp.int32)
+    rid = jnp.arange(R, dtype=jnp.int32)
+    kw = jnp.asarray(np.array([1, 2], np.uint32))
+    want = np.asarray(multinomial_rows_ref(counts, deg, rid, kw, eps=0.2,
+                                           width=width))
+    rows = np.random.default_rng(cap).choice(R, cap // 2, replace=False)
+    perm = np.full(cap, -1, np.int32)
+    perm[::2] = rows                        # every other slot is padding
+    got = _one_bucket(counts, deg, rid, kw, perm, cap=cap, width=width,
+                      eps=0.2)
+    np.testing.assert_array_equal(got[::2], want[rows])
+    assert not got[1::2].any()

@@ -1,33 +1,33 @@
-"""Compile the Pallas kernels for a TPU v5e chip at main-path shapes.
+"""Compile the cells' stage programs for a TPU v5e chip.
 
 No chip is needed: the TPU compiler compiles for a described, unattached
 v5e topology, and refuses what the chip's compiler would refuse
-(unaligned blocks, unsupported gathers, too much VMEM). The shapes are
-those of the one-chip smoke run (`chip_smoke.py`): a `directed_web`
-graph with n = 2^22 vertices and about 16.6M edges, whose counts-engine
-buckets hold up to ~2.2M rows at widths 1..14.
+(unsupported ops, too much memory). The programs are the XLA programs
+the benchmark's cells run, built by the engines' own step makers (the
+CONGEST audit specs) over a mesh of described chips: the counts
+engine's `jit_sample` at bucket widths 1 to 14 and its `jit_exchange`,
+on one chip and on the 2x2 host's four; Algorithm 2's Phase-1, stitch
+and count programs on one chip. The graphs are small, so the file
+stays quick; full-size compiles are for a scratch script before a chip
+run.
 
 The topology is described inside a module fixture, never while a module
 is imported, so every pytest worker collects the same tests and only the
 worker running this file loads the TPU library.
 """
-import functools
-
 import jax
-import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.kernels.histogram.histogram import histogram_pallas
-from repro.kernels.multinomial_rows.multinomial_rows import \
-    multinomial_rows_pallas
-from repro.kernels.segment_spmv.segment_spmv import segment_spmv_pallas
-from repro.kernels.walk_step import walk_step
-from repro.kernels.walk_step.walk_step import walk_step_pallas
+from repro.core import distributed_counts, distributed_improved
+from repro.core.distributed import AXIS
+from repro.core.graph import from_edges
+from repro.graphs import erdos_renyi
 
-N = 1 << 22            # vertices of the smoke run's graph
-M = 16_595_258         # its edges
-BUCKET_ROWS = 2_177_084   # rows of its largest degree bucket
+EPS = 0.15
+WALKS_PER_NODE = 32
+N = 1 << 10
 
 
 @pytest.fixture(scope="module")
@@ -41,68 +41,90 @@ def topo():
 
 
 @pytest.fixture(scope="module")
-def one_chip(topo):
-    """Shape factory on one described chip, with the persistent compile
-    cache off: entries compiled for an unattached chip cannot be read
-    back without one."""
+def meshes(topo):
+    """Meshes of one and of four described chips, with the persistent
+    compile cache off: entries compiled for an unattached chip cannot be
+    read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    sharding = SingleDeviceSharding(topo.devices[0])
-    yield lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=sharding)
+    yield {k: Mesh(np.array(topo.devices[:k]), (AXIS,)) for k in (1, 4)}
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
-    return compiled
+@pytest.fixture(scope="module")
+def graph():
+    return erdos_renyi(N, 8.0, seed=1)
+
+
+def _graph_of_width(width: int):
+    """A directed graph on N vertices whose out-degrees run 0..width, so
+    the sampler's buckets are those of a graph with max degree `width`."""
+    rng = np.random.default_rng(width)
+    deg = np.arange(N) % (width + 1)
+    src = np.repeat(np.arange(N), deg)
+    dst = (src + 1 + rng.integers(0, N - 1, size=len(src))) % N
+    return from_edges(src, dst, N, dedup=False)
+
+
+def _compile(mesh, prog):
+    """Compile `prog` with its example arguments placed on `mesh`: the
+    per-shard arrays split over the shard axis, scalars replicated."""
+    shards = mesh.devices.size
+
+    def place(x):
+        split = x.ndim > 0 and x.shape[0] == shards
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=NamedSharding(mesh, P(AXIS) if split else P()))
+
+    args = jax.tree_util.tree_map(place, prog.example_args)
+    text = prog.fn.lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    return text
+
+
+def _counts_program(mesh, graph, name):
+    spec = distributed_counts.audit_spec(graph, mesh, eps=EPS,
+                                         walks_per_node=WALKS_PER_NODE)
+    (prog,) = [p for p in spec.programs if p.program == name]
+    return prog
 
 
 @pytest.mark.parametrize("width", [1, 2, 4, 8, 14])
-def test_multinomial_rows_compiles(one_chip, width):
-    rows = one_chip((BUCKET_ROWS,))
-    _compile(functools.partial(multinomial_rows_pallas, eps=0.2, width=width,
-                               interpret=False),
-             rows, rows, rows, one_chip((2,), jnp.uint32))
+def test_sample_compiles(meshes, width):
+    g = _graph_of_width(width)
+    assert g.max_out_deg == width
+    prog = _counts_program(meshes[1], g, "sample")
+    assert prog.fn.__name__ == "sample"          # jit_sample in a trace
+    _compile(meshes[1], prog)
 
 
-def test_histogram_compiles(one_chip):
-    _compile(functools.partial(histogram_pallas, num_segments=N,
-                               interpret=False), one_chip((1 << 21,)))
+def test_exchange_compiles(meshes, graph):
+    prog = _counts_program(meshes[1], graph, "exchange")
+    assert prog.fn.__name__ == "exchange"
+    text = _compile(meshes[1], prog)
+    assert "all-to-all" not in text               # one shard: no wire
 
 
-def test_segment_spmv_compiles(one_chip):
-    _compile(functools.partial(segment_spmv_pallas, num_segments=N,
-                               interpret=False),
-             one_chip((M,), jnp.float32), one_chip((M,)))
+def test_exchange_compiles_on_four_chips(meshes, graph):
+    text = _compile(meshes[4],
+                    _counts_program(meshes[4], graph, "exchange"))
+    assert "all-to-all" in text
 
 
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="Mosaic: Only 2D gather is supported")
-def test_walk_step_compiles(one_chip):
-    walks = one_chip((1 << 18,))
-    u = one_chip((1 << 18,), jnp.float32)
-    try:
-        _compile(functools.partial(walk_step_pallas, eps=0.2,
-                                   interpret=False),
-                 walks, walks, u, u, one_chip((N + 1,)), one_chip((M,)),
-                 one_chip((N,)))
-    except NotImplementedError as e:
-        # any other refusal is a new failure, not this expected one
-        if "Only 2D gather is supported" not in str(e):
-            raise AssertionError(f"walk_step refused for another reason: "
-                                 f"{e}") from e
-        raise
-
-
-def test_walk_step_refuses_compiled_call():
-    """Off interpret mode the public wrapper raises instead of falling
-    back to interpret mode or to the jnp path."""
-    x = jnp.zeros((8,), jnp.int32)
-    with pytest.raises(NotImplementedError, match="does not compile for TPU"):
-        walk_step(x, x, x.astype(jnp.float32), x.astype(jnp.float32),
-                  jnp.zeros((3,), jnp.int32), x, x[:2], eps=0.2,
-                  interpret=False)
+@pytest.mark.parametrize("stage,name,jitted", [
+    ("phase1", "request", "p1_request"),
+    ("phase1", "sample", "p1_sample"),
+    ("phase1", "assign", "p1_assign"),
+    ("phase2", "stitch", "p2_stitch"),
+    ("phase3", "count", "p3_count"),
+])
+def test_improved_stage_compiles(meshes, graph, stage, name, jitted):
+    spec = distributed_improved.audit_spec(graph, meshes[1], eps=EPS,
+                                           walks_per_node=WALKS_PER_NODE)
+    (prog,) = [p for p in spec.programs
+               if (p.stage, p.program) == (stage, name)]
+    assert prog.fn.__name__ == jitted
+    _compile(meshes[1], prog)
