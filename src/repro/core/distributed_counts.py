@@ -65,7 +65,8 @@ from repro.core.binomial_tree import key_words
 from repro.core.distributed import AXIS, shard_map
 from repro.core.estimator import pagerank_from_visits
 from repro.core.graph import CSRGraph
-from repro.core.routing import entry_nbytes, lane_slots
+from repro.core.routing import (entry_nbytes, lane_slots,
+                                sorted_segment_sums)
 from repro.checkpoint import LayoutSpec
 from repro.runtime import Stage, StagedState, StageSchedule, run_staged
 from repro.runtime import tracing
@@ -195,20 +196,6 @@ def _sample_step(bperm, deg, counts, key, *, eps: float, n_loc: int,
     occ = jax.lax.psum(occ, AXIS)
     residual = jax.lax.psum(residual, AXIS)
     return flat_T[None], key[None], occ, residual
-
-
-def sorted_segment_sums(dst, ends, values):
-    """Per-vertex sums of per-slot `values`: sort them by their static
-    destination `dst`, cumsum, and difference the prefix sums at each
-    vertex's segment `ends` ([n + 1], vertex v's slots are
-    [ends[v], ends[v + 1]) in dst order). Slots keyed past the last
-    vertex sort after every segment and count for none. Integer sums do
-    not depend on order, so this equals a scatter-add exactly; on the chip
-    a sort moves data several times faster than a scatter or a gather."""
-    _, v = jax.lax.sort((dst, values), num_keys=1)
-    csum = jnp.cumsum(v)
-    at = jnp.where(ends > 0, csum[jnp.maximum(ends - 1, 0)], 0)
-    return at[1:] - at[:-1]
 
 
 def _exchange_step(index: ExchangeIndex, flat_T, zeta, *, n_loc: int,

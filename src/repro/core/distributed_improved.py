@@ -63,7 +63,11 @@ Phase 2 — stitching. The n*K long walks are anonymous too ("which coupon
   (natural-order consumption — distributionally identical to
   uniform-without-replacement because coupons are iid), marks them used,
   retires walks whose coupon recorded an eps-reset, and ships the rest as
-  per-destination counts (`route_counts`, 8 B/entry). Walks at an
+  per-destination counts (`route_counts`, 8 B/entry). A coupon's final
+  vertex never changes during Phase 2, so the counts are summed by a sort
+  of the round's movers on that fixed key, a cumsum and a take at segment
+  ends that one program (`jit_p2_index`) builds at Phase 2's entry
+  (`routing.sorted_segment_sums`), not by a scatter-add. Walks at an
   exhausted pool (undersized — the paper's whp bound violated)
   accumulate in a per-vertex tail count for the naive fallback.
 
@@ -86,10 +90,10 @@ distinct cells), so `dropped` stays 0 by construction; the naive tail's
 buffer holds every tail walk.
 
 Each stage program has its own name in a device trace: `jit_p1_request`,
-`jit_p1_sample`, `jit_p1_assign`, `jit_p2_stitch`, `jit_p3_count` and
-`jit_tail_step`. `max_rounds` caps each stage's rounds: a stage that
-reaches it stops, and the result counts the walks still out
-(`unfinished`).
+`jit_p1_sample`, `jit_p1_assign`, `jit_p2_index`, `jit_p2_stitch`,
+`jit_p3_count` and `jit_tail_step`. `max_rounds` caps each stage's
+rounds: a stage that reaches it stops, and the result counts the walks
+still out (`unfinished`).
 
 The phases only ever see a per-node pool-size vector, so the whole driver
 lives in the budget-policy-agnostic `_run_three_phase`; this module's
@@ -133,7 +137,8 @@ from repro.core.graph import CSRGraph
 from repro.core.improved_pagerank import (coupon_pool_sizes,
                                           demand_pool_sizes)
 from repro.core.routing import (entry_nbytes, exchange_stacked, lane_slots,
-                                pack_lanes, route_counts, vertex_histogram)
+                                pack_lanes, route_counts,
+                                sorted_segment_sums, vertex_histogram)
 from repro.checkpoint import LayoutSpec
 from repro.core.simple_pagerank import walks_per_node_for
 from repro.runtime import Stage, StagedState, StageSchedule, run_staged
@@ -382,20 +387,47 @@ def _make_p1_steps(mesh: Mesh, *, eps: float, n_loc: int, shards: int,
 # Phase 2: count-aggregated coupon stitching
 # ---------------------------------------------------------------------------
 
-def _p2_local(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
-              *, n_loc: int, shards: int, S_loc_pad: int):
+def _p2_index_local(dest, *, n_loc: int, shards: int):
+    """The stitch's sort index, fixed for all of Phase 2: each slot's key,
+    its coupon's final vertex (a padding slot, dest -1, is keyed n_pad, so
+    it sorts past every vertex and counts for none), and the shard's segment
+    ends over its own slots, ends[v] = the slots keyed below v."""
+    dest = dest[0]
+    n_pad = shards * n_loc
+    dkey = jnp.where(dest >= 0, dest, n_pad)
+    ends = jnp.searchsorted(jnp.sort(dkey),
+                            jnp.arange(n_pad + 1, dtype=jnp.int32))
+    return dkey[None], ends.astype(jnp.int32)[None]
+
+
+@lru_cache(maxsize=64)
+def _make_p2_index(mesh: Mesh, *, n_loc: int, shards: int):
+    fn = partial(_p2_index_local, n_loc=n_loc, shards=shards)
+    sharded = shard_map(fn, mesh, in_specs=(P(AXIS),),
+                        out_specs=(P(AXIS), P(AXIS)))
+
+    @jax.jit
+    def p2_index(dest):
+        return sharded(dest)
+
+    return p2_index
+
+
+def _p2_local(walks, next_c, used, tail_cnt, dkey, ends, cterm, psize,
+              pstart, *, n_loc: int, shards: int, S_loc_pad: int):
     """One stitch superstep. Long walks are anonymous, so the state is a
     per-owned-vertex count: allocate the next unused coupons of each
     vertex's pool to the walks waiting there (natural-order consumption —
     distributionally identical to uniform-without-replacement because
     coupons are iid), retire walks whose coupon recorded an eps-reset,
     route the movers as per-destination counts, and bank pool-exhausted
-    walks in `tail_cnt` for the naive fallback."""
-    (walks, next_c, used, tail_cnt, dest, cterm, psize, pstart) = (
-        walks[0], next_c[0], used[0], tail_cnt[0], dest[0], cterm[0],
-        psize[0], pstart[0])
+    walks in `tail_cnt` for the naive fallback. The movers are summed per
+    destination by a sort on the slots' fixed keys (`_p2_index_local`),
+    not a scatter-add."""
+    (walks, next_c, used, tail_cnt, dkey, ends, cterm, psize, pstart) = (
+        walks[0], next_c[0], used[0], tail_cnt[0], dkey[0], ends[0],
+        cterm[0], psize[0], pstart[0])
     shard_id = jax.lax.axis_index(AXIS)
-    n_pad = shards * n_loc
 
     a = jnp.minimum(walks, psize - next_c)        # coupons allocatable now
     exh = walks - a                               # pool empty: naive tail
@@ -410,7 +442,7 @@ def _p2_local(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
     next_c = next_c + a
     term_now = alloc & (cterm > 0)      # coupon's eps-reset fired: walk done
     go = alloc & (cterm == 0)           # walk continues at coupon's dest
-    dcnt = vertex_histogram(dest, go, n_pad)
+    dcnt = sorted_segment_sums(dkey, ends, go.astype(jnp.int32))
     arrivals, sent_entries, sent_bytes = route_counts(
         dcnt, axis=AXIS, shard_id=shard_id, n_loc=n_loc, shards=shards)
     tail_cnt = tail_cnt + exh
@@ -429,13 +461,14 @@ def _p2_local(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart,
 def _make_p2_step(mesh: Mesh, *, n_loc: int, shards: int, S_loc_pad: int):
     fn = partial(_p2_local, n_loc=n_loc, shards=shards, S_loc_pad=S_loc_pad)
     sharded = shard_map(fn, mesh,
-                        in_specs=(P(AXIS),) * 8,
+                        in_specs=(P(AXIS),) * 9,
                         out_specs=(P(AXIS),) * 4 + (P(),) * 6)
 
     @jax.jit
-    def p2_stitch(walks, next_c, used, tail_cnt, dest, cterm, psize, pstart):
-        return sharded(walks, next_c, used, tail_cnt, dest, cterm, psize,
-                       pstart)
+    def p2_stitch(walks, next_c, used, tail_cnt, dkey, ends, cterm, psize,
+                  pstart):
+        return sharded(walks, next_c, used, tail_cnt, dkey, ends, cterm,
+                       psize, pstart)
 
     return p2_stitch
 
@@ -775,7 +808,8 @@ def _run_three_phase(
 
     A job is one `<name>.job` span of `runtime.tracing` (counts `rounds`,
     `phase1_rounds`, `phase2_rounds`, `tail_rounds`, `tail_walks` and
-    `coupons`): `<name>.build` (pools, plan, placement, device puts),
+    `coupons`): `<name>.build` (pools, plan, placement, device puts;
+    count `stitch_sort_entries`, the slots a stitch round sorts),
     one `round.<stage>` per round — a Phase-1 round holds `p1.request`,
     `p1.sample`, `p1.assign` (the three dispatches) and `p1.sync` — then
     `<name>.finish` (the estimator).
@@ -811,6 +845,8 @@ def _run_three_phase(
                 graph, eps, K, key, mesh, pool_np=pool_np, lam=lam,
                 cap2=cap2, route_cap2=route_cap2, max_rounds=max_rounds,
                 bucketed=bucketed)
+            # the slots the stitch sorts each round
+            tracing.count("stitch_sort_entries", plan.S_loc_pad)
 
         # global rounds sum over the four stages, each bounded by
         # max_rounds
@@ -874,8 +910,8 @@ _compiled: set = set()
 def _compile_concurrently(programs) -> None:
     """Compile jitted programs for their arguments' shapes on threads of
     their own: the compiler then works on them side by side (at 7.8M
-    coupons the five stage programs take about three minutes to compile
-    for a TPU v5e one after another, 75 s side by side), and each later
+    coupons the stage programs took about three minutes to compile for
+    a TPU v5e one after another, 75 s side by side), and each later
     call with those shapes finds its program compiled. A program
     compiled before is left alone."""
     def sig(args):
@@ -939,6 +975,7 @@ def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
     p1_req, p1_samp, p1_asn = _make_p1_steps(
         mesh, eps=float(eps), n_loc=n_loc, shards=shards,
         rep_cap=plan.rep_cap, S_loc_pad=S_loc_pad, layout=layout)
+    p2_idx = _make_p2_index(mesh, n_loc=n_loc, shards=shards)
     p2_step = _make_p2_step(mesh, n_loc=n_loc, shards=shards,
                             S_loc_pad=S_loc_pad)
     p3_step = _make_p3_step(mesh, n_loc=n_loc, shards=shards)
@@ -949,6 +986,7 @@ def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
         return jax.ShapeDtypeStruct(shape, dtype, sharding=spec)
     vert, slot = sds((shards, n_loc)), sds((shards, S_loc_pad))
     traj = sds((shards, S_loc_pad, lam))
+    ends = sds((shards, shards * n_loc + 1))
     _compile_concurrently([
         (p1_req, (slot, slot)),
         (p1_samp, (bperm_j, sg_dg, sds((shards, shards * n_loc)),
@@ -958,9 +996,21 @@ def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
                                          + layout.total_edges))),
                   slot, slot, traj, sds((shards, 2), jnp.uint32),
                   jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))),
-        (p2_step, (vert, vert, slot, vert, slot, slot, vert, vert)),
+        (p2_idx, (slot,)),
+        (p2_step, (vert, vert, slot, vert, slot, ends, slot, vert, vert)),
         (p3_step, (traj, slot, vert)),
     ])
+
+    # The stitch's sort index is derived from `dest` once at Phase 2's
+    # entry and never snapshotted: a per-shard index over n_pad does not
+    # survive an elastic re-layout, so a resume into Phase 2 rebuilds it
+    # from the restored `dest` at its first stitch.
+    stitch_index = {}
+
+    def _stitch_index(dest):
+        if stitch_index.get("dest") is not dest:
+            stitch_index.update(dest=dest, index=p2_idx(dest))
+        return stitch_index["index"]
 
     # ---------------- stage step functions + host transitions ----------
     # Telemetry lives in the JSON-able `host` dict so a restored snapshot
@@ -1013,13 +1063,15 @@ def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
                                     spec),
             dest=a["pos"], cterm=1 - a["alive"], traj=a["traj"],
             zeta=jax.device_put(jnp.asarray(zeta3_np), spec))
+        _stitch_index(ms.arrays["dest"])
         return ms
 
     def _phase2(ms: StagedState):
         a = ms.arrays
+        dkey, ends = _stitch_index(a["dest"])
         (walks, next_c, used, tail_cnt, active, stitched, terminated,
          exhausted, entries, nbytes) = p2_step(
-            a["walks"], a["next_c"], a["used"], a["tail_cnt"], a["dest"],
+            a["walks"], a["next_c"], a["used"], a["tail_cnt"], dkey, ends,
             a["cterm"], psize_j, pstart_j)
         a.update(walks=walks, next_c=next_c, used=used, tail_cnt=tail_cnt)
         # one device sync for all six telemetry scalars, not six
@@ -1045,6 +1097,7 @@ def _build_three_phase(graph, eps, K, key, mesh, *, pool_np, lam, cap2,
     def _after_phase2(ms: StagedState) -> StagedState:
         a = ms.arrays
         ms.host["coupons_used"] = int(jnp.sum(a["used"]))
+        stitch_index.clear()
         ms.arrays = dict(traj=a["traj"], used=a["used"], zeta=a["zeta"],
                          tail_cnt=a["tail_cnt"])
         return ms
@@ -1162,7 +1215,7 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
                            engine: str = "improved",
                            bucketed: bool = True):
     """CONGEST-auditor spec for the 3-phase engines (improved + directed
-    frontends): all six stage programs rebuilt through the SAME memoized
+    frontends): all seven stage programs rebuilt through the SAME memoized
     step makers with the SAME statics the engine would use (via
     `plan_three_phase`), each exchange's declared per-round wire budget,
     and the elastic layout schema.
@@ -1183,6 +1236,7 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
     p1_req, p1_samp, p1_asn = _make_p1_steps(
         mesh, eps=float(eps), n_loc=n_loc, shards=shards, rep_cap=rep_cap,
         S_loc_pad=S_loc_pad, layout=layout)
+    p2_idx = _make_p2_index(mesh, n_loc=n_loc, shards=shards)
     p2_step = _make_p2_step(mesh, n_loc=n_loc, shards=shards,
                             S_loc_pad=S_loc_pad)
     p3_step = _make_p3_step(mesh, n_loc=n_loc, shards=shards)
@@ -1208,6 +1262,7 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
     t = sds((), i32)
     vert = sds((shards, n_loc), i32)
     slot = sds((shards, S_loc_pad), i32)
+    ends = sds((shards, shards * n_loc + 1), i32)
     tail_state = DistState(pos=sds((shards, tail_cap), i32), zeta=vert,
                            key=key, round=t, dropped=t, waited=t)
 
@@ -1245,8 +1300,10 @@ def three_phase_audit_spec(graph: CSRGraph, mesh: Mesh, *, eps: float,
                                    traj, key, t),
                      sites=(rep_site,) if shards > 1 else (),
                      count_bound=S_total),
+        StageProgram(stage="phase2", program="index", fn=p2_idx,
+                     example_args=(slot,), sites=(), count_bound=S_total),
         StageProgram(stage="phase2", program="stitch", fn=p2_step,
-                     example_args=(vert, vert, slot, vert, slot, slot,
+                     example_args=(vert, vert, slot, vert, slot, ends, slot,
                                    vert, vert),
                      sites=(ExchangeSite(site="phase2", **_count),),
                      count_bound=n * K),

@@ -21,8 +21,10 @@ scatter + all_to_all), `route_walks`/`merge_walks` (full route superstep for
 walk buffers with arbitrary payload fields riding along), `route_counts`
 (the Lemma-1 count-aggregated exchange: per-destination-vertex counts as
 (vertex, count) lanes, payload independent of how many walks move),
-`advance_owned` (one eps-reset/uniform-out-edge PageRank step for owned
-walks) and `count_owned_arrivals` (owner-side visit accounting).
+`vertex_histogram` and `sorted_segment_sums` (the per-vertex counts it
+ships: a scatter-add, or a sort on keys fixed for a job), `advance_owned`
+(one eps-reset/uniform-out-edge PageRank step for owned walks) and
+`count_owned_arrivals` (owner-side visit accounting).
 
 Wire accounting: `entry_nbytes` is the single source of truth for
 bytes-per-lane-entry — it is derived from the dtypes of the arrays actually
@@ -166,6 +168,20 @@ def vertex_histogram(v: jnp.ndarray, mask: jnp.ndarray,
         mask.astype(jnp.int32),
         jnp.where(mask & (v >= 0) & (v < num_vertices), v, num_vertices),
         num_segments=num_vertices + 1)[:num_vertices]
+
+
+def sorted_segment_sums(dst, ends, values):
+    """Per-vertex sums of per-slot `values`: sort them by their static
+    destination `dst`, cumsum, and difference the prefix sums at each
+    vertex's segment `ends` ([n + 1], vertex v's slots are
+    [ends[v], ends[v + 1]) in dst order). Slots keyed past the last
+    vertex sort after every segment and count for none. Integer sums do
+    not depend on order, so this equals a scatter-add exactly; on the chip
+    a sort moves data several times faster than a scatter or a gather."""
+    _, v = jax.lax.sort((dst, values), num_keys=1)
+    csum = jnp.cumsum(v)
+    at = jnp.where(ends > 0, csum[jnp.maximum(ends - 1, 0)], 0)
+    return at[1:] - at[:-1]
 
 
 def route_counts(per_vertex: jnp.ndarray, *, axis: str,

@@ -66,7 +66,9 @@ available on the returned `ImprovedDistResult`/`DirectedDistResult`):
                  O(sqrt(log n)/eps) undirected resp. O(sqrt(log n / eps))
                  directed.
   coupons        created vs used pool sizes and exhausted walks (pool
-                 ran dry -> naive fallback).
+                 ran dry -> naive fallback), and the slots a stitch
+                 round sorts (`stitch_sort_entries`, a count on the
+                 job's build span).
   wire           all_to_all payload bytes by phase. Every phase ships
                  Lemma-1 aggregated (vertex, count) entries — 8 B/entry
                  for stitch/count traffic, 8+12 B/entry for the Phase-1
@@ -370,9 +372,12 @@ def run(n: int, eps: float, walks_per_node: int, graph_kind: str,
               f"(p1={res.phase1_rounds} "
               f"report={res.report_rounds} p2={res.phase2_rounds} "
               f"p3={res.phase3_rounds} tail={res.tail_rounds})")
+        build = [r for r in tracing.spans() if r.name == algo + ".build"]
         print(f"[pagerank] coupons created={res.coupons_created} "
               f"used={res.coupons_used} exhausted_walks="
-              f"{res.exhausted_walks} tail_walks={res.tail_walks}")
+              f"{res.exhausted_walks} tail_walks={res.tail_walks} "
+              f"stitch_sort_entries="
+              f"{build[-1].counts['stitch_sort_entries']}")
         print(f"[pagerank] wire by phase: {res.a2a_bytes_by_phase} "
               f"dropped={res.dropped} waited={res.waited}")
         print(f"[pagerank] spans: "

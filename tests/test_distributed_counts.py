@@ -112,8 +112,8 @@ def test_sorted_segment_sums_equal_segment_sum(shards, graph):
     given nonzero values) counting for no vertex."""
     import jax
     import numpy as np
-    from repro.core.distributed_counts import (shard_graph_padded,
-                                               sorted_segment_sums)
+    from repro.core.distributed_counts import shard_graph_padded
+    from repro.core.routing import sorted_segment_sums
     g = _graph(graph)
     sg = shard_graph_padded(g, shards)
     pad = sg.bnbr == sg.n_pad

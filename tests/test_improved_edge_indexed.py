@@ -8,7 +8,10 @@ its round cap and its program spans.
 * the rank vector matches the plain reference (`bench/reference/walks.py`)
   on a Graph500 graph, with no walk left to the naive tail;
 * `max_rounds` stops a stage and counts the walks still out;
-* one job records its spans and counts in the ring.
+* one job records its spans and counts in the ring;
+* the stitch's sort on its fixed keys gives every round the arrivals a
+  histogram of the movers' destinations gives, at 1, 2 and 4 shards, and
+  a whole job the `zeta` of the same engine summing by `vertex_histogram`.
 """
 import os
 import sys
@@ -277,3 +280,151 @@ def test_a_job_records_its_spans_and_counts(g500_job):
 ])
 def test_tail_buffer_is_sized_from_its_walks(walks, shards, want):
     assert di._tail_sizes(walks, shards, None, None) == want
+
+
+# Several stitch rounds through the engine's own index and stitch
+# programs, each against a numpy stitch round that histograms the movers'
+# destinations. Vertex 0 is a hub that half the coupons end at; a slot
+# with dest -1 is padding, inside the pools (allocated, cterm 0, so it
+# moves, and must count for no vertex) as well as past them.
+STITCH_CODE = textwrap.dedent("""
+    import json
+    import jax, numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.core import distributed_improved as di
+
+    mesh = Mesh(np.array(jax.devices()), (di.AXIS,))
+    shards = mesh.devices.size
+    n, K = 37, 3
+    n_loc = -(-n // shards)
+    n_pad = shards * n_loc
+    rng = np.random.default_rng(shards)
+    psize = np.zeros((shards, n_loc), np.int64)
+    psize.reshape(-1)[:n] = rng.integers(1, 7, n)
+    psize.reshape(-1)[0] = 40                         # the hub's pool
+    pstart = np.cumsum(psize, axis=1) - psize
+    S_loc_pad = int(psize.sum(axis=1).max()) + 5      # padding past pools
+    dest = np.full((shards, S_loc_pad), -1, np.int32)
+    cterm = np.zeros((shards, S_loc_pad), np.int32)
+    for p in range(shards):
+        S = int(psize[p].sum())
+        dest[p, :S] = np.where(rng.random(S) < 0.5, 0,
+                               rng.integers(0, n, S))
+        dest[p, :S][rng.random(S) < 0.1] = -1         # padding, allocated
+        cterm[p, :S] = rng.random(S) < 0.3
+    walks = np.zeros((shards, n_loc), np.int32)
+    walks.reshape(-1)[:n] = K
+
+    spec = NamedSharding(mesh, P(di.AXIS))
+    put = lambda x: jax.device_put(np.asarray(x, np.int32), spec)
+    index = di._make_p2_index(mesh, n_loc=n_loc, shards=shards)
+    step = di._make_p2_step(mesh, n_loc=n_loc, shards=shards,
+                            S_loc_pad=S_loc_pad)
+    dkey, ends = index(put(dest))
+    state = [put(walks), put(np.zeros_like(walks)),
+             put(np.zeros_like(dest)), put(np.zeros_like(walks))]
+    next_c = np.zeros_like(walks)
+    tail = np.zeros_like(walks)
+    rounds = []
+    for _ in range(6):
+        # the numpy stitch round
+        a = np.minimum(walks, psize - next_c)
+        go = np.zeros_like(dest, bool)
+        for p in range(shards):
+            for v in range(n_loc):
+                lo = pstart[p, v] + next_c[p, v]
+                go[p, lo:lo + a[p, v]] = cterm[p, lo:lo + a[p, v]] == 0
+        moved = dest[go]
+        want = np.bincount(moved[moved >= 0], minlength=n_pad)
+        want = want.reshape(shards, n_loc)
+        tail = tail + walks - a
+        next_c = next_c + a
+        walks = want
+
+        out = step(*state, dkey, ends, put(cterm), put(psize), put(pstart))
+        state = list(out[:4])
+        got = np.asarray(out[0])
+        rounds.append(dict(
+            same=bool(np.array_equal(got, want)),
+            next_c=bool(np.array_equal(np.asarray(out[1]), next_c)),
+            tail=bool(np.array_equal(np.asarray(out[3]), tail)),
+            active=int(out[4]) == int(want.sum()),
+            padding_moved=int((moved < 0).sum()), hub=int(want[0, 0]),
+            moved=int(want.sum())))
+    print(json.dumps(dict(shards=shards, rounds=rounds)))
+""")
+
+
+@pytest.mark.parametrize("devices", [1, 2, 4])
+def test_stitch_arrivals_equal_a_histogram_of_destinations(devices):
+    out = run_forced_devices(STITCH_CODE, devices=devices)
+    assert out["shards"] == devices
+    rounds = out["rounds"]
+    for r in rounds:
+        assert r["same"] and r["next_c"] and r["tail"] and r["active"], r
+    assert rounds[0]["padding_moved"] > 0 and rounds[0]["hub"] > 0, rounds
+    assert sum(r["moved"] > 0 for r in rounds) >= 3, rounds
+
+
+def test_zeta_is_bit_identical_to_a_scatter_add_stitch():
+    """The stitch's sort changes no sum: on a tiny Graph500 graph, at 1
+    and 2 shards, `zeta` and the stitch rounds' records equal those of
+    the same engine whose stitch sums by `vertex_histogram` (the
+    scatter-add it replaced)."""
+    out = run_forced_devices(textwrap.dedent("""
+        import json, os, sys, jax, numpy as np
+        from jax.sharding import Mesh
+        sys.path.insert(0, %r)
+        from bench.data.graph500 import graph500
+        from repro.core import distributed_improved as di
+        from repro.core.graph import CSRGraph
+        from repro.core.routing import vertex_histogram
+
+        def histogram_reference(dst, ends, values):
+            return vertex_histogram(dst, values > 0, ends.shape[0] - 1)
+
+        row_ptr, col_idx, out_deg = graph500(8, 22)
+        g = CSRGraph(row_ptr=row_ptr, col_idx=col_idx, out_deg=out_deg,
+                     n=len(out_deg), m=len(col_idx), undirected=True)
+        runs = {}
+        for shards in (1, 2):
+            mesh = Mesh(np.array(jax.devices()[:shards]), (di.AXIS,))
+            for name in ("sorted", "scatter"):
+                real = di.sorted_segment_sums
+                if name == "scatter":
+                    di.sorted_segment_sums = histogram_reference
+                di._make_p2_step.cache_clear()
+                try:
+                    res = di.distributed_improved_pagerank(
+                        g, 0.15, 8, jax.random.PRNGKey(5), mesh=mesh)
+                finally:
+                    di.sorted_segment_sums = real
+                    di._make_p2_step.cache_clear()
+                runs[shards, name] = res
+        out = {}
+        for shards in (1, 2):
+            a, b = runs[shards, "sorted"], runs[shards, "scatter"]
+            out[shards] = dict(
+                zeta=bool(np.array_equal(np.asarray(a.zeta),
+                                         np.asarray(b.zeta))),
+                records=a.phase2_records == b.phase2_records,
+                wire=a.a2a_bytes_by_phase == b.a2a_bytes_by_phase,
+                rounds=a.phase2_rounds, visits=int(a.total_visits))
+        print(json.dumps(out))
+    """ % ROOT), devices=2)
+    for shards in ("1", "2"):
+        r = out[shards]
+        assert r["zeta"] and r["records"] and r["wire"], out
+        assert r["rounds"] > 2 and r["visits"] > 0, out
+
+
+def test_build_counts_the_stitch_sort_entries(g500_job):
+    """`improved.build` counts the slots a stitch round sorts: every
+    coupon slot of a shard, padding included (one shard here, so all
+    the coupons)."""
+    g, res, recs = g500_job
+    (build,) = [r for r in recs if r.name == "improved.build"]
+    plan = di.plan_three_phase(
+        g, res.shards, demand_pool_sizes(g, 0.15, 32, res.lam), 32)
+    assert build.counts == dict(stitch_sort_entries=plan.S_loc_pad)
+    assert plan.S_loc_pad == res.coupons_created > g.n * 32

@@ -7,9 +7,9 @@ the benchmark's cells run, built by the engines' own step makers (the
 CONGEST audit specs) over a mesh of described chips: the counts
 engine's `jit_sample` at bucket widths 1 to 14 and its `jit_exchange`,
 on one chip and on the 2x2 host's four; Algorithm 2's Phase-1, stitch
-and count programs on one chip. The graphs are small, so the file
-stays quick; full-size compiles are for a scratch script before a chip
-run.
+index, stitch and count programs on one chip. The graphs are small, so
+the file stays quick; full-size compiles are for a scratch script before
+a chip run.
 
 The topology is described inside a module fixture, never while a module
 is imported, so every pytest worker collects the same tests and only the
@@ -118,6 +118,7 @@ def test_exchange_compiles_on_four_chips(meshes, graph):
     ("phase1", "request", "p1_request"),
     ("phase1", "sample", "p1_sample"),
     ("phase1", "assign", "p1_assign"),
+    ("phase2", "index", "p2_index"),
     ("phase2", "stitch", "p2_stitch"),
     ("phase3", "count", "p3_count"),
 ])
